@@ -13,34 +13,14 @@ Layer map (mirrors SURVEY.md §1):
     api/, cli  HTTP proving service + client           (the "mpc-api"/"zk-cli" role)
 """
 
-import os
-
 import jax
 
 # Persistent compilation cache: our kernels are built from deep uint32 limb
-# graphs; caching compiled executables across processes matters for tests,
-# benches and the service alike. Partitioned by CPU fingerprint
-# (utils/cache.py): XLA:CPU AOT entries from a host with different vector
-# features can SIGILL on load, and driver rounds hop between hosts.
-try:
-    from .utils import config as _config
+# graphs, and a cold proof is mostly compile. Placement is decided here,
+# once per process, by utils/cache.py (JAX_COMPILATION_CACHE_DIR if the
+# launcher set it, else a fixed <checkout>/.jax_cache).
+from .utils.cache import setup_compile_cache
 
-    if _config.env_flag("DG16_NO_JAX_CACHE"):
-        from .utils.cache import disable_compile_cache
-
-        disable_compile_cache(jax)
-    elif cache_dir := _config.env_str("DG16_JAX_CACHE"):
-        jax.config.update(
-            "jax_compilation_cache_dir", os.path.abspath(cache_dir)
-        )
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    else:
-        from .utils.cache import setup_compile_cache
-
-        setup_compile_cache(
-            jax, os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
-        )
-except Exception:  # pragma: no cover - older jax without these flags
-    pass
+setup_compile_cache(jax)
 
 __version__ = "0.1.0"

@@ -84,8 +84,8 @@ def build_mesh_prover(pp: PackedSharingParams, m: int, mesh: Mesh,
         # --- A, B, C ----------------------------------------------------
         # the three G1 MSMs run as ONE batched d_msm (zero-padded to a
         # common length): one curve-ladder instantiation instead of three,
-        # the main compile-time lever (VERDICT r2 weak #3). Zero-scalar /
-        # zero-point padding contributes the identity.
+        # the main compile-time lever. Zero-scalar / zero-point padding
+        # contributes the identity.
         cmax = max(s_q.shape[1], w_q.shape[1], u_q.shape[1])
 
         def pads(x):  # scalars (c, 16) -> (cmax, 16); zero scalar is inert

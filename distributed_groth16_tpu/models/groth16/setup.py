@@ -90,7 +90,7 @@ def _g1_ladder(scalars: list[int]) -> jnp.ndarray:
     """(k,) ints -> (k, 3, 16) projective points scalar * G1 generator via
     the windowed fixed-base table (ops/fixedbase.py) — 31 batched adds per
     point instead of a 256-step ladder, the scaling fix for million-size
-    setup (VERDICT r2 weak #5)."""
+    setup."""
     from ...ops.fixedbase import fixed_base_mul
 
     return fixed_base_mul("g1", encode_scalars_std(scalars))

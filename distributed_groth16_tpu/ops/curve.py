@@ -283,7 +283,7 @@ class CurvePoints:
         """Point sum along an axis via fori_loop accumulation — ONE add
         instantiation versus the tree's log n. Each distinct add/double
         instance costs seconds of XLA:CPU compile (the mesh-prover dryrun
-        blowup of VERDICT r2 weak #3), so small-n reductions inside large
+        blowup), so small-n reductions inside large
         traced programs should prefer this; large-n hot-path reductions
         keep the parallel tree of `sum`."""
         ax = axis % (pts.ndim - 1 - self.coord_axes)

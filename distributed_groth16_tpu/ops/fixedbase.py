@@ -5,7 +5,7 @@ generator), so the 256-step double-and-add ladder is wasteful: precompute
 T[w][d] = d * 2^(c*w) * G once (host affine arithmetic, ops/refmath.py),
 then each scalar costs W-1 = 31 batched complete additions of table
 gathers — 16x fewer curve ops than the ladder, and a single add
-instantiation (compile-light, see VERDICT r2 weak #3/#5).
+instantiation (compile-light).
 
 Replaces the per-element generator ladders of the reference's
 circuit_specific_setup (the reference leans on arkworks

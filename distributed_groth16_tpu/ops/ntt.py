@@ -40,22 +40,9 @@ _R_ROW = _ROUTE.labels(kernel="ntt", path="row")
 
 
 def _tracing_active() -> bool:
-    """True when called under a jit/vmap trace. Prefers the private
-    trace_state_clean (public jax.core lost it in this version); if a
-    future jax drops the _src alias too, falls back to probing whether
-    arithmetic on a concrete array yields a Tracer — and on any probe
-    failure conservatively reports True (the in-trace path is always
-    correct, just slightly more device work)."""
-    try:
-        from jax._src.core import trace_state_clean
-
-        return not trace_state_clean()
-    except ImportError:
-        try:
-            probe = jnp.zeros((), dtype=jnp.int32) + 0
-            return isinstance(probe, jax.core.Tracer)
-        except Exception:
-            return True
+    """True when called under a jit/vmap trace: arithmetic on a concrete
+    array yields a Tracer exactly then."""
+    return isinstance(jnp.zeros((), dtype=jnp.int32) + 0, jax.core.Tracer)
 
 
 def _bitrev(n: int, xp):

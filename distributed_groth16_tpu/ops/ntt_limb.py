@@ -7,13 +7,14 @@ sublane axis, elements on lanes), in Montgomery form, redundant [0, 2p) —
 the same representation as ops/limb_kernels.LimbField, instantiated here
 for the SCALAR field r (limb_kernels uses the base field q).
 
-Structure (four-step Cooley-Tukey):
+Structure (four-step Cooley-Tukey, applied recursively):
   * n <= _S_MAX: one fused Pallas kernel — bitrev in XLA, then log2(n)
     butterfly stages entirely in VMEM with per-stage twiddle tables.
-  * n > _S_MAX: n = A*B split (A, B <= _S_MAX): batched NTT_A kernel over
+  * n > _S_MAX: n = A*B split with A = _S_MAX: batched NTT_A kernel over
     the B columns, one elementwise twiddle multiply w^{k1*j2} (table built
-    device-side from the domain's dense root table), transpose, batched
-    NTT_B kernel — output lands in natural order without a final
+    device-side from the domain's dense root table), transpose, then the
+    size-B transform the same way (a kernel if B <= _S_MAX, another split
+    otherwise) — output lands in natural order without a final
     permutation (X[k1 + A*k2] = Z[k2, k1] and the (16, B, A) reshape IS
     that ordering).
 
@@ -34,12 +35,15 @@ from .limb_kernels import NL, LimbField, _pl, kernel_roll_mode, use_pallas
 from .ntt import bitrev_perm
 from .refmath import finv
 
-# max single-kernel NTT size: the (16, S, lane-tile) block plus the stage
-# temporaries must stay inside VMEM. The lane tile must be a multiple of
-# 128 (Mosaic requires block minor dim % 128 == 0 — the original 64 failed
-# lowering outright), so S is capped at 256: 16*256*128*4 = 2 MB per block,
-# in + out + ~3 live stage temporaries ~= 10 MB of the 16 MB VMEM.
-_S_MAX = 256
+# max single-kernel NTT size. The lane tile must be a multiple of 128
+# (Mosaic requires block minor dim % 128 == 0) and Mosaic unrolls every
+# stage's field ops over the whole (16, S*128/2) block, so both the
+# scoped-VMEM stack and the compile time grow with S. Compiled for a v5e
+# (libtpu 0.0.34): S = 256 needs 16.01 MB of stack against the 16 MB
+# default limit (RESOURCE_EXHAUSTED) and ~100 s per kernel once the limit
+# is raised; S = 128 compiles in ~28 s; S = 64 in ~8 s with a ~4 MB stack.
+# Larger transforms take one more four-step level instead.
+_S_MAX = 64
 _LANE_TILE = 128
 
 
@@ -148,32 +152,37 @@ class _SmallNTT:
         @jax.jit
         def run(x):  # (16, S, L) natural order
             x = jnp.take(x, self.perm, axis=1)
+            # every transform rides the kernel: lane counts that are not a
+            # multiple of the 128-lane tile (n < 128 * _S_MAX at one
+            # four-step level) are zero-padded up to it, not rerouted to
+            # the XLA body
             L = x.shape[2]
-            lt = min(_LANE_TILE, L)
-            return pl.pallas_call(
+            Lp = -(-L // _LANE_TILE) * _LANE_TILE
+            if Lp != L:
+                x = jnp.pad(x, ((0, 0), (0, 0), (0, Lp - L)))
+            out = pl.pallas_call(
                 kern,
-                out_shape=jax.ShapeDtypeStruct((NL, S, L), jnp.uint32),
-                grid=(L // lt,),
+                out_shape=jax.ShapeDtypeStruct((NL, S, Lp), jnp.uint32),
+                grid=(Lp // _LANE_TILE,),
                 in_specs=[
-                    pl.BlockSpec((NL, S, lt), lambda i: (0, 0, i),
+                    pl.BlockSpec((NL, S, _LANE_TILE), lambda i: (0, 0, i),
                                  memory_space=pltpu.VMEM),
                     pl.BlockSpec((NL, logn, TW), lambda i: (0, 0, 0),
                                  memory_space=pltpu.VMEM),
                     pl.BlockSpec((2 * NL, 1), lambda i: (0, 0),
                                  memory_space=pltpu.VMEM),
                 ],
-                out_specs=pl.BlockSpec((NL, S, lt), lambda i: (0, 0, i),
+                out_specs=pl.BlockSpec((NL, S, _LANE_TILE),
+                                       lambda i: (0, 0, i),
                                        memory_space=pltpu.VMEM),
             )(x, jnp.asarray(self.tw_np), jnp.asarray(consts))
+            return out[:, :, :L]
 
         return run
 
     def __call__(self, x):
         """(16, S, L) natural-order columns -> NTT'd along axis 1."""
-        L = x.shape[2]
-        if use_pallas() and L % _LANE_TILE == 0:
-            return self._pallas(x)
-        return self._xla(x)
+        return self._pallas(x) if use_pallas() else self._xla(x)
 
 
 @functools.cache

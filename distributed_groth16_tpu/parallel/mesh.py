@@ -26,24 +26,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map as _shard_map_fn
 from jax.sharding import Mesh
-
-try:  # jax>=0.4.35 moved shard_map out of experimental
-    from jax import shard_map as _shard_map_fn
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_fn(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_fn
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_fn(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
 
 from ..ops.field import fr
 from ..telemetry.compile import timed_jit
@@ -53,11 +37,18 @@ from .pss import PackedSharingParams
 AXIS = "parties"
 
 
+def shard_map(f, mesh, in_specs, out_specs):
+    return _shard_map_fn(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )
+
+
 def mesh_jit(fn_name: str, fn):
     """jit a mesh program with compile-cost telemetry: the first call per
     argument signature lands in `compile_seconds{fn}` and the hit/miss
     counters (telemetry/compile.py) — the m=32768 prover is compile-bound
-    on some backends (VERDICT), and this makes that a measured number
+    on some backends, and this makes that a measured number
     instead of folklore. Use for every whole-mesh jitted entry point."""
     return timed_jit(fn_name, jax.jit(fn))
 
@@ -130,8 +121,8 @@ def _mesh_dmsm_batched(curve, bases_block, scalar_block, pp: PackedSharingParams
 
     bases: (1, B, c, 3)+elem, scalars: (1, B, c, 16) Montgomery ->
     replicated clear (B, 3)+elem. Batching is the compile-time lever: each
-    distinct curve-op instantiation costs seconds of XLA:CPU compile
-    (VERDICT r2 weak #3), so the prover's three same-length G1 MSMs share
+    distinct curve-op instantiation costs seconds of XLA:CPU compile,
+    so the prover's three same-length G1 MSMs share
     one ladder instead of instantiating three.
     """
     from ..ops.msm import msm_batched

@@ -330,7 +330,7 @@ def _dense_ladder_jit(curve: CurvePoints, ax: int, nbits: int,
 
     acc, _ = jax.lax.fori_loop(0, nbits, body, (acc, base))
     # K is small (<= 2n): sequential accumulation is one add instance,
-    # the compile-light reduction (VERDICT r2 weak #3)
+    # the compile-light reduction
     return curve.sum_sequential(acc, axis=len(batch) + 1)
 
 

@@ -325,9 +325,9 @@ def main(argv=None) -> int:
             run = load_run(args.check)
         else:
             # the package __init__ already configured the persistent
-            # compile cache (DG16_JAX_CACHE / DG16_NO_JAX_CACHE honored)
-            # — re-pointing it here would override an operator's explicit
-            # cache directory
+            # compile cache (JAX_COMPILATION_CACHE_DIR / DG16_NO_JAX_CACHE
+            # honored) — re-pointing it here would override an operator's
+            # explicit cache directory
             from . import perf
 
             try:

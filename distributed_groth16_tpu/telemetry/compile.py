@@ -1,8 +1,7 @@
 """JAX compile-cost telemetry: make "it's compile-bound" measurable.
 
-The m=32768 mesh prover is dominated by XLA compilation on some backends
-(VERDICT r5), but until now that showed up only as an unexplained slow
-first call. `timed_jit` wraps a jitted callable and keys calls by the
+The m=32768 mesh prover is dominated by XLA compilation on some backends,
+but until now that showed up only as an unexplained slow first call. `timed_jit` wraps a jitted callable and keys calls by the
 argument signature (shapes + dtypes): the first call per signature is a
 compile miss — timed to full materialisation (`block_until_ready`, so the
 number is compile + first execution; for a compile-bound program that IS

@@ -310,7 +310,7 @@ def make_record(
     }
     # roofline attribution (telemetry/roofline.py): device records with a
     # cost model also say which roof they lean on and how hard — the
-    # device/host split BENCH_r0x's "kernels" section reports
+    # device/host split the bench line's "kernels" section reports
     rec["roofline"] = (
         _roofline.attribute(cost, med) if not host else None
     )

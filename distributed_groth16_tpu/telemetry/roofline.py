@@ -16,9 +16,11 @@ use for kernel optimization:
                                   the machine are we using" number)
 
 Peaks come from `DG16_PEAK_FLOPS` / `DG16_PEAK_BW` when set, else a
-device-kind default table (TPU datasheet numbers; a deliberately
-conservative host-class default for XLA:CPU — CPU utilization numbers are
-for TREND, the table is the TPU contract). Attribution lands in every
+device-kind table (TPU datasheet numbers). XLA:CPU (device kind "cpu")
+gets a deliberately conservative host-class default, labelled
+`default:cpu` — CPU utilization numbers are for TREND, the table is the
+TPU contract. Any other device kind missing from the table is an error,
+never a default. Attribution lands in every
 device perf record (`record["roofline"]`), in the
 `perf_kernel_utilization{kernel,size}` gauge, and in the
 `dg16-cli perf roofline` table (docs/PERF.md "Roofline workflow").
@@ -41,35 +43,37 @@ PEAKS_BY_DEVICE_KIND: tuple = (
     ("TPU v2", 46e12, 7.0e11),
 )
 
-# host-class fallback (XLA:CPU, unknown kinds): a few-core x86 container —
-# utilization against it is a trend signal, not a contract
-DEFAULT_PEAK_FLOPS = 1e11
-DEFAULT_PEAK_BW = 5e10
+# host-class peaks for XLA:CPU only (device kind "cpu"): a few-core x86
+# container — utilization against it is a trend signal, not a contract
+CPU_DEVICE_KIND = "cpu"
+CPU_PEAK_FLOPS = 1e11
+CPU_PEAK_BW = 5e10
 
 
 def device_kind() -> str:
-    try:
-        import jax
+    import jax
 
-        return str(jax.devices()[0].device_kind)
-    except Exception:  # noqa: BLE001 — no backend: attribute against defaults
-        return "unknown"
+    return str(jax.devices()[0].device_kind)
 
 
 def peaks(kind: str | None = None) -> dict:
     """The peak table one attribution run uses:
     {flops, bw, deviceKind, source} with source one of `env`,
-    `device:<kind>`, `default`. Env knobs override per-field."""
+    `device:<kind>`, `default:cpu`. Env knobs override per-field. Raises
+    LookupError for an accelerator the table does not know."""
     kind = kind if kind is not None else device_kind()
-    flops = bw = None
-    source = "default"
-    for prefix, f, b in PEAKS_BY_DEVICE_KIND:
+    for prefix, flops, bw in PEAKS_BY_DEVICE_KIND:
         if kind.startswith(prefix):
-            flops, bw = f, b
             source = f"device:{prefix}"
             break
-    if flops is None:
-        flops, bw = DEFAULT_PEAK_FLOPS, DEFAULT_PEAK_BW
+    else:
+        if kind != CPU_DEVICE_KIND:
+            raise LookupError(
+                f"no peak-table row for device kind {kind!r} "
+                "(telemetry/roofline.py PEAKS_BY_DEVICE_KIND)"
+            )
+        flops, bw = CPU_PEAK_FLOPS, CPU_PEAK_BW
+        source = "default:cpu"
     env_flops = _config.env_float("DG16_PEAK_FLOPS", 0.0)
     env_bw = _config.env_float("DG16_PEAK_BW", 0.0)
     if env_flops > 0 or env_bw > 0:

@@ -98,7 +98,6 @@ KNOBS: dict[str, str] = {
     "DG16_SLO_SAMPLE_S": "SLO sampler period",
     # kernels / JAX (docs/PERF.md)
     "DG16_NO_JAX_CACHE": "disable the persistent compilation cache",
-    "DG16_JAX_CACHE": "explicit compilation-cache directory",
     "DG16_FORCE_LIMB_NTT": "route NTTs to the limb-major path anywhere",
     "DG16_FORCE_TREE_MSM": "route MSMs to the limb tree path anywhere",
     "DG16_PALLAS_ROLL": "Pallas kernel body mode: fori|scan|unroll",
@@ -106,10 +105,8 @@ KNOBS: dict[str, str] = {
     "DG16_NO_CWASM": "force the pure-Python WASM witness VM",
     "DG16_STORE": "circuit store root directory",
     # bench / examples / tests
-    "DG16_BENCH_BUDGET_S": "bench.py per-stage time budget",
     "DG16_BENCH_BATCH_REPS": "bench.py --batch timing repetitions",
     "DG16_BENCH_BATCH_CHAIN": "bench.py --batch chain-circuit length",
-    "DG16_EXAMPLE_TPU": "examples: allow running on a real TPU",
     "DG16_VECTORS": "introspect.py: external test-vector directory",
     "DG16_REQUIRE_VECTORS": "introspect.py: fail when vectors missing",
     "DG16_TEST_CACHE": "scripts/run_tests.py: keep the jit cache on",

@@ -24,12 +24,6 @@ import sys
 _ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
 sys.path.insert(0, _ROOT)
 
-import jax  # noqa: E402
-
-from distributed_groth16_tpu.utils.cache import setup_compile_cache  # noqa: E402
-
-setup_compile_cache(jax, _ROOT)
-
 
 async def _run_dfft(opt, pp, net):
     """d_fft with king_clear: king receives the clear evaluations and

@@ -23,14 +23,8 @@ import time
 _ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
 sys.path.insert(0, _ROOT)
 
-# fingerprint-partitioned persistent compile cache: 8 rank processes share
-# compilations instead of each cold-compiling the full prover
-import jax  # noqa: E402
-
-from distributed_groth16_tpu.utils.cache import setup_compile_cache  # noqa: E402
-
-setup_compile_cache(jax, _ROOT)
-
+# the 8 rank processes share the package's persistent compile cache
+# (utils/cache.py) instead of each cold-compiling the full prover
 
 def _build_circuit(opt):
     if opt.circuit == "sha256":

@@ -15,13 +15,6 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
 
 import jax
-
-from distributed_groth16_tpu.utils.cache import setup_compile_cache
-
-setup_compile_cache(
-    jax, os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
-)
-
 import jax.numpy as jnp
 import numpy as np
 

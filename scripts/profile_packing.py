@@ -1,6 +1,6 @@
 """CRS in-the-exponent packing micro-bench (the million-workload CPU
 bottleneck: 74-84% of wall-clock rode the row-major ladders; on TPU the
-same packexp ladders ride the limb-major Pallas kernels — VERDICT r3 #6).
+same packexp ladders ride the limb-major Pallas kernels).
 
 Times pp.packexp_from_public over BN254 G1 at --log2-m points (the S-query
 shape: m points packed l at a time into n-share groups), reporting
@@ -29,10 +29,6 @@ def main() -> int:
     args = ap.parse_args()
 
     import jax
-
-    from distributed_groth16_tpu.utils.cache import setup_compile_cache
-
-    setup_compile_cache(jax, os.path.join(os.path.dirname(__file__), ".."))
     import jax.numpy as jnp
     import numpy as np
 

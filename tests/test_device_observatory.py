@@ -266,7 +266,7 @@ def test_roofline_bound_classification_and_utilization():
 
 def test_roofline_peaks_env_overrides(monkeypatch):
     base = roofline.peaks(kind="cpu")
-    assert base["source"] == "default"
+    assert base["source"] == "default:cpu"
     monkeypatch.setenv("DG16_PEAK_FLOPS", "2e12")
     monkeypatch.setenv("DG16_PEAK_BW", "1e11")
     over = roofline.peaks(kind="cpu")
@@ -281,7 +281,10 @@ def test_roofline_peaks_env_overrides(monkeypatch):
 def test_roofline_device_kind_table_prefix_match():
     pk = roofline.peaks(kind="TPU v5 lite")
     assert pk["source"] == "device:TPU v5 lite" and pk["flops"] == 197e12
-    assert roofline.peaks(kind="weird accelerator")["source"] == "default"
+    # an accelerator the table does not know is an error, never the host
+    # default (the v5e reports "TPU v5 lite", matched above)
+    with pytest.raises(LookupError):
+        roofline.peaks(kind="weird accelerator")
 
 
 def _perf_rec(key, host=False, cost=None, med=0.1, error=None):

@@ -2,10 +2,9 @@
 with the fori-rolled formulations) under Mosaic interpret mode on CPU.
 
 Everything else in the CPU suite exercises the plain-XLA fallback bodies;
-the pallas_call plumbing itself (block slicing, grid iteration, the
-in-kernel masked row extraction) had zero coverage off-TPU — the NTT lane
-tile that could never have lowered (minor dim 64 vs Mosaic's 128
-requirement) survived three rounds that way. Interpret mode runs the
+the pallas_call plumbing itself (block slicing, grid iteration) had zero
+coverage off-TPU — the NTT lane tile that could never have lowered (minor
+dim 64 vs Mosaic's 128 requirement) survived three rounds that way. Interpret mode runs the
 pallas_call semantics with numpy, so these tests catch BlockSpec/grid
 logic bugs without a chip. Small shapes only: interpret mode is slow."""
 
@@ -131,3 +130,25 @@ def test_ntt_limb_pallas_interpret(pallas_interpret):
     got = fr().decode(np.transpose(dec, (1, 0, 2)).reshape(-1, 16))
     want = [v for c in host for v in c]
     assert list(got) == want
+
+
+def test_ntt_unaligned_lane_count_rides_the_kernel(pallas_interpret,
+                                                   monkeypatch):
+    """A lane count that is not a multiple of the 128-lane tile (small n
+    at one four-step level) is padded into the Pallas kernel — there is
+    no silent XLA substitute behind the ntt/limb route label."""
+    import distributed_groth16_tpu.ops.ntt_limb as nl
+
+    S, L = 8, 24
+    rng = np.random.default_rng(13)
+    x = jnp.asarray(rng.integers(0, 1 << 16, size=(16, S, L),
+                                 dtype=np.uint32))
+    small = nl._SmallNTT(S, False)
+    want = np.asarray(small._xla(x))
+
+    def no_xla(*a):
+        raise AssertionError("XLA body used on the Pallas path")
+
+    monkeypatch.setitem(small.__dict__, "_xla", no_xla)
+    got = np.asarray(small(x))
+    assert got.shape == (16, S, L) and (got == want).all()

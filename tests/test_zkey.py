@@ -2,7 +2,7 @@
 reproduce the key (and the A/B constraint matrices) exactly, and the
 re-imported key must still prove. Binary spec: ark-circom/src/zkey.rs:53-385
 (no .zkey fixture ships in the reference checkout — they are gitignored —
-so the writer doubles as the fixture generator, per VERDICT r2 item 6)."""
+so the writer doubles as the fixture generator)."""
 
 import os
 
