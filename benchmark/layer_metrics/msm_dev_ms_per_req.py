@@ -1,0 +1,11 @@
+"""Device trace: time of the launches whose program is of the group
+`kernel_groups/msm.json`, per job."""
+
+from ._common import per_job
+
+LAYER, UNIT, MOVES = "kernels", "ms", "proof_p50_s"
+
+
+def read(run):
+    pj = per_job(run)
+    return 1e3 * pj["group_s"]["msm"] if pj and "msm" in pj["group_s"] else None
