@@ -23,7 +23,7 @@ from typing import Iterator
 
 from ..core import Finding, Module, Project, call_kw, dotted_name, rule
 
-_JIT_NAMES = {"jit", "pjit", "mesh_jit", "timed_jit", "shard_map"}
+_JIT_NAMES = {"jit", "pjit", "mesh_jit", "named_jit", "shard_map"}
 _STATIC_ATTRS = {"shape", "ndim", "dtype", "size"}
 _STATIC_CALLS = {"len", "isinstance"}
 

@@ -19,6 +19,7 @@ from ..frontend.readers import read_r1cs
 from ..models.groth16.keys import ProvingKey
 from ..models.groth16.setup import setup
 from ..utils import config as _config
+from ..utils.timers import PhaseTimings, phase
 
 SETUP_SEED = 42
 
@@ -77,11 +78,19 @@ class CircuitStore:
             raise FileNotFoundError(f"no {ext} in {circuit_id}")
         return max(cands, key=os.path.getmtime)
 
-    def load(self, circuit_id: str) -> tuple[R1CS, ProvingKey]:
-        r1cs, _ = read_r1cs(self._latest(circuit_id, ".r1cs"))
-        pk = ProvingKey.load(
-            os.path.join(self._dir(circuit_id), "proving_key.npz")
-        )
+    def load(
+        self, circuit_id: str, timings: PhaseTimings | None = None
+    ) -> tuple[R1CS, ProvingKey]:
+        """The circuit and its proving key, read from disk. The two reads
+        are the phases `load.r1cs` (a Python parse of the `.r1cs`) and
+        `load.key` (`np.load` plus the key's uploads), recorded into
+        `timings` when a job hands its own in."""
+        with phase("load.r1cs", timings):
+            r1cs, _ = read_r1cs(self._latest(circuit_id, ".r1cs"))
+        with phase("load.key", timings):
+            pk = ProvingKey.load(
+                os.path.join(self._dir(circuit_id), "proving_key.npz")
+            )
         return r1cs, pk
 
     def get_files(self, circuit_id: str) -> tuple[bytes, bytes]:

@@ -127,8 +127,8 @@ def build_mesh_prover(pp: PackedSharingParams, m: int, mesh: Mesh,
         in_specs=(sharded,) * n_in,
         out_specs=(sharded,) * n_out,
     )
-    # compile cost is THE first-run number at m=32768 — record it
-    # (compile_seconds{fn}, compile_cache_{hits,misses}_total)
+    # compile cost is THE first-run number at m=32768 — the name makes it
+    # readable (jax_trace_seconds_total{fn}, jax_compile_seconds_total{fn})
     return mesh_jit("mesh_prover_zk" if zk else "mesh_prover", mapped)
 
 

@@ -80,7 +80,9 @@ async def compute_A(
     N=None,
     r: int = 0,
 ):
-    with _tracing.span("prove.A", party=net.party_id, sid=sid):
+    with _tracing.span(
+        "prove.A", party=net.party_id, sid=sid, attrs=_tracing.DISPATCH
+    ):
         prod = await d_msm(g1(), S, a_share, pp, net, sid)
         return _acc(g1(), L, _maybe_mul(g1(), N, r), prod)
 
@@ -95,7 +97,9 @@ async def compute_B(
     K=None,
     s: int = 0,
 ):
-    with _tracing.span("prove.B", party=net.party_id, sid=sid):
+    with _tracing.span(
+        "prove.B", party=net.party_id, sid=sid, attrs=_tracing.DISPATCH
+    ):
         prod = await d_msm(g2(), V, a_share, pp, net, sid)
         return _acc(g2(), Z, _maybe_mul(g2(), K, s), prod)
 
@@ -114,7 +118,9 @@ async def compute_C(
     r: int = 0,
     s: int = 0,
 ):
-    with _tracing.span("prove.C", party=net.party_id):
+    with _tracing.span(
+        "prove.C", party=net.party_id, attrs=_tracing.DISPATCH
+    ):
         msms = [
             d_msm(g1(), W, ax_share, pp, net, 0),
             d_msm(g1(), U, h_share, pp, net, 1),
@@ -198,8 +204,12 @@ async def distributed_prove_party(
     zk = (r % fr().p, s % fr().p) != (0, 0)
     if zk and pub is None:
         raise ValueError("randomized proof needs pub=public_prove_consts(pk)")
-    with _tracing.span("prove.party", party=net.party_id):
-        with _tracing.span("prove.h", party=net.party_id):
+    with _tracing.span(
+        "prove.party", party=net.party_id, attrs=_tracing.DISPATCH
+    ):
+        with _tracing.span(
+            "prove.h", party=net.party_id, attrs=_tracing.DISPATCH
+        ):
             h_share = await ext_wit_h(qap_share, pp, net)
         # A and B are independent distributed rounds — overlap them on
         # separate channels (the reference runs them back-to-back on
@@ -252,44 +262,64 @@ def prove_single(
 
     F = fr()
     C1, C2 = g1(), g2()
-    qap = compiled.qap(z_mont)
-    m = pk.domain_size
-    dom = _domain(m)
-    shift = _domain(2 * m).group_gen
-    dom_shift = _domain(m, offset=shift)
-    p_ev = dom_shift.fft(dom.ifft(qap.a))
-    q_ev = dom_shift.fft(dom.ifft(qap.b))
-    w_ev = dom_shift.fft(dom.ifft(qap.c))
-    h_vec = F.sub(F.mul(p_ev, q_ev), w_ev)  # (m, 16) Montgomery
+    # The stage spans carry the MPC path's names. With r = s = 0 (the
+    # served path) every body up to `prove.decode` only enqueues device
+    # work, so those spans are on the dispatch clock; a randomized proof's
+    # `_maybe_mul` reads points back, which makes them wall time.
+    zk = r % F.p != 0 or s % F.p != 0
+    enqueue = None if zk else _tracing.DISPATCH
+    with _tracing.span("prove.qap", attrs=enqueue):
+        qap = compiled.qap(z_mont)
+    with _tracing.span("prove.h", attrs=enqueue):
+        m = pk.domain_size
+        dom = _domain(m)
+        shift = _domain(2 * m).group_gen
+        dom_shift = _domain(m, offset=shift)
+        p_ev = dom_shift.fft(dom.ifft(qap.a))
+        q_ev = dom_shift.fft(dom.ifft(qap.b))
+        w_ev = dom_shift.fft(dom.ifft(qap.c))
+        h_vec = F.sub(F.mul(p_ev, q_ev), w_ev)  # (m, 16) Montgomery
 
     z_std = F.from_mont(z_mont)
     ni = pk.num_instance
-    a_pt = C1.add(
-        _msm(C1, pk.a_query, z_std), C1.encode([pk.vk.alpha_g1])[0]
-    )
-    b_pt = C2.add(
-        _msm(C2, pk.b_g2_query, z_std), C2.encode([pk.vk.beta_g2])[0]
-    )
-    c_pt = C1.add(
-        _msm(C1, pk.l_query, z_std[ni:]),
-        _msm(C1, pk.h_query, F.from_mont(h_vec)),
-    )
-    if r % F.p != 0:
-        a_pt = C1.add(a_pt, _maybe_mul(C1, pk.delta_g1, r))
-    if s % F.p != 0:
-        b_pt = C2.add(b_pt, _maybe_mul(C2, C2.encode([pk.vk.delta_g2])[0], s))
-    if r % F.p != 0 or s % F.p != 0:
-        # C += s*A + r*B1 - rs*delta; with B1 = beta + sum z v + s*delta the
-        # delta terms cancel, leaving s*A + r*(beta + sum z v)
-        extra = _acc(
-            C1,
-            _maybe_mul(C1, a_pt, s),
-            _maybe_mul(
-                C1, C1.add(pk.beta_g1, _msm(C1, pk.b_g1_query, z_std)), r
-            ),
+    with _tracing.span("prove.A", attrs=enqueue):
+        a_pt = C1.add(
+            _msm(C1, pk.a_query, z_std), C1.encode([pk.vk.alpha_g1])[0]
         )
-        c_pt = C1.add(c_pt, extra)
-    return Proof(a=C1.decode(a_pt), b=C2.decode(b_pt), c=C1.decode(c_pt))
+        if r % F.p != 0:
+            a_pt = C1.add(a_pt, _maybe_mul(C1, pk.delta_g1, r))
+    with _tracing.span("prove.B", attrs=enqueue):
+        b_pt = C2.add(
+            _msm(C2, pk.b_g2_query, z_std), C2.encode([pk.vk.beta_g2])[0]
+        )
+        if s % F.p != 0:
+            b_pt = C2.add(
+                b_pt, _maybe_mul(C2, C2.encode([pk.vk.delta_g2])[0], s)
+            )
+    with _tracing.span("prove.C", attrs=enqueue):
+        c_pt = C1.add(
+            _msm(C1, pk.l_query, z_std[ni:]),
+            _msm(C1, pk.h_query, F.from_mont(h_vec)),
+        )
+        if zk:
+            # C += s*A + r*B1 - rs*delta; with B1 = beta + sum z v +
+            # s*delta the delta terms cancel, leaving
+            # s*A + r*(beta + sum z v)
+            extra = _acc(
+                C1,
+                _maybe_mul(C1, a_pt, s),
+                _maybe_mul(
+                    C1,
+                    C1.add(pk.beta_g1, _msm(C1, pk.b_g1_query, z_std)),
+                    r,
+                ),
+            )
+            c_pt = C1.add(c_pt, extra)
+    # where the host waits for the chip: wall time, no `clock` attribute
+    with _tracing.span("prove.decode"):
+        return Proof(
+            a=C1.decode(a_pt), b=C2.decode(b_pt), c=C1.decode(c_pt)
+        )
 
 
 def reassemble_proof(share: PartyProofShare, pk: ProvingKey) -> Proof:

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..telemetry.compile import named_jit
 from ..utils import config as _config
 from .constants import LIMB_BITS, N_LIMBS, Q, to_limbs
 
@@ -450,6 +451,9 @@ class LimbGroup:
 
     def __init__(self, field, b, tile: int | None = None):
         self.F = field
+        # "g1" over a prime field, "g2" over its quadratic extension: the
+        # tree MSM's program is named by it (`_msm_tree_jit`)
+        self.kind = "g2" if isinstance(field, LimbFq2) else "g1"
         self.CR = field.CR
         self.ROWS = 3 * self.CR
         # base-field limb rows (== CR for Fq, CR/2 for Fq2) — the consts
@@ -828,23 +832,27 @@ def msm_tree(points_rm, scalars_std, c: int | None = None,
         # MSM with c=8 would spend everything on 255 empty buckets
         c = 8 if points_rm.shape[0] >= 4096 else 4
     g = group or (lg2() if points_rm.ndim == 4 else lg1())
-    return _msm_tree_jit(g, points_rm, scalars_std, c, window_group)
+    return _MSM_TREE_JITS[g.kind](g, points_rm, scalars_std, c, window_group)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 3, 4))
-def _msm_tree_jit(g: LimbGroup, points_rm, scalars_std, c: int,
-                  window_group: int | None):
+def _msm_tree(g: LimbGroup, points_rm, scalars_std, c: int,
+              window_group: int | None):
+    """The tree MSM's body (see `msm_tree`). Its five stages sit in
+    `jax.named_scope`s (`msm.sort`, `msm.upsweep`, `msm.fenwick`,
+    `msm.combine`, `msm.horner`), so every device op of the program can be
+    put down to a stage in a profiler trace."""
     RR = g.ROWS
     n = points_rm.shape[0]
     W_all = scalars_std.shape[1] * LIMB_BITS // c
     B = 1 << c
     npad = 1 << max(1, (n - 1).bit_length())
-    lm = g.from_rowmajor(points_rm)
-    if npad != n:
-        lm = jnp.concatenate([lm, g.infinity(npad - n)], axis=1)
-    digits = _digits(scalars_std, c)  # (W, n)
-    if npad != n:
-        digits = jnp.pad(digits, ((0, 0), (0, npad - n)))
+    with jax.named_scope("msm.sort"):  # its inputs: layout, padding, digits
+        lm = g.from_rowmajor(points_rm)
+        if npad != n:
+            lm = jnp.concatenate([lm, g.infinity(npad - n)], axis=1)
+        digits = _digits(scalars_std, c)  # (W, n)
+        if npad != n:
+            digits = jnp.pad(digits, ((0, 0), (0, npad - n)))
     levels_n = npad.bit_length() - 1  # log2(npad)
 
     if window_group is None:
@@ -858,79 +866,125 @@ def _msm_tree_jit(g: LimbGroup, points_rm, scalars_std, c: int,
     for w0 in range(0, W_all, window_group):
         dg = digits[w0 : w0 + window_group]  # (Wg, npad)
         Wg = dg.shape[0]
-        order = jnp.argsort(dg, axis=-1)
-        sortd = jnp.take_along_axis(dg, order, axis=-1)
-        ends = jax.vmap(
-            lambda row: jnp.searchsorted(row, jnp.arange(B - 1), side="right")
-        )(sortd)  # (Wg, B-1)
-        gathered = jnp.take(lm, order.reshape(-1), axis=1).reshape(RR, Wg, npad)
+        with jax.named_scope("msm.sort"):
+            order = jnp.argsort(dg, axis=-1)
+            sortd = jnp.take_along_axis(dg, order, axis=-1)
+            ends = jax.vmap(
+                lambda row: jnp.searchsorted(
+                    row, jnp.arange(B - 1), side="right"
+                )
+            )(sortd)  # (Wg, B-1)
+            gathered = jnp.take(lm, order.reshape(-1), axis=1).reshape(
+                RR, Wg, npad
+            )
 
         # Up-sweep; each level is also kept transposed to (Wg*K, ROWS) so
         # the Fenwick node lookups below are contiguous row gathers
         # (embedding-style) instead of ROWS-way strided minor-axis gathers.
-        lvls_t = []
-        x = gathered
-        lvls_t.append(jnp.transpose(x, (1, 2, 0)).reshape(-1, RR))
-        for _ in range(levels_n):
-            k = x.shape[-1]
-            pair = x.reshape(RR, Wg, k // 2, 2)
-            x = g.add(pair[..., 0], pair[..., 1])
+        with jax.named_scope("msm.upsweep"):
+            lvls_t = []
+            x = gathered
             lvls_t.append(jnp.transpose(x, (1, 2, 0)).reshape(-1, RR))
-        total = x[..., 0:1]  # (RR, Wg, 1)
+            for _ in range(levels_n):
+                k = x.shape[-1]
+                pair = x.reshape(RR, Wg, k // 2, 2)
+                x = g.add(pair[..., 0], pair[..., 1])
+                lvls_t.append(jnp.transpose(x, (1, 2, 0)).reshape(-1, RR))
+            total = x[..., 0:1]  # (RR, Wg, 1)
 
         # Fenwick prefix at the B-1 bucket boundaries: gather one node per
         # level per boundary, then sum the levels with a pairwise tree.
-        inf_row = jnp.asarray(g.inf_col)[:, 0]  # (RR,)
-        nodes = []
-        for d in range(levels_n + 1):
-            pd = ends >> d
-            takebit = (pd & 1) == 1
-            idx = jnp.maximum(pd - 1, 0)
-            k = npad >> d
-            flat = (jnp.arange(Wg)[:, None] * k + idx).reshape(-1)
-            node = jnp.take(lvls_t[d], flat, axis=0).reshape(Wg, B - 1, RR)
-            node = jnp.where(takebit[..., None], node, inf_row)
-            nodes.append(node)
-        D = len(nodes)
-        dpad = 1 << (D - 1).bit_length()
-        stack = jnp.stack(nodes, axis=0)  # (D, Wg, B-1, RR)
-        if dpad != D:
-            stack = jnp.concatenate(
-                [
-                    stack,
-                    jnp.broadcast_to(inf_row, (dpad - D, Wg, B - 1, RR)),
-                ],
-                axis=0,
-            )
-        stack = jnp.transpose(stack, (3, 0, 1, 2))  # (RR, dpad, Wg, B-1)
-        while stack.shape[1] > 1:
-            half = stack.shape[1] // 2
-            stack = g.add(stack[:, :half], stack[:, half:])
-        acc = stack[:, 0]  # (RR, Wg, B-1)
-
-        # sum_b b * S_b = sum_{j=0..B-2} (total - C_j)
-        terms = g.add(jnp.broadcast_to(total, acc.shape), g.neg(acc))
-        k = B - 1
-        while k > 1:
-            if k % 2:
-                terms = jnp.concatenate(
+        with jax.named_scope("msm.fenwick"):
+            inf_row = jnp.asarray(g.inf_col)[:, 0]  # (RR,)
+            nodes = []
+            for d in range(levels_n + 1):
+                pd = ends >> d
+                takebit = (pd & 1) == 1
+                idx = jnp.maximum(pd - 1, 0)
+                k = npad >> d
+                flat = (jnp.arange(Wg)[:, None] * k + idx).reshape(-1)
+                node = jnp.take(lvls_t[d], flat, axis=0).reshape(
+                    Wg, B - 1, RR
+                )
+                node = jnp.where(takebit[..., None], node, inf_row)
+                nodes.append(node)
+            D = len(nodes)
+            dpad = 1 << (D - 1).bit_length()
+            stack = jnp.stack(nodes, axis=0)  # (D, Wg, B-1, RR)
+            if dpad != D:
+                stack = jnp.concatenate(
                     [
-                        terms,
+                        stack,
                         jnp.broadcast_to(
-                            jnp.asarray(g.inf_col)[:, :, None], (RR, Wg, 1)
+                            inf_row, (dpad - D, Wg, B - 1, RR)
                         ),
                     ],
-                    axis=-1,
+                    axis=0,
                 )
-                k += 1
-            pair = terms.reshape(RR, Wg, k // 2, 2)
-            terms = g.add(pair[..., 0], pair[..., 1])
-            k //= 2
-        sums.append(terms[..., 0])  # (RR, Wg)
+            stack = jnp.transpose(stack, (3, 0, 1, 2))  # (RR, dpad, Wg, B-1)
+            while stack.shape[1] > 1:
+                half = stack.shape[1] // 2
+                stack = g.add(stack[:, :half], stack[:, half:])
+            acc = stack[:, 0]  # (RR, Wg, B-1)
 
-    s_all = jnp.concatenate(sums, axis=1)  # (RR, W_all)
-    out = g.horner(s_all, c)  # (RR, 1)
-    return g.to_rowmajor(out)[0]
+        # sum_b b * S_b = sum_{j=0..B-2} (total - C_j)
+        with jax.named_scope("msm.combine"):
+            terms = g.add(jnp.broadcast_to(total, acc.shape), g.neg(acc))
+            k = B - 1
+            while k > 1:
+                if k % 2:
+                    terms = jnp.concatenate(
+                        [
+                            terms,
+                            jnp.broadcast_to(
+                                jnp.asarray(g.inf_col)[:, :, None],
+                                (RR, Wg, 1),
+                            ),
+                        ],
+                        axis=-1,
+                    )
+                    k += 1
+                pair = terms.reshape(RR, Wg, k // 2, 2)
+                terms = g.add(pair[..., 0], pair[..., 1])
+                k //= 2
+            sums.append(terms[..., 0])  # (RR, Wg)
+
+    with jax.named_scope("msm.horner"):
+        s_all = jnp.concatenate(sums, axis=1)  # (RR, W_all)
+        out = g.horner(s_all, c)  # (RR, 1)
+        return g.to_rowmajor(out)[0]
+
+
+# One body, one program per (static) group as before; the group now also
+# shows in the program's name, so a trace tells a G1 launch from a G2 one.
+_MSM_TREE_JITS = {
+    kind: named_jit(
+        f"_msm_tree_jit_{kind}", _msm_tree, static_argnums=(0, 3, 4)
+    )
+    for kind in ("g1", "g2")
+}
+
+
+class _MsmTreeJit:
+    """`_msm_tree_jit(g, points_rm, scalars_std, c, window_group)`: the
+    jitted tree MSM of `g`'s kind, with the surface of the single
+    `jax.jit` object it was (what the tests, bench.py, the perf kernels
+    and scripts/profile_msm.py use)."""
+
+    __wrapped__ = staticmethod(_msm_tree)
+
+    def __call__(self, g: LimbGroup, *args):
+        return _MSM_TREE_JITS[g.kind](g, *args)
+
+    def lower(self, g: LimbGroup, *args):
+        return _MSM_TREE_JITS[g.kind].lower(g, *args)
+
+    def clear_cache(self) -> None:
+        for jitted in _MSM_TREE_JITS.values():
+            jitted.clear_cache()
+
+
+_msm_tree_jit = _MsmTreeJit()
 
 
 # ---------------------------------------------------------------------------

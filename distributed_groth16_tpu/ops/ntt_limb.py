@@ -230,30 +230,38 @@ def _ntt_rec(x, n: int, inverse: bool, L: int):
     Recursion: n = A*B with A = min(n, _S_MAX); NTT_A batched over (B, L),
     per-level twiddle w_n^{k1*j2}, transpose, recurse on B batched over
     (A, L). Output ordering X[k1 + A*k2] = Z[k2, k1] makes the final
-    reshape natural order with no extra permutation."""
+    reshape natural order with no extra permutation.
+
+    The steps of a level sit in `jax.named_scope`s, so a device op of the
+    program can be put down to one in a profiler trace: `ntt.small` (the
+    size-<=_S_MAX kernel, bit reversal included), `ntt.twiddle` (the root
+    table, its gather and the multiply), `ntt.transpose`."""
     F = lfr()
     if n <= _S_MAX:
-        return _small(n, inverse)(x)
+        with jax.named_scope("ntt.small"):
+            return _small(n, inverse)(x)
     A = _S_MAX
     B = n // A
-    m = x.reshape(NL, A, B * L)
-    y = _small(A, inverse)(m).reshape(NL, A, B, L)
-    # twiddle w^{k1*j2}: indices into this level's dense root table mod n
-    k1 = jnp.arange(A, dtype=jnp.uint32)[:, None]
-    j2 = jnp.arange(B, dtype=jnp.uint32)[None, :]
-    idx = (k1 * j2) % jnp.uint32(n)  # (A, B)
-    wp = _wpows_lm_traced(n, inverse)  # (16, n)
-    tw = jnp.take(wp, idx.reshape(-1), axis=1).reshape(NL, A, B, 1)
-    y = F.mul(
-        y.reshape(NL, -1),
-        jnp.broadcast_to(tw, y.shape).reshape(NL, -1),
-        jnp.asarray(F.p_col),
-        unroll=False,
-    ).reshape(NL, A, B, L)
-    z = _ntt_rec(
-        jnp.transpose(y, (0, 2, 1, 3)).reshape(NL, B, A * L), B, inverse,
-        A * L,
-    )
+    with jax.named_scope("ntt.small"):
+        m = x.reshape(NL, A, B * L)
+        y = _small(A, inverse)(m).reshape(NL, A, B, L)
+    with jax.named_scope("ntt.twiddle"):
+        # twiddle w^{k1*j2}: indices into this level's dense root table
+        # mod n
+        k1 = jnp.arange(A, dtype=jnp.uint32)[:, None]
+        j2 = jnp.arange(B, dtype=jnp.uint32)[None, :]
+        idx = (k1 * j2) % jnp.uint32(n)  # (A, B)
+        wp = _wpows_lm_traced(n, inverse)  # (16, n)
+        tw = jnp.take(wp, idx.reshape(-1), axis=1).reshape(NL, A, B, 1)
+        y = F.mul(
+            y.reshape(NL, -1),
+            jnp.broadcast_to(tw, y.shape).reshape(NL, -1),
+            jnp.asarray(F.p_col),
+            unroll=False,
+        ).reshape(NL, A, B, L)
+    with jax.named_scope("ntt.transpose"):
+        yt = jnp.transpose(y, (0, 2, 1, 3)).reshape(NL, B, A * L)
+    z = _ntt_rec(yt, B, inverse, A * L)
     return z.reshape(NL, n, L)
 
 

@@ -184,7 +184,8 @@ async def _d_transform(
     log.debug("d_%sfft: party %d stage-1 m=%d (sid=%d)",
               "i" if inverse else "", net.party_id, m, sid)
     with _tracing.span(
-        "dfft.ifft" if inverse else "dfft.fft", party=net.party_id, sid=sid
+        "dfft.ifft" if inverse else "dfft.fft", party=net.party_id, sid=sid,
+        attrs=_tracing.DISPATCH,
     ):
         if inverse:
             share_vec = F.mul(share_vec, dom._size_inv)

@@ -44,7 +44,9 @@ async def d_msm(
     F = scalar_field or fr()
     log.debug("d_msm: party %d local MSM over %d bases (sid=%d)",
               net.party_id, bases.shape[0], sid)
-    with _tracing.span("dmsm", party=net.party_id, sid=sid):
+    with _tracing.span(
+        "dmsm", party=net.party_id, sid=sid, attrs=_tracing.DISPATCH
+    ):
         # wide standard forms (r381 -> 17 limbs) pass through unchanged:
         # ops/msm.py's digit decomposition is width-aware as of r5
         std = F.from_mont(scalar_shares)

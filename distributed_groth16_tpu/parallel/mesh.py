@@ -30,7 +30,7 @@ from jax import shard_map as _shard_map_fn
 from jax.sharding import Mesh
 
 from ..ops.field import fr
-from ..telemetry.compile import timed_jit
+from ..telemetry.compile import named_jit
 from .dfft import _fft1_local, _king_clear_array, _king_tail_array
 from .pss import PackedSharingParams
 
@@ -45,12 +45,13 @@ def shard_map(f, mesh, in_specs, out_specs):
 
 
 def mesh_jit(fn_name: str, fn):
-    """jit a mesh program with compile-cost telemetry: the first call per
-    argument signature lands in `compile_seconds{fn}` and the hit/miss
-    counters (telemetry/compile.py) — the m=32768 prover is compile-bound
-    on some backends, and this makes that a measured number
-    instead of folklore. Use for every whole-mesh jitted entry point."""
-    return timed_jit(fn_name, jax.jit(fn))
+    """jit a mesh program under the name `fn_name`, so that jax's own
+    compile clocks (telemetry/compile.py: `jax_trace_seconds_total{fn}`,
+    `jax_compile_seconds_total{fn}`) and a device trace's launches carry
+    it — the m=32768 prover is compile-bound on some backends, and this
+    makes that a measured number. Use for every whole-mesh jitted entry
+    point."""
+    return named_jit(fn_name, fn)
 
 
 def make_mesh(n_parties: int) -> Mesh:

@@ -73,11 +73,20 @@ class ProofExecutor:
     def resolve_witness(self, job: ProofJob, r1cs) -> list[int]:
         """Resolve + validate a job's witness assignment. Public because
         the batching scheduler's BatchProver resolves each batched job's
-        witness through the same path (scheduler/batch_prover.py)."""
+        witness through the same path (scheduler/batch_prover.py). The
+        two halves are the phases `witness.parse` and `witness.check`."""
+        with phase("witness.parse", job.timings):
+            z = self._parse_witness(job)
+        with phase("witness.check", job.timings):
+            if len(z) != r1cs.num_wires or not r1cs.is_satisfied(z):
+                raise ValueError("witness does not satisfy the circuit")
+        return z
+
+    def _parse_witness(self, job: ProofJob) -> list[int]:
         fields = job.fields
         if "witness_file" in fields:
-            z = read_wtns(fields["witness_file"])
-        elif "input_file" in fields:
+            return read_wtns(fields["witness_file"])
+        if "input_file" in fields:
             # the reference's primary prove flow (mpc-api/src/main.rs:
             # 282-421): JSON inputs -> circom WASM witness generation on
             # the pure-Python interpreter (frontend/wasm_vm.py)
@@ -92,13 +101,8 @@ class ProofExecutor:
                     "upload a .wtns in the witness_file field instead"
                 )
             inputs = json.loads(fields["input_file"].decode())
-            wc = WitnessCalculator(wasm)
-            z = wc.calculate_witness(inputs)
-        else:
-            raise ValueError("need witness_file or input_file")
-        if len(z) != r1cs.num_wires or not r1cs.is_satisfied(z):
-            raise ValueError("witness does not satisfy the circuit")
-        return z
+            return WitnessCalculator(wasm).calculate_witness(inputs)
+        raise ValueError("need witness_file or input_file")
 
     # -- CRS -----------------------------------------------------------------
 
@@ -163,26 +167,37 @@ class ProofExecutor:
             # envelope, entirely different body — no witness, no CRS,
             # no mesh
             return self.verifier.run_job(job)
+        # The phases below are the direct children of the `job` span and
+        # partition it: every statement of a job lies in one of them, so
+        # the DTO's top-level `phases` (keys without a dot) add up to the
+        # job. Dotted keys are children of the phase they name.
         timings = job.timings
         job.note_phase("load")
         with phase("load", timings):
-            r1cs, pk = self.store.load(job.circuit_id)
+            r1cs, pk = self.store.load(job.circuit_id, timings)
         job.check_cancel()
         job.note_phase("witness")
         with phase("witness", timings):
             z = self.resolve_witness(job, r1cs)
         job.check_cancel()
-        F = fr()
-        # the witness-upload boundary: F.encode materializes the (wires,
-        # 16) Montgomery limb tensor on device from host bigints
-        with transfer.account("h2d") as t:
-            z_mont = F.encode(z)
-            t.add_tree(z_mont)
+        job.note_phase("encode")
+        with phase("encode", timings):
+            # the witness-upload boundary: F.encode materializes the
+            # (wires, 16) Montgomery limb tensor on device from host
+            # bigints. The span is the clock; `account` counts the bytes.
+            with transfer.account("h2d") as t:
+                z_mont = fr().encode(z)
+                t.add_tree(z_mont)
         if job.kind == "prove":
             job.note_phase("prove")
             with phase("prove", timings):
-                comp = CompiledR1CS(r1cs)
+                with phase("prove.r1cs", timings):
+                    comp = CompiledR1CS(r1cs)
                 proof = prove_single(pk, comp, z_mont)
+                # the parsed circuit is some 10^5 Python objects and takes
+                # tens of ms to free: here, inside the phase that used it,
+                # not at return, where no phase would own the time
+                del comp, r1cs
         elif job.kind == "mpc_prove":
             pp = PackedSharingParams(job.l)
             job.note_phase("packing")
@@ -220,16 +235,21 @@ class ProofExecutor:
                     ],
                     retries=self.cfg.round_retries,
                 )
-            proof = reassemble_proof(res[0], pk)
+                # the host's wait for the round's device work: decoding
+                # adds no span, so the critical-path window is unchanged
+                proof = reassemble_proof(res[0], pk)
+                del comp, r1cs  # as in `prove`: freed inside a phase
         else:
             raise ValueError(f"unknown job kind {job.kind!r}")
-        job.note_phase(None)
         job.check_cancel()
-        # the proof-readback boundary: serializing pulls the proof's
-        # device-resident curve points back to host
-        with transfer.account("d2h") as t:
-            proof_bytes = proof_to_bytes(proof)
-            t.add(len(proof_bytes))
+        job.note_phase("serialize")
+        with phase("serialize", timings):
+            # the proof-readback boundary: serializing pulls the proof's
+            # device-resident curve points back to host
+            with transfer.account("d2h") as t:
+                proof_bytes = proof_to_bytes(proof)
+                t.add(len(proof_bytes))
+        job.note_phase(None)
         return {
             "circuitId": job.circuit_id,
             "proof": list(proof_bytes),

@@ -1,8 +1,9 @@
 """Telemetry spine: the process-wide metrics registry (metrics.py), span
 tracing with Chrome trace-event export (tracing.py), the star-wide
 aggregation plane — clock alignment, cross-party trace merging, critical
-path (aggregate.py) — the fault flight recorder (flight.py), and JAX
-compile-cost accounting (compile.py). Every layer — transport,
+path (aggregate.py) — the fault flight recorder (flight.py), and jax's
+own compile and trace clocks as counters (compile.py: importing this
+package registers the program's one `jax.monitoring` listener). Every layer — transport,
 distributed kernels, prover, service, API, bench — records through here;
 docs/OBSERVABILITY.md is the catalog and naming convention.
 
@@ -17,6 +18,14 @@ regression gate), which pulls in ops/ and is loaded by its consumers
 stays cheap.
 """
 
-from . import aggregate, devmem, flight, metrics, tracing, transfer  # noqa: F401
+from . import (  # noqa: F401
+    aggregate,
+    compile,
+    devmem,
+    flight,
+    metrics,
+    tracing,
+    transfer,
+)
 from .metrics import registry  # noqa: F401
 from .tracing import TraceBuffer, collect, span  # noqa: F401

@@ -1,81 +1,106 @@
-"""JAX compile-cost telemetry: make "it's compile-bound" measurable.
+"""jax's own compile and trace clocks, as metrics.
 
-The m=32768 mesh prover is dominated by XLA compilation on some backends,
-but until now that showed up only as an unexplained slow first call. `timed_jit` wraps a jitted callable and keys calls by the
-argument signature (shapes + dtypes): the first call per signature is a
-compile miss — timed to full materialisation (`block_until_ready`, so the
-number is compile + first execution; for a compile-bound program that IS
-the compile cost, and it is an upper bound otherwise) and observed into
-`compile_seconds{fn}` — subsequent calls are cache hits. The hit/miss
-counters make jit-cache churn (e.g. an accidentally varying shape
-re-compiling per round) visible as a ratio instead of folklore.
+A cold proof is mostly tracing and compiling, and until a process has
+traced every program it uses nothing runs at speed. jax times each of
+these steps itself and reports them as `jax.monitoring` time-span events
+(`jax/_src/dispatch.py`), keyed by the jitted function's name:
+
+    /jax/core/compile/jaxpr_trace_duration            tracing to a jaxpr
+    /jax/core/compile/jaxpr_to_mlir_module_duration   lowering to StableHLO
+    /jax/core/compile/backend_compile_duration        XLA / Mosaic compile,
+                                                      or the fetch of a
+                                                      persistent-cache hit
+
+This module is the program's one listener for them. It feeds
+
+    jax_trace_seconds_total{fn}     tracing + lowering seconds (what no
+                                    cache keeps)
+    jax_compile_seconds_total{fn}   backend compile or cache-fetch seconds
+    jax_compiles_total              backend compiles begun, all functions
+
+Sums, not merged wall time: a jitted function traced inside another
+reports both, so add series of one `fn`, not across them. After `/readyz`
+a rise of `jax_compiles_total` is the recompile alarm: a shape or a static
+argument is varying per job, and `jax_compile_seconds_total{fn}` says
+whose. The listener is registered when the telemetry package is imported
+and costs nothing between compiles.
 """
 
 from __future__ import annotations
 
-import time
+import types
+
+import jax
 
 from . import metrics as _tm
-from . import tracing as _tracing
+
+_EV_COMPILE = "/jax/core/compile/backend_compile_duration"
+_EV_TRACE = (
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+)
 
 _REG = _tm.registry()
-_COMPILE_SECONDS = _REG.histogram(
-    "compile_seconds",
-    "First-call (trace+compile+run, host-synced) seconds per jitted fn "
-    "and argument signature",
+_TRACE_SECONDS = _REG.counter(
+    "jax_trace_seconds_total",
+    "Seconds jax spent tracing and lowering a jitted function "
+    "(jax.monitoring), per function name",
     ("fn",),
 )
-_HITS = _REG.counter(
-    "compile_cache_hits_total",
-    "Calls served by an already-compiled signature, per fn",
+_COMPILE_SECONDS = _REG.counter(
+    "jax_compile_seconds_total",
+    "Seconds of backend compilation, or of fetching a persistent-cache "
+    "hit (jax.monitoring), per function name",
     ("fn",),
 )
-_MISSES = _REG.counter(
-    "compile_cache_misses_total",
-    "Calls that triggered a trace+compile (new signature), per fn",
-    ("fn",),
+_COMPILES = _REG.counter(
+    "jax_compiles_total",
+    "Backend compilations (or persistent-cache fetches) begun; a rise "
+    "after warm-up means a program is recompiling",
 )
 
 
-def _signature(args: tuple) -> tuple:
-    import jax
-
-    leaves, treedef = jax.tree_util.tree_flatten(args)
-    sig = []
-    for leaf in leaves:
-        shape = getattr(leaf, "shape", None)
-        if shape is not None:
-            sig.append((tuple(shape), str(getattr(leaf, "dtype", ""))))
-        else:
-            sig.append(repr(leaf))
-    return (treedef, tuple(sig))
+def _fn(kw: dict) -> str:
+    """The event's function name; the lowering and compile events say
+    `jit(<name>)` where the trace event says `<name>`."""
+    name = str(kw.get("fun_name", "?"))
+    if name.startswith("jit(") and name.endswith(")"):
+        name = name[4:-1]
+    return name
 
 
-def timed_jit(fn_name: str, jitted):
-    """Wrap a jitted callable with compile-cost accounting (see module
-    docstring). The wrapper is transparent for positional-array call
-    sites — the shape every mesh prover entry point uses."""
-    seen: set = set()
-    hits = _HITS.labels(fn=fn_name)
-    misses = _MISSES.labels(fn=fn_name)
-    hist = _COMPILE_SECONDS.labels(fn=fn_name)
+def _on_time_span(event: str, start: float, end: float, **kw) -> None:
+    if event == _EV_COMPILE:
+        _COMPILE_SECONDS.labels(fn=_fn(kw)).inc(end - start)
+        _COMPILES.inc()
+    elif event in _EV_TRACE:
+        _TRACE_SECONDS.labels(fn=_fn(kw)).inc(end - start)
 
-    def wrapper(*args):
-        key = _signature(args)
-        if key in seen:
-            hits.inc()
-            return jitted(*args)
-        import jax
 
-        with _tracing.span("compile", attrs={"fn": fn_name}):
-            t0 = time.perf_counter()
-            out = jax.block_until_ready(jitted(*args))
-            dt = time.perf_counter() - t0
-        seen.add(key)
-        misses.inc()
-        hist.observe(dt)
-        return out
+def seconds_total() -> float:
+    """Trace, lowering and compile seconds so far, every function summed:
+    the delta round a first call is what that call cost before it ran."""
+    return sum(
+        child.value
+        for fam in (_TRACE_SECONDS, _COMPILE_SECONDS)
+        for _, child in fam.items()
+    )
 
-    wrapper.__wrapped__ = jitted
-    wrapper.__name__ = f"timed_jit({fn_name})"
-    return wrapper
+
+def named_jit(name: str, fn, **jit_kwargs):
+    """`jax.jit(fn)` under a program name of its own: jax's events carry
+    `name` as `fun_name`, XLA names the module `jit_<name>`, and that is
+    what a device trace shows for a launch. `fn` is a plain Python
+    function; the jitted one is a copy of it under the new name (same
+    code, globals and closure), so no wrapper frame enters the traced
+    stack."""
+    named = types.FunctionType(
+        fn.__code__, fn.__globals__, name, fn.__defaults__, fn.__closure__
+    )
+    named.__qualname__ = name
+    named.__kwdefaults__ = fn.__kwdefaults__
+    named.__doc__ = fn.__doc__
+    return jax.jit(named, **jit_kwargs)
+
+
+jax.monitoring.register_event_time_span_listener(_on_time_span)

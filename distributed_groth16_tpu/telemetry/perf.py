@@ -10,10 +10,10 @@ This module is the measurement half of that loop:
     builder; the builder gets a log2-size and returns a `KernelCase`
     (a jitted callable + concrete args + items-per-call). Builders run
     their setup (random bases, twiddle tables) OUTSIDE the timed region.
-  * `run_kernel` executes one case: the first call goes through
-    `telemetry/compile.timed_jit`, so compile cost is measured separately
-    (`compile_seconds{fn}`) and excluded from the warm reps; warm
-    throughput is reported as median + IQR over K host-synced reps.
+  * `run_kernel` executes one case: the first call's trace and compile
+    seconds are read from jax's own clocks (`telemetry/compile.py`), so
+    compile cost is measured separately and excluded from the warm reps;
+    warm throughput is reported as median + IQR over K host-synced reps.
   * Each record also carries XLA's own accounting — `cost_analysis()`
     flops / bytes-accessed (roofline context) and `memory_analysis()`
     argument/temp/output bytes, plus per-device `memory_stats()` peak
@@ -56,7 +56,7 @@ _KERNEL_RATE = _REG.gauge(
 )
 _KERNEL_COMPILE = _REG.gauge(
     "perf_kernel_compile_seconds",
-    "First-call (trace+compile+run) seconds of the last run, per kernel "
+    "First-call trace+compile seconds of the last run, per kernel "
     "and size",
     ("kernel", "size"),
 )
@@ -228,18 +228,12 @@ def run_kernel(spec: KernelSpec, log2n: int, reps: int | None = None) -> dict:
         cost = memory = None
         call = case.fn
     else:
-        tj = _compile.timed_jit(label, case.fn)
-        # timed_jit observes the first-call cost into compile_seconds{fn};
-        # read the number back as the histogram-sum delta so the record
-        # and the /metrics series can never disagree
-        child = _REG.family("compile_seconds").labels(fn=label)
-        before = child.sum
-        tj(*case.args)
-        compile_s = max(0.0, child.sum - before)
-        # warm reps time the RAW jitted callable: the wrapper's per-call
-        # signature hashing is microseconds of Python — an additive bias
-        # of several percent on the tens-of-microseconds kernels the
-        # sub-ms buckets exist to resolve
+        # the first call's cost before it ran, on jax's own clocks: the
+        # delta of the trace + compile counters (telemetry/compile.py), so
+        # the record and the /metrics series can never disagree
+        before = _compile.seconds_total()
+        jax.block_until_ready(case.fn(*case.args))
+        compile_s = max(0.0, _compile.seconds_total() - before)
         raw = case.fn
 
         def call(*a):

@@ -14,9 +14,10 @@ can trigger against a LIVE ApiServer mid-job —
 The capture wraps `jax.profiler.start_trace/stop_trace` writing under
 `DG16_PROF_DIR`; at stop the trace directory (xplane.pb + trace.json.gz)
 is tarred into one artifact, openable in TensorBoard's profile plugin or
-Perfetto. While a capture is live, `tracing.set_annotator` bridges every
-`tracing.span` into a `jax.profiler.TraceAnnotation` of the same name, so
-job phases (load / witness / packing / MPC Proof / dmsm / dfft...) line
+Perfetto. Captures run with the Python tracer off. While a capture is
+live, `tracing.set_annotator` bridges every `tracing.span` into a
+`jax.profiler.TraceAnnotation` of the same name (the `job` span's carries
+its job id), so job phases (load / witness / packing / MPC Proof / dmsm / dfft...) line
 up with the XLA ops they launched in ONE timeline. With no capture
 running the annotator is None and the span hot path is untouched (the
 PR 3 idle zero-overhead guard stays green).
@@ -89,9 +90,14 @@ class Capture:
         }
 
 
-def _annotation_factory(name: str):
+def _annotation_factory(name: str, attrs: dict | None):
+    """The span-to-device-timeline bridge. The `job` annotation carries
+    its job id as an event stat, so a trace's `job` events can be matched
+    to their status DTOs; every other span is a bare name."""
     import jax
 
+    if name == "job" and attrs and "job" in attrs:
+        return jax.profiler.TraceAnnotation(name, job_id=attrs["job"])
     return jax.profiler.TraceAnnotation(name)
 
 
@@ -145,7 +151,13 @@ class Profiler:
                 self._history.popitem(last=False)
         try:
             os.makedirs(cap.directory, exist_ok=True)
-            jax.profiler.start_trace(cap.directory)
+            # Python tracer off: it records every Python and C call of
+            # every thread (a slice of seconds grows to hundreds of MB and
+            # the host slows to a fraction of its speed). The spans reach
+            # the trace as TraceAnnotations, which the host tracer keeps.
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(cap.directory, profiler_options=options)
         except Exception as e:  # noqa: BLE001 — a failed start frees the slot
             with self._lock:
                 self._current = None

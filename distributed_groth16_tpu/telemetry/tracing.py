@@ -65,10 +65,19 @@ _extra_sinks: tuple = ()
 _annotator = None
 
 
+# Span attribute of a body that only ENQUEUES device work (jax dispatch is
+# asynchronous): its duration is the host's dispatch time, not the chip's.
+# Spans whose body is host work, or ends in a host read of a device value,
+# carry no `clock` and are wall time. Device time has one source, the
+# device trace (docs/OBSERVABILITY.md "Which clock a span is on").
+DISPATCH = {"clock": "dispatch"}
+
+
 def set_annotator(factory) -> None:
     """Install (or, with None, remove) the device-timeline annotation
-    factory: a callable `name -> context manager` entered for the span's
-    extent. Installed only while a profiler capture is live."""
+    factory: a callable `(name, attrs) -> context manager` entered for the
+    span's extent (`attrs` is the span's attribute dict or None). Installed
+    only while a profiler capture is live."""
     global _annotator
     _annotator = factory
 
@@ -310,7 +319,7 @@ def span(
     annotation = None
     if ann is not None:
         try:
-            annotation = ann(name)
+            annotation = ann(name, a)
         except Exception:  # noqa: BLE001 — a capture teardown race is benign
             annotation = None
     return Span(name, bufs, timings, party, a, annotation)

@@ -93,7 +93,7 @@ def test_capture_duration_clamped_to_max(tmp_path):
 class _FakeAnnotation:
     entered: list = []
 
-    def __init__(self, name):
+    def __init__(self, name, attrs=None):
         self.name = name
 
     def __enter__(self):

@@ -1,0 +1,212 @@
+"""The proving job's time account (ISSUE 23; docs/OBSERVABILITY.md "Which
+clock a span is on"): the direct children of `job` partition it and reach
+the DTO's `phases`, `prove_single` carries the MPC path's stage names with
+the dispatch clock marked, the profiler bridge starts captures with the
+Python tracer off and puts the job id on the `job` annotation, and the
+tree MSM and the limb NTT carry their names into what a device trace
+shows (program names per group, `jax.named_scope` per stage).
+
+CPU, tiny circuit: these check the account, never a speed.
+"""
+
+import asyncio
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from aiohttp.test_utils import TestClient, TestServer
+
+from distributed_groth16_tpu.api.server import ApiServer
+from distributed_groth16_tpu.api.store import CircuitStore
+from distributed_groth16_tpu.frontend.r1cs import mult_chain_circuit
+from distributed_groth16_tpu.frontend.readers import write_r1cs, write_wtns
+from distributed_groth16_tpu.ops import limb_kernels as lk
+from distributed_groth16_tpu.ops import ntt_limb
+from distributed_groth16_tpu.telemetry import profiler, tracing
+from distributed_groth16_tpu.utils.config import ServiceConfig
+
+TOP_LEVEL = ("load", "witness", "encode", "prove", "serialize")
+CHILDREN = {
+    "load": ("load.r1cs", "load.key"),
+    "witness": ("witness.parse", "witness.check"),
+    "prove": ("prove.r1cs",),
+}
+ENQUEUE_ONLY = ("prove.qap", "prove.h", "prove.A", "prove.B", "prove.C")
+
+
+@pytest.fixture(scope="module")
+def done_job(tmp_path_factory):
+    """The status DTO of one DONE `prove` job, through the HTTP API."""
+    r1cs, z = mult_chain_circuit(9, 7).finish()
+    root = str(tmp_path_factory.mktemp("account_store"))
+    cid = CircuitStore(root).save_circuit("acct", write_r1cs(r1cs), b"")
+
+    async def run():
+        server = ApiServer(
+            CircuitStore(root), ServiceConfig(workers=1, queue_bound=4)
+        )
+        client = TestClient(TestServer(server.app()))
+        await client.start_server()
+        try:
+            resp = await client.post(
+                "/jobs/prove",
+                data={"circuit_id": cid, "witness_file": write_wtns(z)},
+            )
+            body = await resp.json()
+            assert resp.status == 202, body
+            while True:
+                resp = await client.get(f"/jobs/{body['jobId']}")
+                st = await resp.json()
+                if st["state"] in ("DONE", "FAILED", "CANCELLED"):
+                    return st
+                await asyncio.sleep(0.02)
+        finally:
+            await client.close()
+
+    st = asyncio.run(run())
+    assert st["state"] == "DONE", st
+    return st
+
+
+def test_phases_hold_every_top_level_phase_and_child(done_job):
+    want = set(TOP_LEVEL) | {c for cs in CHILDREN.values() for c in cs}
+    assert want <= set(done_job["phases"])
+    # top-level keys are those without a dot, and there is no other
+    assert {k for k in done_job["phases"] if "." not in k} == set(TOP_LEVEL)
+
+
+@pytest.mark.parametrize("parent", sorted(CHILDREN))
+def test_a_phase_is_at_least_the_sum_of_its_children(done_job, parent):
+    phases = done_job["phases"]
+    children = sum(phases[c] for c in CHILDREN[parent])
+    # as_millis rounds each phase to a microsecond
+    assert phases[parent] >= children - 0.01
+
+
+def test_top_level_phases_cover_the_job(done_job):
+    """The account closes: what lies in no phase (the hand-over to the
+    worker thread, the bookkeeping round `finishedAt`) is a small part of
+    the job. Loose on purpose: it checks the account, not a speed."""
+    job_ms = 1e3 * (done_job["finishedAt"] - done_job["startedAt"])
+    named = sum(done_job["phases"][k] for k in TOP_LEVEL)
+    assert named >= 0.9 * job_ms, (named, job_ms)
+    assert named <= job_ms + 1.0
+
+
+def _find(nodes, name):
+    for n in nodes:
+        if n["name"] == name:
+            return n
+    raise AssertionError(f"no span {name!r} among {[n['name'] for n in nodes]}")
+
+
+def test_job_span_tree_is_the_phase_partition(done_job):
+    job = _find(done_job["metrics"]["spans"], "job")
+    assert [c["name"] for c in job["children"]] == list(TOP_LEVEL)
+    for parent, kids in CHILDREN.items():
+        got = [c["name"] for c in _find(job["children"], parent)["children"]]
+        assert got[: len(kids)] == list(kids)
+
+
+def test_prove_single_stages_and_their_clock(done_job):
+    job = _find(done_job["metrics"]["spans"], "job")
+    stages = _find(job["children"], "prove")["children"]
+    assert [s["name"] for s in stages] == [
+        "prove.r1cs", *ENQUEUE_ONLY, "prove.decode",
+    ]
+    for name in ENQUEUE_ONLY:
+        assert _find(stages, name)["attrs"]["clock"] == "dispatch"
+    # host work, and the host's wait for the chip: wall time
+    for name in ("prove.r1cs", "prove.decode"):
+        assert "clock" not in _find(stages, name).get("attrs", {})
+
+
+# -- the bridge to the device clock ------------------------------------------
+
+
+class _Recorder:
+    def __init__(self):
+        self.starts, self.annotations = [], []
+
+    def start_trace(self, log_dir, *args, **kw):
+        self.starts.append((log_dir, args, kw))
+
+    def stop_trace(self):
+        pass
+
+    def annotation(self, name, **kw):
+        self.annotations.append((name, kw))
+        return tracing.NOOP
+
+
+def test_capture_starts_with_python_tracer_off_and_job_id_annotated(
+    tmp_path, monkeypatch
+):
+    rec = _Recorder()
+    monkeypatch.setattr(jax.profiler, "start_trace", rec.start_trace)
+    monkeypatch.setattr(jax.profiler, "stop_trace", rec.stop_trace)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", rec.annotation)
+    p = profiler.Profiler(str(tmp_path))
+    cap = p.start(duration_s=0)
+    try:
+        (log_dir, args, kw), = rec.starts
+        assert log_dir == cap.directory and not args
+        assert kw["profiler_options"].python_tracer_level == 0
+        with tracing.span("job", job="j-123", attrs={"kind": "prove"}):
+            with tracing.span("load"):
+                pass
+    finally:
+        p.stop()
+    assert rec.annotations == [("job", {"job_id": "j-123"}), ("load", {})]
+    assert tracing.span("idle.after") is tracing.NOOP
+
+
+# -- names in the device trace ------------------------------------------------
+
+MSM_SCOPES = ("msm.sort", "msm.upsweep", "msm.fenwick", "msm.combine",
+              "msm.horner")
+
+
+def _lowered_msm(kind: str) -> str:
+    g, pts = {
+        "g1": (lk.lg1(), (16, 3, 16)),
+        "g2": (lk.lg2(), (16, 3, 2, 16)),
+    }[kind]
+    assert g.kind == kind
+    return lk._msm_tree_jit.lower(
+        g, jax.ShapeDtypeStruct(pts, jnp.uint32),
+        jax.ShapeDtypeStruct((16, 16), jnp.uint32), 4, None,
+    ).as_text(debug_info=True)
+
+
+def _module_name(text: str) -> str:
+    return re.search(r"module @(\S+)", text).group(1)
+
+
+@pytest.mark.parametrize("kind", ("g1", "g2"))
+def test_tree_msm_program_is_named_by_group_and_scoped_by_stage(kind):
+    text = _lowered_msm(kind)
+    # what `XLA Modules` shows for a launch; kernel_groups/msm.json still
+    # matches it by the substring
+    assert _module_name(text) == f"jit__msm_tree_jit_{kind}"
+    for scope in MSM_SCOPES:
+        assert f"/{scope}/" in text, scope
+
+
+def test_tree_msm_dispatcher_keeps_its_old_surface():
+    """`_msm_tree_jit` is what tests, bench.py and the perf kernels import:
+    callable with the old signature, `lower` for XLA's cost analysis,
+    `__wrapped__` the un-jitted body, `clear_cache` over both programs."""
+    assert lk._msm_tree_jit.__wrapped__ is lk._msm_tree
+    assert set(lk._MSM_TREE_JITS) == {"g1", "g2"}
+    assert callable(lk._msm_tree_jit) and callable(lk._msm_tree_jit.lower)
+    lk._msm_tree_jit.clear_cache()
+
+
+def test_limb_ntt_steps_are_scoped():
+    text = ntt_limb.ntt_limb.lower(
+        jax.ShapeDtypeStruct((16, 4096), jnp.uint32), 4096, False
+    ).as_text(debug_info=True)
+    for scope in ("ntt.small", "ntt.twiddle", "ntt.transpose"):
+        assert f"/{scope}/" in text, scope
