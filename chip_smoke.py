@@ -425,8 +425,14 @@ def drive(url: str, store, args, r1cs_bytes: bytes, cases: list,
         check(moved.get(("ntt", "limb"), 0) == 1 + 6 * n_prove,
               f"ntt/limb advanced {moved.get(('ntt', 'limb'))}, "
               f"want {1 + 6 * n_prove}: {moved}")
-        # 4 MSMs per single-node proof, 4 per party per MPC proof
-        check(moved.get(("msm", "tree"), 0) >= 4 * n_prove + 4 * 8 * n_prove,
+        # 4 MSMs per single-node proof: the three over the witness take
+        # the limb-0 windows (the worker hands over the host's view of z),
+        # the one over h all windows; 4 per party per MPC proof, all
+        # full-width (the shares fill the field)
+        check(moved.get(("msm", "tree_limb0"), 0) == 3 * n_prove,
+              f"msm/tree_limb0 advanced {moved.get(('msm', 'tree_limb0'))}, "
+              f"want {3 * n_prove}: {moved}")
+        check(moved.get(("msm", "tree"), 0) >= n_prove + 4 * 8 * n_prove,
               f"msm/tree advanced too little: {moved}")
     stage("device path", routes=json.dumps(
         {"/".join(k): int(v) for k, v in sorted(moved.items()) if v}))

@@ -247,7 +247,8 @@ async def distributed_prove_party(
 
 
 def prove_single(
-    pk: ProvingKey, compiled, z_mont: jnp.ndarray, r: int = 0, s: int = 0
+    pk: ProvingKey, compiled, z_mont: jnp.ndarray, r: int = 0, s: int = 0,
+    wide=None,
 ) -> Proof:
     """Single-node prove on device (r = s = 0 default) — the role the plain
     arkworks prover plays in the reference's service
@@ -256,6 +257,11 @@ def prove_single(
     h is the CircomReduction witness map computed with device NTTs: the
     odd-2m-th-root evaluations are one coset FFT (offset = the 2m-th root)
     of the m-domain coefficients.
+
+    `wide` is the host's view of the witness that `encode_observed` made
+    beside `z_mont` (`ops/msm.py`); the MSMs over z then run limb-0
+    windows where its wide wires fit, and the proof is the same. The MSM
+    over h always runs all windows: its scalars fill the field.
     """
     from ...ops.msm import msm as _msm
     from ...ops.ntt import domain as _domain
@@ -268,6 +274,13 @@ def prove_single(
     # `_maybe_mul` reads points back, which makes them wall time.
     zk = r % F.p != 0 or s % F.p != 0
     enqueue = None if zk else _tracing.DISPATCH
+
+    def over_z(view):
+        # the span says how many wide wires its MSM over z was told of
+        if view is None:
+            return enqueue
+        return {**(enqueue or {}), "wide_scalars": view.count}
+
     with _tracing.span("prove.qap", attrs=enqueue):
         qap = compiled.qap(z_mont)
     with _tracing.span("prove.h", attrs=enqueue):
@@ -282,23 +295,26 @@ def prove_single(
 
     z_std = F.from_mont(z_mont)
     ni = pk.num_instance
-    with _tracing.span("prove.A", attrs=enqueue):
+    wide_l = None if wide is None else wide.tail(ni)
+    with _tracing.span("prove.A", attrs=over_z(wide)):
         a_pt = C1.add(
-            _msm(C1, pk.a_query, z_std), C1.encode([pk.vk.alpha_g1])[0]
+            _msm(C1, pk.a_query, z_std, wide=wide),
+            C1.encode([pk.vk.alpha_g1])[0],
         )
         if r % F.p != 0:
             a_pt = C1.add(a_pt, _maybe_mul(C1, pk.delta_g1, r))
-    with _tracing.span("prove.B", attrs=enqueue):
+    with _tracing.span("prove.B", attrs=over_z(wide)):
         b_pt = C2.add(
-            _msm(C2, pk.b_g2_query, z_std), C2.encode([pk.vk.beta_g2])[0]
+            _msm(C2, pk.b_g2_query, z_std, wide=wide),
+            C2.encode([pk.vk.beta_g2])[0],
         )
         if s % F.p != 0:
             b_pt = C2.add(
                 b_pt, _maybe_mul(C2, C2.encode([pk.vk.delta_g2])[0], s)
             )
-    with _tracing.span("prove.C", attrs=enqueue):
+    with _tracing.span("prove.C", attrs=over_z(wide_l)):
         c_pt = C1.add(
-            _msm(C1, pk.l_query, z_std[ni:]),
+            _msm(C1, pk.l_query, z_std[ni:], wide=wide_l),
             _msm(C1, pk.h_query, F.from_mont(h_vec)),
         )
         if zk:
@@ -310,7 +326,10 @@ def prove_single(
                 _maybe_mul(C1, a_pt, s),
                 _maybe_mul(
                     C1,
-                    C1.add(pk.beta_g1, _msm(C1, pk.b_g1_query, z_std)),
+                    C1.add(
+                        pk.beta_g1,
+                        _msm(C1, pk.b_g1_query, z_std, wide=wide),
+                    ),
                     r,
                 ),
             )

@@ -23,6 +23,7 @@ for BN254 Fq; the same machinery can host BLS12-381's base field.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -554,15 +555,33 @@ class LimbGroup:
             lambda p: self.double_body(p, self._consts(), unroll=False)
         )
 
+    # The group law on one (ROWS, tile) block, jitted: a kernel is traced
+    # anew for every lane count it is called at, though its block never
+    # changes, and with the body behind a jit that trace binds one cached
+    # call instead of running some 10^4 lines of field arithmetic again.
+    # Mosaic lowers the call inline; the kernel is the same.
+
+    @functools.cached_property
+    def _add_block(self):
+        def add_block(p, q, consts):
+            return self.add_body(p, q, consts, unroll=self._kmode())
+
+        return jax.jit(add_block)
+
+    @functools.cached_property
+    def _double_block(self):
+        def double_block(p, consts):
+            return self.double_body(p, consts, unroll=self._kmode())
+
+        return jax.jit(double_block)
+
     @functools.cached_property
     def _pallas_add(self):
         pl, pltpu = _pl()
         RR, T, CROWS = self.ROWS, self.tile, self.consts_np.shape[0]
 
         def kern(p_ref, q_ref, c_ref, o_ref):
-            o_ref[:] = self.add_body(
-                p_ref[:], q_ref[:], c_ref[:], unroll=self._kmode()
-            )
+            o_ref[:] = self._add_block(p_ref[:], q_ref[:], c_ref[:])
 
         @jax.jit
         def run(p, q):
@@ -591,9 +610,7 @@ class LimbGroup:
         RR, T, CROWS = self.ROWS, self.tile, self.consts_np.shape[0]
 
         def kern(p_ref, c_ref, o_ref):
-            o_ref[:] = self.double_body(
-                p_ref[:], c_ref[:], unroll=self._kmode()
-            )
+            o_ref[:] = self._double_block(p_ref[:], c_ref[:])
 
         @jax.jit
         def run(p):
@@ -623,13 +640,17 @@ class LimbGroup:
         shape = args[0].shape
         flat = [a.reshape(RR, -1) for a in args]
         n = flat[0].shape[1]
-        pallas = use_pallas()
-        granule = self.tile if pallas else 256
-        npad = max(granule, 1 << (n - 1).bit_length())
+        npad = self.lane_pad(n)
         if npad != n:
             flat = [jnp.pad(a, ((0, 0), (0, npad - n))) for a in flat]
-        out = (fn_pallas if pallas else fn_xla)(*flat)[:, :n]
+        out = (fn_pallas if use_pallas() else fn_xla)(*flat)[:, :n]
         return out.reshape(shape)
+
+    def lane_pad(self, n: int) -> int:
+        """The lane width `add` / `double` run n lanes at: the power of two
+        at or above n, and at least one Pallas tile (256 on the XLA path)."""
+        granule = self.tile if use_pallas() else 256
+        return max(granule, 1 << max(n - 1, 0).bit_length())
 
     def add(self, p, q):
         """Complete add on (ROWS, ...) limb-major batches."""
@@ -646,18 +667,29 @@ class LimbGroup:
 
     # -- window combine (Horner over c-bit windows), one fused kernel -------
 
-    def horner_body(self, getcol, consts, c: int, W: int, unroll=True):
-        """acc = sum_w 2^(c*w) * S_w; getcol(w) -> (ROWS, 1) window sum."""
+    def horner_body(self, getcol, consts, c: int, W: int, kernel: bool):
+        """acc = sum_w 2^(c*w) * S_w; getcol(w) -> (ROWS, 1) window sum.
+        `kernel` says whose body this is: the Pallas kernel's (its c
+        doublings and one add a step are calls of the jitted blocks) or
+        the XLA fallback's."""
         RR = self.ROWS
         acc0 = jnp.broadcast_to(getcol(W - 1), (RR, 128))
+
+        def double(a):
+            if kernel:
+                return self._double_block(a, consts)
+            return self.double_body(a, consts, unroll=False)
+
+        def add(a, b):
+            if kernel:
+                return self._add_block(a, b, consts)
+            return self.add_body(a, b, consts, unroll=False)
 
         def step(i, acc):
             w = W - 2 - i
             for _ in range(c):
-                acc = self.double_body(acc, consts, unroll)
-            return self.add_body(
-                acc, jnp.broadcast_to(getcol(w), (RR, 128)), consts, unroll
-            )
+                acc = double(acc)
+            return add(acc, jnp.broadcast_to(getcol(w), (RR, 128)))
 
         return jax.lax.fori_loop(0, W - 1, step, acc0)
 
@@ -668,7 +700,7 @@ class LimbGroup:
             return jax.jit(
                 lambda s: self.horner_body(
                     lambda w: jax.lax.dynamic_slice(s, (0, w), (RR, 1)),
-                    self._consts(), c, W, unroll=False,
+                    self._consts(), c, W, kernel=False,
                 )[:, :1]
             )
         pl, pltpu = _pl()
@@ -680,7 +712,7 @@ class LimbGroup:
             # the accumulator a lane-replicated layout that Mosaic cannot
             # carry through the fori_loop ("Invalid relayout").
             o_ref[:] = self.horner_body(
-                lambda w: s_ref[w], c_ref[:], c, W, unroll=self._kmode()
+                lambda w: s_ref[w], c_ref[:], c, W, kernel=True
             )
 
         @jax.jit
@@ -804,8 +836,66 @@ def _digits(scalars_std, c: int):
     return jnp.transpose(inter).astype(jnp.int32)  # (W, n)
 
 
+# A scalar is wide when a limb above limb 0 is set (value >= 2^16); limbs
+# 1..15 are what the limb-0 form of the tree carries beside the points.
+_UPPER_LIMBS = N_LIMBS - 1
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class WideScalars:
+    """What the host knows of an MSM's n scalars: which are wide (>= 2^16)
+    and the upper limbs of those. Made by `observe` from the integers
+    themselves (`ops/msm.py:encode_observed` makes it beside the device
+    encoding of the same list), never from a caller's say-so: the limb-0
+    tree drops the upper limbs of every scalar the view does not name."""
+
+    n: int
+    idx: np.ndarray  # (K,) int32, ascending: positions of the wide scalars
+    limbs: np.ndarray  # (K, 15) uint32: their standard-form limbs 1..15
+
+    @classmethod
+    def observe(cls, values) -> "WideScalars":
+        """values: n Python ints in [0, 2^256), the scalars as encoded."""
+        idx = [i for i, v in enumerate(values) if v >> LIMB_BITS]
+        limbs = [to_limbs(values[i] >> LIMB_BITS, _UPPER_LIMBS) for i in idx]
+        return cls(
+            len(values),
+            np.asarray(idx, np.int32),
+            np.asarray(limbs, np.uint32).reshape(len(idx), _UPPER_LIMBS),
+        )
+
+    @property
+    def count(self) -> int:
+        return int(self.idx.shape[0])
+
+    def tail(self, start: int) -> "WideScalars":
+        """The view of scalars[start:]."""
+        keep = self.idx >= start
+        return WideScalars(
+            self.n - start, self.idx[keep] - np.int32(start), self.limbs[keep]
+        )
+
+
+def _tree_npad(n: int) -> int:
+    return 1 << max(1, (n - 1).bit_length())
+
+
+def wide_capacity(g: "LimbGroup", n: int) -> int:
+    """How many wide scalars the limb-0 tree carries beside n points: each
+    takes 15 of the slots that padding n to its power of two leaves empty,
+    and the ladder that makes their points runs at one lane tile."""
+    return min((_tree_npad(n) - n) // _UPPER_LIMBS, g.tile)
+
+
+def takes_limb0(g: "LimbGroup", n: int, wide: WideScalars | None) -> bool:
+    """The rule of `msm_tree`'s window count: limb-0 windows when the host
+    has seen the scalars and their wide ones fit, all windows otherwise."""
+    return wide is not None and wide.count <= wide_capacity(g, n)
+
+
 def msm_tree(points_rm, scalars_std, c: int | None = None,
-             window_group: int | None = None, group: "LimbGroup" = None):
+             window_group: int | None = None, group: "LimbGroup" = None,
+             wide: WideScalars | None = None):
     """sum_i scalars[i] * points[i], limb-major TPU path (any LimbGroup).
 
     points_rm: (n, 3, nl) G1 / (n, 3, 2, nl) G2 projective row-major
@@ -826,13 +916,93 @@ def msm_tree(points_rm, scalars_std, c: int | None = None,
 
     The whole computation is one jitted program: per-dispatch host latency
     would otherwise dominate the ~30 narrow query/combine steps.
+
+    The window count follows the occupancy of the scalars, as arkworks'
+    MSM drops zero scalars and takes unit ones in its first window. Without
+    `wide` (device scalars nobody has seen) every window of the k limbs is
+    run. With `wide`, the host's view of the same scalars, and room for the
+    wide ones (`wide_capacity`), the tree runs over limb 0 alone, 16/c
+    windows in place of 16k/c: a witness of bits costs 2 windows, not 32.
+    Each wide scalar's limbs 1..15 ride as 15 more 16-bit scalars on the
+    points 2^(16j) P, which one doubling ladder makes
+    (`_msm_tree_jit_*_limb0_fill`) and which sit in the slots that padding
+    n to its power of two leaves empty: the same sum, exactly, from a
+    second launch of this program text at one shape per padded length.
+    Too many wide scalars, or no free slot, and the call runs all windows.
     """
+    n = points_rm.shape[0]
     if c is None:
         # the Fenwick/combine stages scale with B = 2^c per window: a small
         # MSM with c=8 would spend everything on 255 empty buckets
-        c = 8 if points_rm.shape[0] >= 4096 else 4
+        c = 8 if n >= 4096 else 4
     g = group or (lg2() if points_rm.ndim == 4 else lg1())
-    return _MSM_TREE_JITS[g.kind](g, points_rm, scalars_std, c, window_group)
+    if not takes_limb0(g, n, wide):
+        return _MSM_TREE_JITS[g.kind](
+            g, points_rm, scalars_std, c, window_group
+        )
+    assert wide.n == n == scalars_std.shape[0], (wide.n, n)
+    # The shapes below follow n alone, never the number of wide scalars:
+    # the view is padded to the capacity, and the ladder's trip count (the
+    # highest limb any wide scalar uses) is a device scalar.
+    cap = wide_capacity(g, n)
+    idx = np.zeros((cap,), np.int32)
+    limbs = np.zeros((cap, _UPPER_LIMBS), np.uint32)
+    idx[: wide.count] = wide.idx
+    limbs[: wide.count] = wide.limbs
+    used = np.flatnonzero(limbs.any(axis=0))
+    steps = np.int32(used[-1] + 1 if used.size else 0)
+    points_pad, limb0_pad = _MSM_LIMB0_FILL_JITS[g.kind](
+        g, points_rm, scalars_std, idx, limbs, steps
+    )
+    return _MSM_LIMB0_JITS[g.kind](g, points_pad, limb0_pad, c, window_group)
+
+
+def _limb0_fill(g: LimbGroup, points_rm, scalars_std, idx, limbs, steps):
+    """The limb-0 tree's inputs, padded to the power of two ahead of its
+    jit (so that MSMs of different lengths share one tree program): the n
+    points, then for each j < 15 the `cap` points 2^(16(j+1)) * points[idx]
+    with `limbs[:, j]` as their scalars, then infinity with scalar 0.
+    `steps` limbs of the ladder are run; the slots past it hold infinity
+    (their limbs are zero by the caller's count)."""
+    RR = g.ROWS
+    n = points_rm.shape[0]
+    cap = idx.shape[0]
+    rest = _tree_npad(n) - n - _UPPER_LIMBS * cap
+    inf_rm = jnp.asarray(g.inf_col)[:, 0].reshape(g.rm_shape)
+    parts_p = [points_rm]
+    parts_s = [scalars_std[:, :1]]
+    if cap:
+        with jax.named_scope("msm.wide"):
+            lanes = g.lane_pad(cap)
+            base = g.from_rowmajor(jnp.take(points_rm, idx, axis=0))
+            # lanes past cap are cut off again below, as in `_batched`
+            base = jnp.pad(base, ((0, 0), (0, lanes - cap)))
+
+            def limb(j, carry):
+                x, out = carry
+                x = jax.lax.fori_loop(
+                    0, LIMB_BITS, lambda _, y: g.double(y), x
+                )
+                return x, jax.lax.dynamic_update_slice(
+                    out, x[None], (j, 0, 0)
+                )
+
+            out0 = jnp.broadcast_to(
+                jnp.asarray(g.inf_col)[None], (_UPPER_LIMBS, RR, lanes)
+            )
+            _, out = jax.lax.fori_loop(0, steps, limb, (base, out0))
+            # (15, ROWS, cap) -> slot j*cap + k, row-major; the tree takes
+            # the ladder's [0, 2p) residues as it takes its own sums
+            parts_p.append(
+                jnp.transpose(out[:, :, :cap], (0, 2, 1)).reshape(
+                    (_UPPER_LIMBS * cap,) + g.rm_shape
+                )
+            )
+            parts_s.append(jnp.transpose(limbs).reshape(-1, 1))
+    if rest:
+        parts_p.append(jnp.broadcast_to(inf_rm, (rest,) + g.rm_shape))
+        parts_s.append(jnp.zeros((rest, 1), jnp.uint32))
+    return jnp.concatenate(parts_p, axis=0), jnp.concatenate(parts_s, axis=0)
 
 
 def _msm_tree(g: LimbGroup, points_rm, scalars_std, c: int,
@@ -845,7 +1015,7 @@ def _msm_tree(g: LimbGroup, points_rm, scalars_std, c: int,
     n = points_rm.shape[0]
     W_all = scalars_std.shape[1] * LIMB_BITS // c
     B = 1 << c
-    npad = 1 << max(1, (n - 1).bit_length())
+    npad = _tree_npad(n)
     with jax.named_scope("msm.sort"):  # its inputs: layout, padding, digits
         lm = g.from_rowmajor(points_rm)
         if npad != n:
@@ -960,6 +1130,22 @@ def _msm_tree(g: LimbGroup, points_rm, scalars_std, c: int,
 _MSM_TREE_JITS = {
     kind: named_jit(
         f"_msm_tree_jit_{kind}", _msm_tree, static_argnums=(0, 3, 4)
+    )
+    for kind in ("g1", "g2")
+}
+# The limb-0 form is the same body under a name of its own (a trace and
+# `jax_trace_seconds_total{fn}` tell its launches from a full-width one),
+# and the program that makes its padded inputs. Both names keep
+# `_msm_tree_jit_<kind>`, which is what the MSM's launches are counted by.
+_MSM_LIMB0_JITS = {
+    kind: named_jit(
+        f"_msm_tree_jit_{kind}_limb0", _msm_tree, static_argnums=(0, 3, 4)
+    )
+    for kind in ("g1", "g2")
+}
+_MSM_LIMB0_FILL_JITS = {
+    kind: named_jit(
+        f"_msm_tree_jit_{kind}_limb0_fill", _limb0_fill, static_argnums=(0,)
     )
     for kind in ("g1", "g2")
 }

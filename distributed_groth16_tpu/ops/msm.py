@@ -3,9 +3,16 @@
 Computes sum_i s_i * P_i — the dominant kernel of the Groth16 prover (the
 reference's per-party hot loop is arkworks `G::msm` at
 dist-primitives/src/dmsm/mod.rs:82, called five times per proof:
-S*a, V*a, W*ax, U*h, H*a — groth16/src/prove.rs).
+S*a, V*a, W*ax, U*h, H*a — groth16/src/prove.rs; the served single-node
+prover runs four, `models/groth16/prove.py:prove_single`).
 
-TPU-first design — no scatter, no data-dependent control flow:
+TPU-first design — no scatter, no data-dependent control flow on the
+device. One choice is made on the host, from data the host already holds:
+a caller that has the scalars as integers (`encode_observed`) hands `msm`
+their `WideScalars` view, and the tree path then runs limb-0 windows only
+(`limb_kernels.msm_tree`); which of two static programs runs depends on
+how many scalars are wider than 16 bits, as in arkworks' own MSM. Nothing
+else here looks at a value.
 
   * windowed digits: each 254-bit scalar is split into W = 256/c digits of
     c bits (c | 16 so digits never straddle the uint16 limbs of ops/field.py).
@@ -34,6 +41,7 @@ import jax.numpy as jnp
 from ..telemetry import metrics as _tm
 from .constants import LIMB_BITS, N_LIMBS
 from .curve import CurvePoints, g1, g2
+from .limb_kernels import WideScalars
 
 # total scalar bits covered (BN254 Fr fits in 254 < 256)
 _SCALAR_BITS = 256
@@ -51,12 +59,18 @@ _ROUTE = _tm.registry().counter(
 # pre-bound children (the metrics.py hot-path contract: one dict lookup
 # + add per record, no per-call label-tuple allocation)
 _R_TREE = _ROUTE.labels(kernel="msm", path="tree")
+_R_TREE_LIMB0 = _ROUTE.labels(kernel="msm", path="tree_limb0")
 _R_LADDER = _ROUTE.labels(kernel="msm", path="ladder")
 _R_PIPPENGER = _ROUTE.labels(kernel="msm", path="pippenger")
 _R_CHUNKED = _ROUTE.labels(kernel="msm", path="pippenger_chunked")
 _RB_TREE = _ROUTE.labels(kernel="msm_batched", path="tree")
 _RB_LADDER = _ROUTE.labels(kernel="msm_batched", path="ladder")
 _RB_VMAP = _ROUTE.labels(kernel="msm_batched", path="pippenger_vmap")
+_WIDE_CARRIED = _tm.registry().counter(
+    "msm_wide_scalars_total",
+    "Scalars wider than 16 bits that limb-0 tree MSMs carried beside "
+    "their points (15 ladder points each), summed over launches",
+)
 
 
 def _digits_for_window(scalars, w, c: int):
@@ -164,7 +178,7 @@ def _tree_group(curve: CurvePoints, n: int):
 
 
 def msm(curve: CurvePoints, points, scalars, window_bits: int | None = None,
-        chunk: int | None = None):
+        chunk: int | None = None, wide: WideScalars | None = None):
     """sum_i scalars[i] * points[i].
 
     points:  (n, 3) + elem_shape projective device points.
@@ -172,6 +186,13 @@ def msm(curve: CurvePoints, points, scalars, window_bits: int | None = None,
     window_bits: Pippenger window c (must divide 16); default auto.
     chunk: process points in chunks of this size (bounds peak memory; MSM is
            linear so chunk results just add).
+    wide: the host's view of these same scalars, from `encode_observed`
+          (`.tail(k)` for scalars[k:]). Only the tree path reads it: with
+          it, and room for the wide scalars, the MSM runs the limb-0
+          windows alone (route `msm/tree_limb0`); without it, as for
+          every scalar array that lives on the device only (h, the MPC
+          round's shares), all windows (route `msm/tree`). The result is
+          the same point either way.
 
     Returns a single projective point (3,) + elem_shape.
     """
@@ -188,8 +209,12 @@ def msm(curve: CurvePoints, points, scalars, window_bits: int | None = None,
         else None
     )
     if tree_g is not None:
-        from .limb_kernels import msm_tree
+        from .limb_kernels import msm_tree, takes_limb0
 
+        if takes_limb0(tree_g, n, wide):
+            _R_TREE_LIMB0.inc()
+            _WIDE_CARRIED.inc(wide.count)
+            return msm_tree(points, scalars, group=tree_g, wide=wide)
         _R_TREE.inc()
         return msm_tree(points, scalars, group=tree_g)
     if window_bits is None and chunk is None and n <= _LADDER_MSM_MAX_N:
@@ -250,6 +275,14 @@ def msm_g1(points, scalars, **kw):
 
 def msm_g2(points, scalars, **kw):
     return msm(g2(), points, scalars, **kw)
+
+
+def encode_observed(F, values) -> tuple[jnp.ndarray, WideScalars]:
+    """`F.encode(values)` and, from the same reduced integers, the view
+    of them that `msm(..., wide=)` takes: the one place the two are made,
+    so that a view cannot disagree with the scalars it describes."""
+    reduced = [int(v) % F.p for v in values]
+    return F.encode(reduced), WideScalars.observe(reduced)
 
 
 def encode_scalars_std(values) -> jnp.ndarray:

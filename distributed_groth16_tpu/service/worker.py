@@ -30,6 +30,7 @@ from ..models.groth16 import (
 )
 from ..models.groth16.prove import prove_single
 from ..ops.field import fr
+from ..ops.msm import encode_observed
 from ..parallel.net import job_context, run_round_with_retries
 from ..parallel.pss import PackedSharingParams
 from ..telemetry import aggregate, devmem, logbus, tracing, transfer
@@ -185,15 +186,17 @@ class ProofExecutor:
             # the witness-upload boundary: F.encode materializes the
             # (wires, 16) Montgomery limb tensor on device from host
             # bigints. The span is the clock; `account` counts the bytes.
+            # The same integers say which wires are wider than 16 bits,
+            # which the single-node prover's MSMs over z go by.
             with transfer.account("h2d") as t:
-                z_mont = fr().encode(z)
+                z_mont, z_wide = encode_observed(fr(), z)
                 t.add_tree(z_mont)
         if job.kind == "prove":
             job.note_phase("prove")
             with phase("prove", timings):
                 with phase("prove.r1cs", timings):
                     comp = CompiledR1CS(r1cs)
-                proof = prove_single(pk, comp, z_mont)
+                proof = prove_single(pk, comp, z_mont, wide=z_wide)
                 # the parsed circuit is some 10^5 Python objects and takes
                 # tens of ms to free: here, inside the phase that used it,
                 # not at return, where no phase would own the time

@@ -3,6 +3,7 @@ the host oracle and the pairing check — the reference's test ladder
 (qap.rs tests, ext_wit.rs:103-191, sha256.rs:228-254) on a native circuit."""
 
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from distributed_groth16_tpu.frontend.r1cs import mult_chain_circuit
@@ -191,3 +192,96 @@ def test_strip_clears_trapdoor_scalars(world):
     again = pack_proving_key(pk, pp)
     assert len(again) == len(shares) == pp.n
     assert pk.strip() is pk  # idempotent, chains
+
+
+def _bits_circuit(nbits: int = 87):
+    """A circuit shaped like the served SHA-256 one: every witness wire a
+    bit, and two public wires that pack the bits into wide values (the
+    digest halves there). 3 + nbits wires; the A query's 90 and the L
+    query's 87 both pad to 128, with room for two wide scalars."""
+    from distributed_groth16_tpu.frontend.r1cs import ConstraintSystem
+
+    rng = np.random.default_rng(24)
+    bits = [int(b) for b in rng.integers(0, 2, size=nbits)]
+    half = nbits // 2
+    weigh = lambda part: sum(b << (90 + i) for i, b in enumerate(part))
+    cs = ConstraintSystem()
+    lo = cs.new_instance(weigh(bits[:half]))
+    hi = cs.new_instance(weigh(bits[half:]))
+    wires = [cs.new_witness(b) for b in bits]
+    for w in wires:
+        cs.enforce([(1, w)], [(1, w)], [(1, w)])
+    for out, part in ((lo, wires[:half]), (hi, wires[half:])):
+        cs.enforce(
+            [(1 << (90 + i), w) for i, w in enumerate(part)],
+            [(1, cs.ONE)],
+            [(1, out)],
+        )
+    return cs.finish()
+
+
+def test_prove_single_follows_the_witness_occupancy(monkeypatch):
+    """`prove_single` with the host's view of a witness of bits and two
+    wide public wires takes the limb-0 route for its G1 MSMs over z (A and
+    L, one tree program between them) and the full route for h; on values
+    that fill the field, the full route for all. Either way the proof is
+    the reference prover's. The G1 MSMs are steered onto the tree path
+    (the TPU's) here; G2 stays on the CPU's generic one, whose tree
+    compiles for minutes (tests/test_limb_kernels.py has its limb-0 form)."""
+    from distributed_groth16_tpu.models.groth16.prove import prove_single
+    from distributed_groth16_tpu.ops import limb_kernels as lk
+    from distributed_groth16_tpu.ops import msm as msm_mod
+    from distributed_groth16_tpu.telemetry import metrics, tracing
+
+    monkeypatch.setattr(
+        msm_mod, "_tree_group",
+        lambda curve, n: lk.lg1() if len(curve.elem_shape) == 1 else None,
+    )
+    routes = metrics.registry().family("kernel_route_total")
+
+    def moved(fn):
+        before = {k: c.value for k, c in routes.items()}
+        out = fn()
+        return out, {
+            k[1]: c.value - before.get(k, 0)
+            for k, c in routes.items()
+            if k[0] == "msm" and c.value != before.get(k, 0)
+        }
+
+    r1cs, z = _bits_circuit()
+    assert sorted(i for i, v in enumerate(z) if v >> 16) == [1, 2]
+    pk = setup(r1cs)
+    comp = CompiledR1CS(r1cs)
+    z_mont, view = msm_mod.encode_observed(fr(), z)
+    limb0_programs = lk._MSM_LIMB0_JITS["g1"]._cache_size()
+    buf = tracing.TraceBuffer()
+    with tracing.collect(buf):
+        proof, took = moved(lambda: prove_single(pk, comp, z_mont, wide=view))
+    assert proof == prove_host(pk, r1cs, z)
+    assert verify(pk.vk, proof, z[1 : r1cs.num_instance])
+    assert took == {"tree_limb0": 2, "tree": 1, "ladder": 1}
+    assert lk._MSM_LIMB0_JITS["g1"]._cache_size() == limb0_programs + 1
+    told = {
+        e["name"]: e["args"].get("wide_scalars")
+        for e in buf.events() if e["name"].startswith("prove.")
+    }
+    assert (told["prove.A"], told["prove.B"], told["prove.C"]) == (2, 2, 0)
+    assert told["prove.h"] is None
+
+    # no view: the parent's call
+    proof, took = moved(lambda: prove_single(pk, comp, z_mont))
+    assert proof == prove_host(pk, r1cs, z)
+    assert took == {"tree": 3, "ladder": 1}
+
+    # field-filling values (they need not satisfy the circuit for the two
+    # provers to agree): every wire is wide, nothing fits, all windows run
+    rng = np.random.default_rng(25)
+    zf = [1] + [
+        int.from_bytes(rng.bytes(40), "little") % fr().p
+        for _ in range(len(z) - 1)
+    ]
+    zf_mont, viewf = msm_mod.encode_observed(fr(), zf)
+    assert viewf.count == len(z) - 1
+    proof, took = moved(lambda: prove_single(pk, comp, zf_mont, wide=viewf))
+    assert proof == prove_host(pk, r1cs, zf)
+    assert took == {"tree": 3, "ladder": 1}

@@ -2,6 +2,8 @@
 implementations. On CPU these exercise the exact jnp bodies the Pallas TPU
 kernels compile; the math is identical on both backends."""
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -244,9 +246,158 @@ def test_msm_routing_forced_g2(monkeypatch):
     monkeypatch.setenv("DG16_FORCE_TREE_MSM", "1")
     C = g2()
     rng = np.random.default_rng(10)
-    n = 16
+    n = 37  # the length test_msm_tree_g2_matches_reference compiled
     ks = [int(x) for x in rng.integers(1, 2**50, size=n)]
     pts = [rm.G2.scalar_mul(G2_GENERATOR, k) for k in ks]
     scs = [int.from_bytes(rng.bytes(40), "little") % R for _ in range(n)]
     got = C.decode(msm(C, C.encode(pts), encode_scalars_std(scs))[None])[0]
     assert got == rm.G2.msm(pts, scs)
+
+
+# ---------------------------------------------------------------------------
+# The limb-0 form of the tree MSM: the window count follows the scalars'
+# occupancy when the caller hands over the host's view of them
+# ---------------------------------------------------------------------------
+
+
+def _limb0_group(name):
+    """(curve, limb group, host curve, generator, scalar order) of a case."""
+    if name == "g1":
+        return g1(), lg1(), rm.G1, G1_GENERATOR, R
+    if name == "g2":
+        from distributed_groth16_tpu.ops.constants import G2_GENERATOR
+        from distributed_groth16_tpu.ops.curve import g2
+        from distributed_groth16_tpu.ops.limb_kernels import lg2
+
+        return g2(), lg2(), rm.G2, G2_GENERATOR, R
+    from distributed_groth16_tpu.ops import bls12_381 as b
+    from distributed_groth16_tpu.ops.limb_kernels import lg1_381
+
+    return b.g1_381(), lg1_381(), b.G1_HOST, b.g1_generator_381(), b.R381
+
+
+@functools.cache
+def _limb0_points(name, n):
+    C, _, host, gen, _ = _limb0_group(name)
+    rng = np.random.default_rng(24)
+    ks = [int(x) for x in rng.integers(1, 2**61, size=n)]
+    pts = [host.scalar_mul(gen, k) for k in ks]
+    return pts, C.encode(pts)
+
+
+# (group, n, wide values at which positions, position of a point at
+# infinity or None, whether the limb-0 form is expected). n = 300 pads to
+# 512 and leaves room for 14 wide scalars; 37 pads to 64, room for 1; 17
+# pads to 32, room for 1; 512 leaves no slot. The full-width programs of
+# n = 300 (G1) and n = 37 (G2) are the ones the tests above compiled. The
+# G2 case that takes the limb-0 form is in tests/test_msm.py: its programs
+# compile for minutes on the CPU, and that file runs on another worker.
+_WIDE_128 = (1 << 128) - 12345
+_LIMB0_CASES = {
+    "g1-bits-only": ("g1", 300, {}, None, True),
+    "g1-one-wide": ("g1", 300, {1: _WIDE_128}, None, True),
+    "g1-two-wide-as-sha256": ("g1", 300, {1: _WIDE_128, 2: 1 << 16}, None, True),
+    "g1-most-that-fits": (
+        "g1", 300, {7 * i + 3: R - 1 - i for i in range(14)}, None, True),
+    "g1-one-past-what-fits": (
+        "g1", 300, {7 * i + 3: R - 1 - i for i in range(15)}, None, False),
+    "g1-infinity-among-the-wide": (
+        "g1", 300, {5: _WIDE_128, 299: R - 2}, 5, True),
+    "g1-power-of-two-no-slot": ("g1", 512, {}, None, True),
+    "g2-one-past-what-fits": ("g2", 37, {0: 1 << 200, 36: R - 3}, None, False),
+    "bls12-381-g1": ("381", 17, {2: (1 << 254) + 9}, None, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LIMB0_CASES))
+def test_msm_tree_limb0_matches_full_width_and_reference(case):
+    from distributed_groth16_tpu.ops import limb_kernels as lk
+    from distributed_groth16_tpu.ops.scalar_pack import encode_scalars
+
+    name, n, wide_at, inf_at, expect_limb0 = _LIMB0_CASES[case]
+    C, g, host, _, order = _limb0_group(name)
+    pts, P = _limb0_points(name, n)
+    if inf_at is not None:
+        pts = list(pts)
+        pts[inf_at] = None
+        P = C.encode(pts)
+    rng = np.random.default_rng(len(case))
+    vals = [int(b) for b in rng.integers(0, 2, size=n)]
+    for i, v in wide_at.items():
+        vals[i] = v % order
+    sc = encode_scalars(vals, order)
+    view = lk.WideScalars.observe(vals)
+    assert view.count == len(wide_at)
+    assert lk.takes_limb0(g, n, view) is expect_limb0
+
+    full_jit = lk._MSM_TREE_JITS[g.kind]
+    limb0_jit = lk._MSM_LIMB0_JITS[g.kind]
+    before = full_jit._cache_size(), limb0_jit._cache_size()
+    got = C.decode(msm_tree(P, sc, group=g, wide=view)[None])[0]
+    after = full_jit._cache_size(), limb0_jit._cache_size()
+    if expect_limb0:
+        assert after[0] == before[0], "the limb-0 form ran a full program"
+    else:
+        assert after[1] == before[1], "the fallback ran a limb-0 program"
+    assert got == host.msm(pts, vals)
+    if (name, n) in (("g1", 300), ("g2", 37)):
+        assert got == C.decode(msm_tree(P, sc, group=g)[None])[0]
+
+
+def _route_counts():
+    from distributed_groth16_tpu.telemetry import metrics
+
+    fam = metrics.registry().family("kernel_route_total")
+    out = {path: child.value for (kernel, path), child in fam.items()
+           if kernel == "msm"}
+    wide = metrics.registry().family("msm_wide_scalars_total")
+    out["wide"] = sum(child.value for _, child in wide.items())
+    return out
+
+
+def test_msm_routes_by_the_hosts_view_and_counts_it(monkeypatch):
+    """`msm` without a view is the parent's call: the full-width program's
+    one cache entry for the shape, counted `msm/tree`. With the view of the
+    same scalars it takes `msm/tree_limb0` and counts the wide scalars it
+    carried; with a view that does not fit, `msm/tree` again."""
+    from distributed_groth16_tpu.ops import limb_kernels as lk
+    from distributed_groth16_tpu.ops.msm import encode_observed
+    from distributed_groth16_tpu.ops.field import fr
+
+    monkeypatch.setenv("DG16_FORCE_TREE_MSM", "1")
+    C, n = g1(), 300
+    pts, P = _limb0_points("g1", n)
+    rng = np.random.default_rng(3)
+    vals = [int(b) for b in rng.integers(0, 2, size=n)]
+    vals[1], vals[2] = _WIDE_128, R - 5
+    z_mont, view = encode_observed(fr(), vals)
+    sc = fr().from_mont(z_mont)
+    assert view.n == n and list(view.idx) == [1, 2]
+    assert view.tail(2).n == n - 2 and list(view.tail(2).idx) == [0]
+    want = rm.G1.msm(pts, vals)
+    full_jit = lk._MSM_TREE_JITS["g1"]
+    limb0_jit = lk._MSM_LIMB0_JITS["g1"]
+
+    c0 = _route_counts()
+    assert C.decode(msm(C, P, sc)[None])[0] == want
+    size = full_jit._cache_size(), limb0_jit._cache_size()
+    assert C.decode(msm(C, P, sc)[None])[0] == want
+    assert (full_jit._cache_size(), limb0_jit._cache_size()) == size
+    c1 = _route_counts()
+    assert c1["tree"] - c0["tree"] == 2
+    assert c1["tree_limb0"] == c0["tree_limb0"]
+    assert c1["wide"] == c0["wide"]
+
+    assert C.decode(msm(C, P, sc, wide=view)[None])[0] == want
+    c2 = _route_counts()
+    assert c2["tree_limb0"] - c1["tree_limb0"] == 1
+    assert c2["tree"] == c1["tree"] and c2["wide"] - c1["wide"] == 2
+    assert full_jit._cache_size() == size[0]
+
+    many = lk.WideScalars.observe([1 << 20] * n)
+    assert not lk.takes_limb0(lk.lg1(), n, many)
+    got = msm(C, P, encode_scalars_std([1 << 20] * n), wide=many)
+    assert C.decode(got[None])[0] == rm.G1.msm(pts, [1 << 20] * n)
+    c3 = _route_counts()
+    assert c3["tree"] - c2["tree"] == 1
+    assert c3["tree_limb0"] == c2["tree_limb0"] and c3["wide"] == c2["wide"]

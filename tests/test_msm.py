@@ -126,3 +126,33 @@ print("BATCHED_OK")
     )
     assert r.returncode == 0, r.stderr[-2000:]
     assert "BATCHED_OK" in r.stdout
+
+
+def test_msm_g2_limb0_windows_match_reference(monkeypatch):
+    """The G2 case of tests/test_limb_kernels.py's limb-0 cases (here so
+    that its minutes of XLA:CPU compile run beside that file's, not after
+    them): bits and one wide scalar, the most that fits beside 37 points,
+    through `msm` on the tree path."""
+    from distributed_groth16_tpu.ops import limb_kernels as lk
+    from distributed_groth16_tpu.ops.field import fr
+    from distributed_groth16_tpu.ops.msm import encode_observed
+    from distributed_groth16_tpu.telemetry import metrics
+
+    monkeypatch.setenv("DG16_FORCE_TREE_MSM", "1")
+    rng = random.Random(24)
+    n = 37
+    pts = [rm.G2.scalar_mul(G2_GENERATOR, rng.randrange(1, 2**61))
+           for _ in range(n)]
+    vals = [rng.randrange(2) for _ in range(n)]
+    vals[36] = R - 3
+    z_mont, view = encode_observed(fr(), vals)
+    assert view.count == lk.wide_capacity(lk.lg2(), n) == 1
+    routes = metrics.registry().family("kernel_route_total")
+    limb0 = routes.labels(kernel="msm", path="tree_limb0")
+    before = limb0.value
+    full_programs = lk._MSM_TREE_JITS["g2"]._cache_size()
+    C = g2()
+    out = msm(C, C.encode(pts), fr().from_mont(z_mont), wide=view)
+    assert C.decode(out) == rm.G2.msm(pts, vals)
+    assert limb0.value == before + 1
+    assert lk._MSM_TREE_JITS["g2"]._cache_size() == full_programs
