@@ -719,6 +719,7 @@ class ApiServer:
             {
                 "queue": self.queue.stats(),
                 "crsCache": self.crs_cache.stats(),
+                "circuitCache": self.executor.circuit_cache.stats(),
                 "verifierCache": self.executor.verifier.pvk_cache.stats(),
                 "journal": (
                     self.journal.stats()

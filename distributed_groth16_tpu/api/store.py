@@ -69,14 +69,41 @@ class CircuitStore:
         pk.save(os.path.join(d, "proving_key.npz"))
         return circuit_id
 
-    def _latest(self, circuit_id: str, ext: str) -> str:
+    def _latest_stat(
+        self, circuit_id: str, ext: str
+    ) -> tuple[str, os.stat_result]:
         d = self._dir(circuit_id)
         cands = [
-            os.path.join(d, f) for f in os.listdir(d) if f.endswith(ext)
+            (p, os.stat(p))
+            for p in (os.path.join(d, f) for f in os.listdir(d))
+            if p.endswith(ext)
         ]
         if not cands:
             raise FileNotFoundError(f"no {ext} in {circuit_id}")
-        return max(cands, key=os.path.getmtime)
+        return max(cands, key=lambda c: c[1].st_mtime)
+
+    def _latest(self, circuit_id: str, ext: str) -> str:
+        return self._latest_stat(circuit_id, ext)[0]
+
+    def _key_path(self, circuit_id: str) -> str:
+        return os.path.join(self._dir(circuit_id), "proving_key.npz")
+
+    def identity(
+        self, circuit_id: str, timings: PhaseTimings | None = None
+    ) -> tuple:
+        """Which files `load` would read now, and in what state: the path
+        of the latest `.r1cs`, and for it and the proving key
+        `st_mtime_ns` and `st_size`. One `listdir` and a `stat` a file,
+        so that what is kept of an earlier `load` can be held to it on
+        every use (a file can be put into the directory by hand). Timed
+        under `load`'s own phase names: for a circuit that is resident
+        they are all that these phases hold. Raises `FileNotFoundError`
+        as `load` does."""
+        with phase("load.r1cs", timings):
+            path, st = self._latest_stat(circuit_id, ".r1cs")
+        with phase("load.key", timings):
+            ks = os.stat(self._key_path(circuit_id))
+        return (path, st.st_mtime_ns, st.st_size, ks.st_mtime_ns, ks.st_size)
 
     def load(
         self, circuit_id: str, timings: PhaseTimings | None = None
@@ -88,9 +115,7 @@ class CircuitStore:
         with phase("load.r1cs", timings):
             r1cs, _ = read_r1cs(self._latest(circuit_id, ".r1cs"))
         with phase("load.key", timings):
-            pk = ProvingKey.load(
-                os.path.join(self._dir(circuit_id), "proving_key.npz")
-            )
+            pk = ProvingKey.load(self._key_path(circuit_id))
         return r1cs, pk
 
     def get_files(self, circuit_id: str) -> tuple[bytes, bytes]:

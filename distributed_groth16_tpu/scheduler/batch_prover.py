@@ -186,8 +186,8 @@ class BatchProver:
         ):
             outcomes: list[tuple] = []
             t0 = time.monotonic()
-            r1cs, pk = self.executor.store.load(key.circuit_id)
-            comp = CompiledR1CS(r1cs)
+            circ = self.executor.circuit(key.circuit_id)
+            r1cs, pk, comp = circ.r1cs, circ.pk, circ.comp
             load_s = time.monotonic() - t0
 
             F = fr()

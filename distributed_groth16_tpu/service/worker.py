@@ -18,8 +18,12 @@ from __future__ import annotations
 
 import asyncio
 import logging
+from dataclasses import dataclass
+
+import jax
 
 from ..frontend.ark_serde import proof_to_bytes
+from ..frontend.r1cs import R1CS
 from ..frontend.readers import read_wtns
 from ..models.groth16 import (
     CompiledR1CS,
@@ -28,12 +32,14 @@ from ..models.groth16 import (
     pack_proving_key,
     reassemble_proof,
 )
+from ..models.groth16.keys import ProvingKey
 from ..models.groth16.prove import prove_single
 from ..ops.field import fr
 from ..ops.msm import encode_observed
 from ..parallel.net import job_context, run_round_with_retries
 from ..parallel.pss import PackedSharingParams
 from ..telemetry import aggregate, devmem, logbus, tracing, transfer
+from ..telemetry import metrics as _tm
 from ..utils.config import ServiceConfig
 from ..utils.timers import phase
 from ..verifier.executor import VerifyExecutor
@@ -42,6 +48,56 @@ from .jobs import JobCancelled, JobState, ProofJob
 from .queue import JobQueue
 
 log = logging.getLogger(__name__)
+
+# The share of one chip's memory that resident circuits may hold. The
+# rest is the provers' working set, the packed-CRS cache and XLA's own.
+RESIDENT_SHARE = 0.25
+
+
+@dataclass(frozen=True)
+class ResidentCircuit:
+    """What a proof needs that depends on its circuit alone, built once
+    and shared by the workers: read by every job on the circuit, written
+    by none (nothing on the proving path donates a buffer or assigns to
+    a key; `pack_proving_key(strip=True)` clears only the dealer scalars,
+    which a key read from disk does not carry)."""
+
+    r1cs: R1CS  # `witness.check` runs `is_satisfied` on it
+    comp: CompiledR1CS  # A and B on the device
+    pk: ProvingKey  # its seven arrays on the device
+
+    def device_bytes(self) -> int:
+        held = [
+            v
+            for obj in (self.pk, self.comp.A, self.comp.B)
+            for v in vars(obj).values()
+        ]
+        return sum(v.nbytes for v in held if isinstance(v, jax.Array))
+
+
+_REG = _tm.registry()
+_CIRCUIT_COUNTERS = (
+    _REG.counter(
+        "circuit_cache_hits_total",
+        "Jobs that found their circuit resident (parsed circuit, compiled "
+        "matrices, device-resident proving key)",
+    ),
+    _REG.counter(
+        "circuit_cache_misses_total",
+        "Jobs that read their circuit from disk; an entry replaced because "
+        "its files changed counts here, not as an eviction",
+    ),
+    _REG.counter(
+        "circuit_cache_evictions_total",
+        "Resident circuits turned out, oldest first, by count or by the "
+        "device bytes they hold",
+    ),
+)
+
+
+def _resident_budget() -> int | None:
+    limit = devmem.limit_bytes()
+    return None if limit is None else int(RESIDENT_SHARE * limit)
 
 
 class ProofExecutor:
@@ -63,11 +119,37 @@ class ProofExecutor:
             if crs_cache is not None
             else CrsCache(self.cfg.crs_cache_size)
         )
+        # resident circuits: the same class and the same count as the
+        # packed-CRS cache, bounded besides by the device bytes they hold
+        self.circuit_cache = CrsCache(
+            self.cfg.crs_cache_size,
+            counters=_CIRCUIT_COUNTERS,
+            weigh=ResidentCircuit.device_bytes,
+            budget=_resident_budget,
+        )
         # the verification plane's executor (verifier/executor.py): owns
         # the PreparedVerifyingKey cache the same way this executor owns
         # the packed-CRS cache, sized by the same knob
         self.verifier = VerifyExecutor(store)
         self.verifier.pvk_cache.capacity = self.cfg.crs_cache_size
+
+    # -- circuit -------------------------------------------------------------
+
+    def circuit(self, circuit_id: str, timings=None) -> ResidentCircuit:
+        """The circuit ready to prove with: the one way to get it, for
+        this executor's jobs and the batch prover's. A resident entry is
+        served only while the files it was read from are what
+        `CircuitStore.load` would read now; else `load` is its factory,
+        so a miss costs what every job used to."""
+        def build():
+            r1cs, pk = self.store.load(circuit_id, timings)
+            return ResidentCircuit(r1cs, CompiledR1CS(r1cs), pk)
+
+        return self.circuit_cache.get_or_pack(
+            circuit_id,
+            build,
+            version=self.store.identity(circuit_id, timings),
+        )
 
     # -- witness -------------------------------------------------------------
 
@@ -175,7 +257,10 @@ class ProofExecutor:
         timings = job.timings
         job.note_phase("load")
         with phase("load", timings):
-            r1cs, pk = self.store.load(job.circuit_id, timings)
+            # near nothing for a resident circuit; a worker that waits
+            # for another to build the entry waits in here
+            circ = self.circuit(job.circuit_id, timings)
+            r1cs, pk = circ.r1cs, circ.pk
         job.check_cancel()
         job.note_phase("witness")
         with phase("witness", timings):
@@ -194,19 +279,16 @@ class ProofExecutor:
         if job.kind == "prove":
             job.note_phase("prove")
             with phase("prove", timings):
+                # the phase keeps its place in the account; the matrices
+                # it used to build come with the circuit, in `load`
                 with phase("prove.r1cs", timings):
-                    comp = CompiledR1CS(r1cs)
+                    comp = circ.comp
                 proof = prove_single(pk, comp, z_mont, wide=z_wide)
-                # the parsed circuit is some 10^5 Python objects and takes
-                # tens of ms to free: here, inside the phase that used it,
-                # not at return, where no phase would own the time
-                del comp, r1cs
         elif job.kind == "mpc_prove":
             pp = PackedSharingParams(job.l)
             job.note_phase("packing")
             with phase("packing", timings):
-                comp = CompiledR1CS(r1cs)
-                qap_shares = comp.qap(z_mont).pss(pp)
+                qap_shares = circ.comp.qap(z_mont).pss(pp)
                 crs_shares = self.packed_crs(job, pk, pp)
                 ni = r1cs.num_instance
                 a_sh = pack_from_witness(pp, z_mont[1:])
@@ -241,7 +323,6 @@ class ProofExecutor:
                 # the host's wait for the round's device work: decoding
                 # adds no span, so the critical-path window is unchanged
                 proof = reassemble_proof(res[0], pk)
-                del comp, r1cs  # as in `prove`: freed inside a phase
         else:
             raise ValueError(f"unknown job kind {job.kind!r}")
         job.check_cancel()

@@ -12,6 +12,8 @@ memory API everything else goes through:
   * `snapshot()` — the same read as a JSON-able document, never raising:
     attached to every flight-recorder post-mortem so an OOM post-mortem
     carries the HBM state, and to bench.py's JSON line.
+  * `limit_bytes()` — the least `bytes_limit` of any device; the
+    service's resident circuits are bounded by a share of it.
   * `peak_bytes()` — summed `peak_bytes_in_use`; the executor and batch
     prover bracket a job with it and stamp the peak DELTA into the
     ProofJob DTO (`metrics.deviceMemory`).
@@ -108,6 +110,20 @@ def peak_bytes(devices=None) -> int | None:
         if v is not None:
             total = (total or 0) + int(v)
     return total
+
+
+def limit_bytes(devices=None) -> int | None:
+    """The least `bytes_limit` any device reports: what one chip's
+    allocator will hand out. None when no backend reports it (the CPU
+    answer), and then nothing may be sized by it."""
+    limits = [
+        int(stats["bytes_limit"])
+        for stats in map(
+            _stats_of, devices if devices is not None else _devices()
+        )
+        if stats and stats.get("bytes_limit") is not None
+    ]
+    return min(limits) if limits else None
 
 
 def peak_delta(before: int | None, after: int | None) -> dict | None:

@@ -211,7 +211,8 @@ class ServiceConfig:
         are rejected with a structured queue-full error that the API maps
         to HTTP 429 + a retryAfter hint.
       * crs_cache_size — LRU capacity (entries) of the packed-CRS cache,
-        keyed by (circuit_id, packing params). 0 disables caching.
+        keyed by (circuit_id, packing params), and of the resident-circuit
+        and PVK caches, keyed by circuit id. 0 disables caching.
       * round_retries — transient-fault re-runs per MPC round, forwarded
         to parallel.net.run_round_with_retries.
       * retry_after_s — fallback retryAfter hint (seconds) reported on

@@ -487,6 +487,11 @@ def test_eight_jobs_complete_in_at_most_two_batched_executions(circuit):
             assert sched["enabled"] and sched["batchesDispatched"] <= 10
             assert sched["jobsBatched"] == 16
             assert stats["queue"]["completed"] == 16
+            # each batch took its circuit from the executor's resident
+            # entries: read from disk once, whatever the batches
+            resident = stats["circuitCache"]
+            assert resident["misses"] == 1
+            assert resident["hits"] == len(runs) - 1
 
             # the batch-size histogram is live on /metrics
             resp = await client.get("/metrics")
