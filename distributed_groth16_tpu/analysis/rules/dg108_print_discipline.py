@@ -11,7 +11,7 @@ Allowed:
   * CLI surfaces — modules named ``cli.py`` / ``__main__.py``, where
     stdout IS the product;
   * code lexically inside a function named ``main`` (the argparse entry
-    points of benchgate.py, certs.py, ...);
+    points of certs.py, server.py, ...);
   * deliberate stdout emitters carrying ``# dg16lint: disable=DG108``.
 """
 

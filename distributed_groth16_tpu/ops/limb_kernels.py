@@ -1151,28 +1151,6 @@ _MSM_LIMB0_FILL_JITS = {
 }
 
 
-class _MsmTreeJit:
-    """`_msm_tree_jit(g, points_rm, scalars_std, c, window_group)`: the
-    jitted tree MSM of `g`'s kind, with the surface of the single
-    `jax.jit` object it was (what the tests, bench.py, the perf kernels
-    and scripts/profile_msm.py use)."""
-
-    __wrapped__ = staticmethod(_msm_tree)
-
-    def __call__(self, g: LimbGroup, *args):
-        return _MSM_TREE_JITS[g.kind](g, *args)
-
-    def lower(self, g: LimbGroup, *args):
-        return _MSM_TREE_JITS[g.kind].lower(g, *args)
-
-    def clear_cache(self) -> None:
-        for jitted in _MSM_TREE_JITS.values():
-            jitted.clear_cache()
-
-
-_msm_tree_jit = _MsmTreeJit()
-
-
 # ---------------------------------------------------------------------------
 # Fixed-scalar ladder application: out[..., o] = sum_k M[o][k] * pts[..., k]
 # (the in-the-exponent PSS pack/unpack maps, parallel/pss.py). The ladder

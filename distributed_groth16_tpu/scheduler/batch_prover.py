@@ -1,7 +1,7 @@
 """Batched proving path: B shape-identical jobs through ONE mesh program.
 
-`prove_batch` is the pure API (bench.py --batch and the correctness tests
-drive it directly): given one proving key + compiled circuit and B
+`prove_batch` is the pure API (the correctness tests drive it
+directly): given one proving key + compiled circuit and B
 Montgomery witness assignments, it stacks the witness-dependent tensors
 along a leading batch axis, runs `build_batch_mesh_prover`'s SPMD program
 over one shared packed CRS, and demuxes B deterministic proofs — each

@@ -4,18 +4,16 @@ aggregation plane — clock alignment, cross-party trace merging, critical
 path (aggregate.py) — the fault flight recorder (flight.py), and jax's
 own compile and trace clocks as counters (compile.py: importing this
 package registers the program's one `jax.monitoring` listener). Every layer — transport,
-distributed kernels, prover, service, API, bench — records through here;
+distributed kernels, prover, service, API — records through here;
 docs/OBSERVABILITY.md is the catalog and naming convention.
 
 The device observatory (docs/OBSERVABILITY.md "Device observatory")
 rides the same spine: devmem.py (HBM gauges/snapshots), transfer.py
-(host<->device boundary accounting), profiler.py (on-demand XLA capture),
-roofline.py and buildinfo.py. devmem/transfer register their families
-here; profiler/roofline/perf stay lazy like the performance observatory
-(perf.py registry + runner, perf_kernels.py cases, benchgate.py
-regression gate), which pulls in ops/ and is loaded by its consumers
-(`tools/benchgate`, `dg16-cli perf`, bench.py) so importing the spine
-stays cheap.
+(host<->device boundary accounting), profiler.py (on-demand XLA capture)
+and buildinfo.py. devmem/transfer register their families here;
+profiler.py is loaded by the server that offers `POST /profile`, so
+importing the spine stays cheap. A speed comes from `benchmark/` alone
+(PERF.md).
 """
 
 from . import (  # noqa: F401

@@ -77,16 +77,6 @@ def _on_time_span(event: str, start: float, end: float, **kw) -> None:
         _TRACE_SECONDS.labels(fn=_fn(kw)).inc(end - start)
 
 
-def seconds_total() -> float:
-    """Trace, lowering and compile seconds so far, every function summed:
-    the delta round a first call is what that call cost before it ran."""
-    return sum(
-        child.value
-        for fam in (_TRACE_SECONDS, _COMPILE_SECONDS)
-        for _, child in fam.items()
-    )
-
-
 def named_jit(name: str, fn, **jit_kwargs):
     """`jax.jit(fn)` under a program name of its own: jax's events carry
     `name` as `fun_name`, XLA names the module `jit_<name>`, and that is

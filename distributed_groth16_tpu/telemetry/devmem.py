@@ -1,8 +1,7 @@
 """Device memory telemetry: HBM state as gauges, snapshots, and deltas.
 
-`telemetry/perf.py` sampled `memory_stats()` exactly once per bench run;
-nothing else in the repo could say what device memory looked like while a
-job OOMed or a batch peaked. This module is the one reader of the backend
+Nothing else in the repo can say what device memory looked like while a
+job OOMed or a batch peaked: this module is the one reader of the backend
 memory API everything else goes through:
 
   * `sample()` — read `memory_stats()` per device and export
@@ -11,7 +10,7 @@ memory API everything else goes through:
     gauges fresh for scrapes.
   * `snapshot()` — the same read as a JSON-able document, never raising:
     attached to every flight-recorder post-mortem so an OOM post-mortem
-    carries the HBM state, and to bench.py's JSON line.
+    carries the HBM state.
   * `limit_bytes()` — the least `bytes_limit` of any device; the
     service's resident circuits are bounded by a share of it.
   * `peak_bytes()` — summed `peak_bytes_in_use`; the executor and batch
@@ -89,7 +88,7 @@ _CAMEL = {"in_use": "inUse", "peak": "peak", "limit": "limit"}
 
 
 def snapshot() -> dict:
-    """`sample()` that never raises — the flight-dump / bench attachment."""
+    """`sample()` that never raises — the flight-dump attachment."""
     try:
         return sample()
     except Exception:  # noqa: BLE001 — telemetry must not become the fault

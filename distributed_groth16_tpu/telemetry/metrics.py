@@ -10,13 +10,12 @@ constraints, in order:
      child; `child.inc()` / `child.observe()` is then lock + add.
   2. Process-wide. One registry per process (the Prometheus model): every
      layer registers its families at import time, so `GET /metrics` and
-     bench.py see one coherent snapshot without plumbing a registry handle
+     `/stats` see one coherent snapshot without plumbing a registry handle
      through twelve constructors. `registry()` returns it; tests compare
      deltas, never absolute values.
-  3. Thread-safe. Worker threads, the event loop, and the bench watchdog
-     all record concurrently; every family carries an RLock (re-entrant so
-     a signal handler snapshotting mid-increment cannot deadlock bench's
-     SIGTERM emit path).
+  3. Thread-safe. Worker threads and the event loop record
+     concurrently; every family carries an RLock (re-entrant so a signal
+     handler snapshotting mid-increment cannot deadlock).
 
 Exposition is Prometheus text format 0.0.4 (`render_prometheus`), with
 HELP/TYPE lines for every registered family — a family with no recorded
@@ -43,10 +42,9 @@ DEFAULT_TIME_BUCKETS = (
     1.0, 2.5, 5.0, 10.0, 30.0, 60.0, 120.0, 300.0, INF,
 )
 
-# kernel-latency buckets (perf_kernel_* families, telemetry/perf.py): a
-# warm NTT at 2^10 is tens of microseconds on TPU — DEFAULT_TIME_BUCKETS'
-# 1 ms floor would collapse every fast kernel into one bucket, hiding the
-# exact curve-bending the per-kernel bench exists to show
+# sub-millisecond buckets (`transfer_seconds`, telemetry/transfer.py): a
+# proof readback or a small upload is tens of microseconds —
+# DEFAULT_TIME_BUCKETS' 1 ms floor would collapse them into one bucket
 DEFAULT_KERNEL_BUCKETS = (
     0.00001, 0.000025, 0.00005, 0.0001, 0.00025, 0.0005,
     0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
@@ -327,7 +325,7 @@ class MetricsRegistry:
 
     def snapshot(self) -> dict[str, float]:
         """Flat {series: value} map (histograms as _sum/_count) — the
-        bench.py JSON-line and /stats shape."""
+        /stats shape."""
         out: dict[str, float] = {}
         with self._lock:
             fams = list(self._families.values())

@@ -126,7 +126,7 @@ class Profiler:
     def start(self, duration_s: float | None = None) -> Capture:
         """Begin a capture. `duration_s` > 0 arms a timer that stops it
         (the HTTP path — bounded by `DG16_PROF_MAX_S`); <= 0 or None means
-        the CALLER stops it (`capture_during`, benchgate --profile).
+        the CALLER stops it (`stop()`).
         Raises ProfileBusyError while another capture runs."""
         import jax
 
@@ -245,20 +245,3 @@ class Profiler:
             "captures": [c.to_dict() for c in caps],
         }
 
-
-class capture_during:
-    """Context manager for offline runs (benchgate --profile): capture for
-    the block's extent, artifact packed on exit. `.capture` holds the
-    record afterwards."""
-
-    def __init__(self, directory: str):
-        self.profiler = Profiler(directory)
-        self.capture: Capture | None = None
-
-    def __enter__(self) -> "capture_during":
-        self.capture = self.profiler.start(duration_s=0)
-        return self
-
-    def __exit__(self, *exc) -> bool:
-        self.capture = self.profiler.stop() or self.capture
-        return False

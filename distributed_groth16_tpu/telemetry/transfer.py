@@ -1,6 +1,6 @@
 """Host<->device transfer accounting for the proving hot path.
 
-The perf observatory measures kernels; nothing measured the BOUNDARIES —
+The device trace measures kernels; nothing measured the BOUNDARIES —
 packed-CRS upload, witness upload, proof readback — and on the pipelining
 roadmap item (overlap witness/transfer/prove) the win is exactly the
 transfer time currently serialized with compute. Call sites bracket each

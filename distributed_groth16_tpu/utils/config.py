@@ -65,16 +65,10 @@ KNOBS: dict[str, str] = {
     "DG16_LOG_JSON": "console handler emits JSON lines",
     "DG16_LOG_STORM_BURST": "per-template records before suppression",
     "DG16_LOG_STORM_RATE": "suppressed-template refill, records/sec, <=0 off",
-    # performance observatory (docs/PERF.md, docs/OBSERVABILITY.md)
-    "DG16_PERF_REPS": "benchgate warm reps per kernel case",
-    "DG16_PERF_REL_THRESHOLD": "benchgate relative slowdown gate",
-    "DG16_PERF_ABS_FLOOR_S": "benchgate absolute-seconds noise floor",
     # device observatory (docs/OBSERVABILITY.md "Device observatory")
     "DG16_PROF_DIR": "on-demand XLA profiler artifact directory",
     "DG16_PROF_MAX_S": "cap on one POST /profile capture duration",
     "DG16_DEVMEM_SAMPLE_S": "device-memory sampler period, <=0 off",
-    "DG16_PEAK_FLOPS": "roofline peak flops/sec override for this backend",
-    "DG16_PEAK_BW": "roofline peak HBM bytes/sec override for this backend",
     # fleet plane (docs/FLEET.md)
     "DG16_FLEET_REPLICAS": "router replica set: url[=journal-dir] CSV",
     "DG16_FLEET_POLL_S": "router discovery poll period seconds",
@@ -104,9 +98,7 @@ KNOBS: dict[str, str] = {
     # frontend / store
     "DG16_NO_CWASM": "force the pure-Python WASM witness VM",
     "DG16_STORE": "circuit store root directory",
-    # bench / examples / tests
-    "DG16_BENCH_BATCH_REPS": "bench.py --batch timing repetitions",
-    "DG16_BENCH_BATCH_CHAIN": "bench.py --batch chain-circuit length",
+    # examples / tests
     "DG16_VECTORS": "introspect.py: external test-vector directory",
     "DG16_REQUIRE_VECTORS": "introspect.py: fail when vectors missing",
     "DG16_TEST_CACHE": "scripts/run_tests.py: keep the jit cache on",

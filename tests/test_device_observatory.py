@@ -1,14 +1,13 @@
 """Device observatory tests (telemetry/profiler.py, devmem.py,
-transfer.py, roofline.py, buildinfo.py; docs/OBSERVABILITY.md "Device
+transfer.py, buildinfo.py; docs/OBSERVABILITY.md "Device
 observatory").
 
 Covers the ISSUE 14 ladder: on-demand XLA capture lifecycle (single
 flight, bounded duration, downloadable artifact, the span ->
 TraceAnnotation bridge and its zero-overhead-off guard), device-memory
 sampling (None-safe on XLA:CPU, gauge export with a fake stats-bearing
-device), transfer accounting, roofline attribution math + the
-`perf roofline` table, build-info exposure on /metrics + /readyz, the
-flight-dump memory snapshot, and the job-DTO deviceMemory stamp.
+device), transfer accounting, build-info exposure on /metrics + /readyz,
+the flight-dump memory snapshot, and the job-DTO deviceMemory stamp.
 
 The registry is process-wide: numeric checks compare deltas, not
 absolutes.
@@ -27,7 +26,6 @@ from distributed_groth16_tpu.telemetry import (
     devmem,
     flight,
     profiler,
-    roofline,
     tracing,
     transfer,
 )
@@ -231,169 +229,6 @@ def test_tree_nbytes_ignores_non_arrays():
     x = jnp.zeros((4, 16), dtype=jnp.uint32)
     assert transfer.tree_nbytes({"a": x, "b": [x, "str", 3]}) == 2 * x.nbytes
     assert transfer.tree_nbytes(None) == 0
-
-
-# -- roofline attribution ----------------------------------------------------
-
-
-def test_roofline_bound_classification_and_utilization():
-    peak = {"flops": 100.0, "bw": 10.0, "deviceKind": "t", "source": "test"}
-    # AI = 100 flop/byte >= ridge 10 -> compute-bound; roof = peak flops
-    att = roofline.attribute(
-        {"flops": 50.0, "bytes_accessed": 0.5}, 1.0, peak
-    )
-    assert att["bound"] == "compute"
-    assert att["utilization"] == pytest.approx(0.5)
-    # AI = 1 < ridge 10 -> memory-bound; roof = AI * bw = 10 flops/sec
-    att = roofline.attribute(
-        {"flops": 5.0, "bytes_accessed": 5.0}, 1.0, peak
-    )
-    assert att["bound"] == "memory"
-    assert att["utilization"] == pytest.approx(0.5)
-    assert att["ridge_intensity"] == pytest.approx(10.0)
-    # degenerate records attribute sanely or not at all
-    assert roofline.attribute(None, 1.0, peak) is None
-    assert roofline.attribute({"flops": 0, "bytes_accessed": 0}, 1.0,
-                              peak) is None
-    assert roofline.attribute({"flops": 1.0, "bytes_accessed": 0}, 0.0,
-                              peak) is None
-    only_bytes = roofline.attribute(
-        {"flops": 0, "bytes_accessed": 5.0}, 1.0, peak
-    )
-    assert only_bytes["bound"] == "memory"
-    assert only_bytes["utilization"] == pytest.approx(0.5)
-
-
-def test_roofline_peaks_env_overrides(monkeypatch):
-    base = roofline.peaks(kind="cpu")
-    assert base["source"] == "default:cpu"
-    monkeypatch.setenv("DG16_PEAK_FLOPS", "2e12")
-    monkeypatch.setenv("DG16_PEAK_BW", "1e11")
-    over = roofline.peaks(kind="cpu")
-    assert over == {
-        "flops": 2e12, "bw": 1e11, "deviceKind": "cpu", "source": "env",
-    }
-    monkeypatch.delenv("DG16_PEAK_FLOPS")
-    part = roofline.peaks(kind="cpu")  # one-field override still "env"
-    assert part["source"] == "env" and part["flops"] == base["flops"]
-
-
-def test_roofline_device_kind_table_prefix_match():
-    pk = roofline.peaks(kind="TPU v5 lite")
-    assert pk["source"] == "device:TPU v5 lite" and pk["flops"] == 197e12
-    # an accelerator the table does not know is an error, never the host
-    # default (the v5e reports "TPU v5 lite", matched above)
-    with pytest.raises(LookupError):
-        roofline.peaks(kind="weird accelerator")
-
-
-def _perf_rec(key, host=False, cost=None, med=0.1, error=None):
-    rec = {
-        "kernel": key.split("@")[0], "size": 3, "key": key,
-        "median_seconds": med, "host": host, "cost": cost,
-    }
-    if error:
-        rec = {"key": key, "error": error}
-    return rec
-
-
-def test_roofline_table_rows_and_footnotes():
-    run = {
-        "kernels": {
-            "dev@2e3": _perf_rec(
-                "dev@2e3", cost={"flops": 1e9, "bytes_accessed": 1e8}
-            ),
-            "hostk@2e3": _perf_rec("hostk@2e3", host=True),
-            "boom@2e3": _perf_rec("boom@2e3", error="RuntimeError: x"),
-            "nocost@2e3": _perf_rec("nocost@2e3", cost=None),
-        }
-    }
-    peak = {"flops": 1e11, "bw": 5e10, "deviceKind": "cpu",
-            "source": "test"}
-    table = roofline.format_table(run, peak)
-    lines = table.splitlines()
-    assert lines[0].startswith("KERNEL")
-    [row] = [ln for ln in lines if ln.startswith("dev@2e3")]
-    assert "compute" in row  # AI 10 >= ridge 2
-    assert "hostk@2e3 (host kernel" in table
-    assert "boom@2e3 (errored)" in table
-    assert "nocost@2e3 (no cost model)" in table
-    assert "peaks:" in table
-
-
-def test_perf_records_carry_roofline_and_utilization_gauge():
-    import jax
-    import jax.numpy as jnp
-
-    from distributed_groth16_tpu.telemetry import perf
-
-    def build(log2n):
-        n = 1 << log2n
-        x = jnp.arange(n, dtype=jnp.float32)
-        return perf.KernelCase(jax.jit(lambda v: (v * 3.0).sum()), (x,), n)
-
-    spec = perf.KernelSpec("_t_roof", build, (6,), (6,), "items/sec", False)
-    rec = perf.run_kernel(spec, 6, reps=2)
-    roof = rec["roofline"]
-    assert roof is not None
-    assert roof["bound"] in ("compute", "memory")
-    assert roof["utilization"] > 0
-    snap = REG.snapshot()
-    assert snap[
-        'perf_kernel_utilization{kernel="_t_roof",size="2e6"}'
-    ] == pytest.approx(roof["utilization"])
-    # host records never attribute
-    host_rec = perf.make_record(
-        kernel="_t_roof_host", size=3, items=8, unit="u", seconds=0.1,
-        host=True,
-    )
-    assert host_rec["roofline"] is None
-
-
-def test_cli_perf_roofline_table(tmp_path, capsys):
-    from distributed_groth16_tpu.api import cli
-
-    run = {
-        "schema": "dg16-perf/1", "platform": "cpu", "quick": True,
-        "kernels": {
-            "dev@2e3": {
-                "kernel": "dev", "size": 3, "key": "dev@2e3",
-                "median_seconds": 0.01, "host": False,
-                "cost": {"flops": 1e8, "bytes_accessed": 1e7},
-            },
-        },
-    }
-    path = tmp_path / "run.json"
-    path.write_text(json.dumps(run))
-    with pytest.raises(SystemExit) as e:
-        cli.main(["perf", "roofline", "--run", str(path)])
-    assert e.value.code == 0
-    out = capsys.readouterr().out
-    assert "dev@2e3" in out and "BOUND" in out
-    assert "compute" in out or "memory" in out
-
-
-def test_cli_perf_diff_markdown(tmp_path, capsys):
-    from distributed_groth16_tpu.api import cli
-
-    def doc(med):
-        return {
-            "schema": "dg16-perf/1", "platform": "cpu",
-            "kernels": {"k@2e3": {
-                "kernel": "k", "size": 3, "key": "k@2e3",
-                "median_seconds": med,
-            }},
-        }
-
-    pa, pb = tmp_path / "a.json", tmp_path / "b.json"
-    pa.write_text(json.dumps(doc(0.1)))
-    pb.write_text(json.dumps(doc(0.2)))
-    with pytest.raises(SystemExit) as e:
-        cli.main(["perf", "diff", str(pa), str(pb), "--markdown"])
-    assert e.value.code == 0
-    out = capsys.readouterr().out
-    assert "| kernel | A (s) | B (s) | B/A |" in out
-    assert "| `k@2e3` | 0.1 | 0.2 | 2.0 🔺 |" in out
 
 
 # -- build info --------------------------------------------------------------

@@ -30,15 +30,13 @@ from distributed_groth16_tpu.ops.curve import g1 as g1_rm  # noqa: E402
 
 def _clear_trace_caches():
     """The pallas-vs-xla choice is baked into traced programs at trace
-    time, and several live in process-global caches (_msm_tree_jit's jit
-    cache, the functools-cached LimbGroup._horner). Clear them on both
+    time, and several live in process-global caches (the tree MSM programs'
+    jit caches, the functools-cached LimbGroup._horner). Clear them on both
     sides of the fixture so (a) these tests don't silently reuse
     XLA-flavored traces from earlier suite files with the same shapes and
     (b) Pallas-flavored traces don't leak to later CPU tests."""
-    try:
-        lk._msm_tree_jit.clear_cache()
-    except Exception:
-        pass
+    for jitted in lk._MSM_TREE_JITS.values():
+        jitted.clear_cache()
     try:
         lk.LimbGroup._horner.cache_clear()
     except Exception:

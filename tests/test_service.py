@@ -5,10 +5,14 @@ Covers the acceptance ladder: (a) 8 concurrent submissions through a
 the queue bound with HTTP 429 + retryAfter, (c) a cancelled QUEUED job
 never runs, (d) repeat proofs on one circuit hit the packed-CRS cache
 (exactly one pack_proving_key call) — plus unit tests for the LRU cache,
-thread-safe PhaseTimings, the JobQueue, and the CLI's 429 surfacing.
+thread-safe PhaseTimings, the JobQueue, and the CLI's 429 surfacing; and
+the SLO burn-rate plane (service/slo.py; docs/OBSERVABILITY.md "SLO
+monitoring"): budget math, window expiry, exhaustion -> one flight dump an
+episode, /stats + /slo + /metrics exposure.
 """
 
 import asyncio
+import json
 import threading
 import time
 
@@ -25,7 +29,9 @@ from distributed_groth16_tpu.service import (
     ProofJob,
     QueueFullError,
 )
-from distributed_groth16_tpu.utils.config import ServiceConfig
+from distributed_groth16_tpu.telemetry import flight
+from distributed_groth16_tpu.telemetry import metrics as tm
+from distributed_groth16_tpu.utils.config import ServiceConfig, SLOConfig
 from distributed_groth16_tpu.utils.timers import PhaseTimings
 
 POLL_DEADLINE_S = 300.0
@@ -461,3 +467,153 @@ def test_cli_body_surfaces_429_retry_after():
     assert _body(_FakeResp(202, {"jobId": "j"})) == {"jobId": "j"}
     with pytest.raises(SystemExit, match="boom"):
         _body(_FakeResp(500, {"error": "boom"}))
+
+
+# -- SLO burn-rate plane -----------------------------------------------------
+
+
+def _observe_jobs(kind: str, seconds: float, n: int) -> None:
+    # the SAME registration the queue makes (idempotent by name/labels)
+    fam = tm.registry().histogram(
+        "job_seconds", "End-to-end job runtime (RUNNING to terminal), "
+        "per kind", ("kind",),
+    )
+    child = fam.labels(kind=kind)
+    for _ in range(n):
+        child.observe(seconds)
+
+
+def test_slo_targets_parse():
+    t = SLOConfig.parse_targets("prove=30, mpc_prove=120")
+    assert t == (("prove", 30.0), ("mpc_prove", 120.0))
+    assert SLOConfig.parse_targets("") == ()
+    with pytest.raises(ValueError):
+        SLOConfig.parse_targets("prove")
+    cfg = SLOConfig(target_s=10.0, targets=(("prove", 5.0),))
+    assert cfg.target_for("prove") == 5.0
+    assert cfg.target_for("other") == 10.0
+    assert cfg.enabled
+    assert not SLOConfig().enabled
+
+
+def test_slo_burn_rate_math():
+    from distributed_groth16_tpu.service.slo import SloMonitor
+
+    clock = [0.0]
+    cfg = SLOConfig(target_s=0.05, objective=0.9, window_s=1000.0,
+                    sample_s=1.0)
+    mon = SloMonitor(cfg, now=lambda: clock[0])  # baseline excludes history
+    _observe_jobs("prove", 0.001, 9)
+    clock[0] = 1.0
+    doc = mon.sample()
+    k = doc["kinds"]["prove"]
+    assert k["windowTotal"] == 9 and k["windowBad"] == 0
+    assert k["burnRate"] == 0.0 and k["budgetRemaining"] == 1.0
+    assert not k["exhausted"]
+    _observe_jobs("prove", 1.0, 1)  # misses the 50 ms target
+    clock[0] = 2.0
+    k = mon.sample()["kinds"]["prove"]
+    assert k["windowTotal"] == 10 and k["windowBad"] == 1
+    assert k["burnRate"] == pytest.approx(1.0)  # exactly on the 10% budget
+    assert k["budgetRemaining"] == pytest.approx(0.0) and k["exhausted"]
+    snap = tm.registry().snapshot()
+    assert snap['slo_burn_rate{kind="prove"}'] == pytest.approx(1.0)
+
+
+def test_slo_window_expires_old_samples():
+    from distributed_groth16_tpu.service.slo import SloMonitor
+
+    clock = [0.0]
+    cfg = SLOConfig(target_s=0.05, objective=0.9, window_s=10.0,
+                    sample_s=1.0)
+    mon = SloMonitor(cfg, now=lambda: clock[0])
+    _observe_jobs("mpc_prove", 1.0, 5)  # all bad
+    clock[0] = 1.0
+    assert mon.sample()["kinds"]["mpc_prove"]["windowBad"] == 5
+    # the bad burst ages out of the window with no new traffic
+    clock[0] = 50.0
+    mon.sample()
+    clock[0] = 51.0
+    k = mon.sample()["kinds"]["mpc_prove"]
+    assert k["windowBad"] == 0 and k["burnRate"] == 0.0
+
+
+def test_slo_budget_exhaustion_writes_one_flight_dump(tmp_path):
+    from distributed_groth16_tpu.service.slo import SloMonitor
+
+    flight.configure(str(tmp_path))
+    try:
+        clock = [0.0]
+        cfg = SLOConfig(target_s=0.05, objective=0.5, window_s=1000.0)
+        mon = SloMonitor(cfg, now=lambda: clock[0])
+        _observe_jobs("prove", 1.0, 4)  # 100% bad, 50% allowed -> overdrawn
+        clock[0] = 1.0
+        assert mon.sample()["kinds"]["prove"]["exhausted"]
+        dumps = list(tmp_path.glob("*slo_budget_exhausted*.json"))
+        assert len(dumps) == 1
+        record = json.loads(dumps[0].read_text())
+        assert record["extra"]["kind"] == "prove"
+        assert record["extra"]["windowBad"] == 4
+        # still exhausted on the next tick: same episode, no second dump
+        clock[0] = 2.0
+        mon.sample()
+        assert len(list(tmp_path.glob("*slo_budget_exhausted*.json"))) == 1
+        # recovery re-arms: budget heals, then a fresh burst dumps again
+        _observe_jobs("prove", 0.001, 100)
+        clock[0] = 3.0
+        assert not mon.sample()["kinds"]["prove"]["exhausted"]
+        _observe_jobs("prove", 1.0, 200)
+        clock[0] = 4.0
+        assert mon.sample()["kinds"]["prove"]["exhausted"]
+        assert len(list(tmp_path.glob("*slo_budget_exhausted*.json"))) == 2
+    finally:
+        flight.disable()
+
+
+def test_slo_routes_and_metrics_exposure(tmp_path):
+    async def run():
+        server = ApiServer(
+            CircuitStore(str(tmp_path)),
+            ServiceConfig(workers=1),
+            slo_cfg=SLOConfig(target_s=30.0, targets=(("prove", 30.0),),
+                              objective=0.99, sample_s=0.05),
+        )
+        client = TestClient(TestServer(server.app()))
+        await client.start_server()
+        try:
+            stats = await (await client.get("/stats")).json()
+            assert stats["slo"]["enabled"] is True
+            assert stats["slo"]["objective"] == 0.99
+            slo = await (await client.get("/slo")).json()
+            assert "prove" in slo["kinds"]
+            assert slo["kinds"]["prove"]["targetS"] == 30.0
+            text = await (await client.get("/metrics")).text()
+            assert 'slo_burn_rate{kind="prove"}' in text
+            assert "slo_budget_remaining" in text
+            # the background sampler task is alive between requests
+            await asyncio.sleep(0.1)
+            assert server._slo_task is not None and not server._slo_task.done()
+        finally:
+            await client.close()
+
+    asyncio.run(run())
+
+
+def test_slo_disabled_by_default(tmp_path):
+    async def run():
+        server = ApiServer(
+            CircuitStore(str(tmp_path)), ServiceConfig(workers=1),
+            slo_cfg=SLOConfig(),
+        )
+        assert server.slo is None
+        client = TestClient(TestServer(server.app()))
+        await client.start_server()
+        try:
+            stats = await (await client.get("/stats")).json()
+            assert stats["slo"] == {"enabled": False}
+            slo = await (await client.get("/slo")).json()
+            assert slo == {"enabled": False}
+        finally:
+            await client.close()
+
+    asyncio.run(run())

@@ -733,3 +733,40 @@ def test_histogram_quantile_interpolates_and_clamps():
     # the empty snapshot is 0, not a crash
     empty = tm.HistogramSnapshot((), [], 0.0, 0.0)
     assert tm.histogram_quantile(empty, 0.95) == 0.0
+
+
+def test_compile_listener_zero_delta_on_second_call():
+    """On jax's own clocks since ISSUE 23: a call that jax serves from its
+    jit cache must add NOTHING to the trace/compile counters — a
+    function's series is the whole of what its programs cost (the
+    benchmark's `setup_trace_msm_s` reads it), and `jax_compiles_total`
+    only moves on a real (re)compile, which is what makes it an alarm."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_groth16_tpu.telemetry import compile as tcompile
+
+    reg = tm.registry()
+    trace = reg.family("jax_trace_seconds_total").labels(fn="_t_hit")
+    comp = reg.family("jax_compile_seconds_total").labels(fn="_t_hit")
+    compiles = reg.family("jax_compiles_total")
+    tj = tcompile.named_jit("_t_hit", lambda v: (v * 5.0).sum())
+    x = jnp.arange(32, dtype=jnp.float32)
+    n0 = compiles.value
+    jax.block_until_ready(tj(x))  # first call: traced, lowered, compiled
+    assert trace.value > 0.0 and comp.value > 0.0
+    assert compiles.value >= n0 + 1
+    after = (trace.value, comp.value, compiles.value)
+    jax.block_until_ready(tj(x))  # jit-cache hit: every delta exactly 0
+    assert after == (trace.value, comp.value, compiles.value)
+
+
+def test_kernel_buckets_are_sub_millisecond():
+    assert min(tm.DEFAULT_KERNEL_BUCKETS) < 0.001
+    assert list(tm.DEFAULT_KERNEL_BUCKETS) == sorted(
+        tm.DEFAULT_KERNEL_BUCKETS
+    )
+    fam = tm.registry().family("transfer_seconds")
+    assert fam is not None and fam.buckets == tuple(
+        tm.DEFAULT_KERNEL_BUCKETS
+    )

@@ -174,7 +174,7 @@ def _lowered_msm(kind: str) -> str:
         "g2": (lk.lg2(), (16, 3, 2, 16)),
     }[kind]
     assert g.kind == kind
-    return lk._msm_tree_jit.lower(
+    return lk._MSM_TREE_JITS[kind].lower(
         g, jax.ShapeDtypeStruct(pts, jnp.uint32),
         jax.ShapeDtypeStruct((16, 16), jnp.uint32), 4, None,
     ).as_text(debug_info=True)
@@ -194,14 +194,22 @@ def test_tree_msm_program_is_named_by_group_and_scoped_by_stage(kind):
         assert f"/{scope}/" in text, scope
 
 
-def test_tree_msm_dispatcher_keeps_its_old_surface():
-    """`_msm_tree_jit` is what tests, bench.py and the perf kernels import:
-    callable with the old signature, `lower` for XLA's cost analysis,
-    `__wrapped__` the un-jitted body, `clear_cache` over both programs."""
-    assert lk._msm_tree_jit.__wrapped__ is lk._msm_tree
-    assert set(lk._MSM_TREE_JITS) == {"g1", "g2"}
-    assert callable(lk._msm_tree_jit) and callable(lk._msm_tree_jit.lower)
-    lk._msm_tree_jit.clear_cache()
+def test_tree_msm_programs_keep_their_names_and_count():
+    """Six programs, two a form, each named for its group: the benchmark's
+    `kernel_groups/*.json` and `setup_trace_msm_s` match these names by
+    substring, so a renamed or a seventh program changes what they count."""
+    names = sorted(
+        jitted.__name__
+        for jits in (lk._MSM_TREE_JITS, lk._MSM_LIMB0_JITS,
+                     lk._MSM_LIMB0_FILL_JITS)
+        for jitted in jits.values()
+    )
+    assert names == [
+        "_msm_tree_jit_g1", "_msm_tree_jit_g1_limb0",
+        "_msm_tree_jit_g1_limb0_fill", "_msm_tree_jit_g2",
+        "_msm_tree_jit_g2_limb0", "_msm_tree_jit_g2_limb0_fill",
+    ]
+    assert lk._MSM_TREE_JITS["g1"].__wrapped__.__code__ is lk._msm_tree.__code__
 
 
 def test_limb_ntt_steps_are_scoped():
