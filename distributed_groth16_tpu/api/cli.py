@@ -406,7 +406,7 @@ def cmd_metrics(args) -> dict:
 def cmd_profile_capture(args) -> dict:
     """POST /profile against a LIVE server (mid-job is the point), poll
     until the bounded capture finishes, and download the .tar.gz trace
-    artifact — open it in TensorBoard's profile plugin / Perfetto
+    artifact (an xplane) — open it in TensorBoard's profile plugin
     (docs/OBSERVABILITY.md "Device observatory")."""
     import time as _time
 

@@ -318,6 +318,49 @@ class LimbField:
         """[0, 2p) carried -> canonical [0, p)."""
         return self._cond_sub(a, jnp.asarray(self.p_col))
 
+    def is_zero(self, a, p):
+        """(1, n) uint32: 1 where a, (nl, n) in [0, 2p), is 0 mod p. The
+        redundant class of zero holds two residues, 0 and p: both are
+        looked for, by an OR over the rows of `a` and of `a ^ p` (what a
+        compare after `canon` would say, without its borrow chain)."""
+        z, zp = a[0:1], a[0:1] ^ p[0:1]
+        for i in range(1, a.shape[0]):
+            z = z | a[i : i + 1]
+            zp = zp | (a[i : i + 1] ^ p[i : i + 1])
+        return ((z == 0) | (zp == 0)).astype(jnp.uint32)
+
+    @functools.cached_property
+    def inv_digits(self) -> tuple:
+        """p - 2 in 4-bit digits, the most significant first."""
+        e, out = self.p - 2, []
+        while e:
+            out.append(e & 15)
+            e >>= 4
+        return tuple(out[::-1])
+
+    def inv_body(self, x, base_ops, one, table):
+        """x^(p-2), Fermat's inverse of a non-zero x (0 gives 0), by 4-bit
+        windows: 14 products for x^2..x^15, then four squarings and one
+        product a digit of p - 2. `base_ops` are this field's (mul, add,
+        sub); `one` is Montgomery 1 at x's shape. The loop reads its i-th
+        factor by a dynamic index on a leading axis, so the caller's
+        `table(factors)` says where they are kept and returns the getter:
+        a VMEM scratch in a kernel (dynamic_slice of a value does not
+        lower in Mosaic), a stacked array outside one."""
+        mul = base_ops[0]
+        tab = [one, x]
+        for _ in range(14):
+            tab.append(mul(tab[-1], x))
+        digits = self.inv_digits
+        get = table([tab[d] for d in digits])
+
+        def step(i, acc):
+            for _ in range(4):
+                acc = mul(acc, acc)
+            return mul(acc, get(i))
+
+        return jax.lax.fori_loop(1, len(digits), step, tab[digits[0]])
+
     # -- group-law plumbing --------------------------------------------------
 
     def make_ops(self, p, p2, unroll=True):
@@ -406,6 +449,25 @@ class LimbFq2:
         F, nl = self.fq, self.nl
         return jnp.concatenate([F.canon(a[0:nl]), F.canon(a[nl:])], axis=0)
 
+    def is_zero(self, a, p):
+        nl = self.nl
+        return self.fq.is_zero(a[0:nl], p) & self.fq.is_zero(a[nl:], p)
+
+    def inv_body(self, a, base_ops, one, table):
+        """1 / (a0 + a1 u) = (a0 - a1 u) / (a0^2 + a1^2): one inversion in
+        the base field (the norm of a non-zero a is not zero: -1 is no
+        square there). `base_ops` and `one` are the base field's."""
+        mul, add, sub = base_ops
+        nl = self.nl
+        a0, a1 = a[0:nl], a[nl:]
+        ninv = self.fq.inv_body(
+            add(mul(a0, a0), mul(a1, a1)), base_ops, one, table
+        )
+        c1 = mul(a1, ninv)
+        return jnp.concatenate(
+            [mul(a0, ninv), sub(jnp.zeros_like(c1), c1)], axis=0
+        )
+
     def b3_limbs(self, b) -> np.ndarray:
         """3*b' Montgomery-encoded as a (2*nl, 1) limb column (b' in Fq2)."""
         b0, b1 = b
@@ -471,11 +533,19 @@ class LimbGroup:
         self.consts_np = np.concatenate(
             [field.p_col, field.p2_col, field.b3_limbs(b)], axis=0
         )
-        inf = np.zeros((self.ROWS,), np.uint32)
-        inf[self.CR : self.CR + field.one_limbs().shape[0]] = (
-            field.one_limbs()
+        one = np.zeros((self.CR, 1), np.uint32)
+        one[: field.one_limbs().shape[0], 0] = field.one_limbs()
+        self.one_col = one  # Montgomery 1
+        zero = np.zeros_like(one)
+        self.inf_col = np.concatenate([zero, one, zero], axis=0)
+        # An affine point is (AROWS, n): x rows, y rows and one flag row,
+        # 1 where the point is infinity (x and y are then any residues).
+        self.AROWS = 2 * self.CR + 1
+        # consts block of the field and affine kernels:
+        # rows [0:bn] p, [bn:2bn] 2p, [2bn:2bn+CR] Montgomery 1
+        self.fconsts_np = np.concatenate(
+            [field.p_col, field.p2_col, one], axis=0
         )
-        self.inf_col = inf.reshape(self.ROWS, 1)
 
     # -- bodies -------------------------------------------------------------
 
@@ -635,16 +705,20 @@ class LimbGroup:
         """Flatten trailing batch axes, pad the lane axis to a power-of-two
         width, run. Power-of-two padding bounds the number of distinct
         compiled shapes (the unrolled group-law graphs are large, so each
-        extra shape is a real compile cost on both CPU and TPU)."""
-        RR = self.ROWS
-        shape = args[0].shape
-        flat = [a.reshape(RR, -1) for a in args]
+        extra shape is a real compile cost on both CPU and TPU). Each
+        argument keeps its own row count; so does each output."""
+        lanes = args[0].shape[1:]
+        flat = [a.reshape(a.shape[0], -1) for a in args]
         n = flat[0].shape[1]
         npad = self.lane_pad(n)
         if npad != n:
             flat = [jnp.pad(a, ((0, 0), (0, npad - n))) for a in flat]
-        out = (fn_pallas if use_pallas() else fn_xla)(*flat)[:, :n]
-        return out.reshape(shape)
+        out = (fn_pallas if use_pallas() else fn_xla)(*flat)
+        cut = [
+            o[:, :n].reshape((o.shape[0],) + lanes)
+            for o in (out if isinstance(out, tuple) else (out,))
+        ]
+        return tuple(cut) if isinstance(out, tuple) else cut[0]
 
     def lane_pad(self, n: int) -> int:
         """The lane width `add` / `double` run n lanes at: the power of two
@@ -664,6 +738,376 @@ class LimbGroup:
         return self.neg_body(
             p.reshape(self.ROWS, -1), self._consts()
         ).reshape(p.shape)
+
+    # -- affine points: the field product, the batched inversion, the add ---
+    #
+    # Where a level of the tree MSM's up-sweep is wide (`_AFFINE_MIN_ADDS`),
+    # its adds are affine: 7 field products an add, three of them its share
+    # of ONE inversion for the whole level, against the complete projective
+    # add's 14. Exact on every input: the lanes that need no slope (an
+    # operand at infinity, P + (-P), a doubling of a 2-torsion point) carry
+    # 1 through the inversion and take their result from a select.
+
+    def _fconsts(self):
+        return jnp.asarray(self.fconsts_np)
+
+    def _fops(self, consts, unroll):
+        """The field's (mul, add, sub), p, and 1 from the field consts."""
+        bn = self.base_nl
+        p, p2 = consts[0:bn], consts[bn : 2 * bn]
+        return self.F.make_ops(p, p2, unroll), p, consts[2 * bn :]
+
+    @property
+    def _base_field(self) -> LimbField:
+        return getattr(self.F, "fq", self.F)
+
+    def _base_ops(self, consts, unroll):
+        """(mul, add, sub) of the BASE field (the field's own over Fq)."""
+        bn = self.base_nl
+        return self._base_field.make_ops(
+            consts[0:bn], consts[bn : 2 * bn], unroll
+        )
+
+    def fmul_body(self, a, b, consts, unroll=True):
+        return self._fops(consts, unroll)[0][0](a, b)
+
+    def affine_pre_body(self, a1, a2, consts, unroll=True):
+        """The part of P1 + P2 before the inversion: (num | code) and den,
+        with the slope num / den. code: 0 take the slope's point, 1 take
+        P2 (P1 is infinity), 2 take P1, 3 infinity (the two cancel). The
+        zero tests are on residues in [0, 2p), where zero is 0 or p."""
+        CR = self.CR
+        (mul, add, sub), p, one = self._fops(consts, unroll)
+        x1, y1, f1 = a1[0:CR], a1[CR : 2 * CR], a1[2 * CR :]
+        x2, y2, f2 = a2[0:CR], a2[CR : 2 * CR], a2[2 * CR :]
+        dx, dy = sub(x2, x1), sub(y2, y1)
+        same_x = self.F.is_zero(dx, p)
+        same_y = self.F.is_zero(dy, p)
+        dbl = same_x & same_y
+
+        def doubling():  # the tangent's slope, 3 x1^2 / 2 y1
+            xx = mul(x1, x1)
+            return (jnp.where(dbl != 0, add(add(xx, xx), xx), dy),
+                    jnp.where(dbl != 0, add(y1, y1), dx))
+
+        # a block that holds no doubling (nearly every one: a doubling is a
+        # repeated point meeting itself) skips the tangent's product
+        num, den = jax.lax.cond(
+            jnp.max(dbl.astype(jnp.int32)) != 0, doubling, lambda: (dy, dx)
+        )
+        # equal x: the same point (a doubling, of a 2-torsion point when
+        # y is zero) or its negative
+        gone = same_x & ((same_y ^ 1) | self.F.is_zero(y1, p))
+        code = jnp.where(
+            f1 != 0, 1, jnp.where(f2 != 0, 2, jnp.where(gone != 0, 3, 0))
+        ).astype(jnp.uint32)
+        den = jnp.where(code == 0, den, one)
+        return jnp.concatenate([num, code], axis=0), den
+
+    def affine_post_body(self, a1, a2, nc, inv, consts, unroll=True):
+        """The part after it: lambda = num / den, x3 = lambda^2 - x1 - x2,
+        y3 = lambda (x1 - x3) - y1, then the selects of `code`."""
+        CR = self.CR
+        (mul, add, sub), _, _ = self._fops(consts, unroll)
+        x1, y1 = a1[0:CR], a1[CR : 2 * CR]
+        x2 = a2[0:CR]
+        code = nc[CR:]
+        lam = mul(nc[0:CR], inv)
+        x3 = sub(mul(lam, lam), add(x1, x2))
+        y3 = sub(mul(lam, sub(x1, x3)), y1)
+        xy = jnp.where(
+            code == 1, a2[0 : 2 * CR],
+            jnp.where(code == 2, a1[0 : 2 * CR],
+                      jnp.concatenate([x3, y3], axis=0)),
+        )
+        flag = jnp.where(
+            code == 1, a2[2 * CR :], (code == 3).astype(jnp.uint32)
+        )
+        return jnp.concatenate([xy, flag], axis=0)
+
+    def root_inverse_body(self, x, consts, one, table, kernel: bool):
+        """The narrow end of the batched inversion, (CR, L) with L at most
+        a lane tile: halves multiplied together down to the width of
+        `one` (the base field's 1, (base_nl, `_INV_LANES`) in a kernel),
+        one Fermat inversion there (`inv_body`, which `table` serves), and
+        the halves' inverses multiplied back out. In a kernel the
+        products are calls of the jitted blocks."""
+        if kernel:
+            _, badd, bsub = self._base_ops(consts, self._kmode())
+            base_ops = (
+                lambda a, b: self._bmul_block(a, b, consts), badd, bsub
+            )
+            fmul = lambda a, b: self._fmul_block(a, b, consts)  # noqa: E731
+        else:
+            base_ops = self._base_ops(consts, False)
+            fmul = lambda a, b: self.fmul_body(  # noqa: E731
+                a, b, consts, unroll=False
+            )
+        kept = []
+        while x.shape[1] > one.shape[1]:
+            h = x.shape[1] // 2
+            kept.append(x)
+            x = fmul(x[:, :h], x[:, h:])
+        inv = self.F.inv_body(x, base_ops, one, table)
+        for x in reversed(kept):
+            h = x.shape[1] // 2
+            inv = jnp.concatenate(
+                [fmul(inv, x[:, h:]), fmul(inv, x[:, :h])], axis=1
+            )
+        return inv
+
+    @functools.cached_property
+    def _fmul_block(self):
+        def fmul_block(a, b, consts):
+            return self.fmul_body(a, b, consts, unroll=self._kmode())
+
+        return jax.jit(fmul_block)
+
+    @functools.cached_property
+    def _bmul_block(self):
+        """The base field's product on one block (Fq2's Fermat inversion
+        runs in Fq)."""
+        def bmul_block(a, b, consts):
+            return self._base_ops(consts, self._kmode())[0](a, b)
+
+        return jax.jit(bmul_block)
+
+    @functools.cached_property
+    def _affine_pre_block(self):
+        def affine_pre_block(a1, a2, consts):
+            return self.affine_pre_body(a1, a2, consts, unroll=self._kmode())
+
+        return jax.jit(affine_pre_block)
+
+    @functools.cached_property
+    def _affine_post_block(self):
+        def affine_post_block(a1, a2, nc, inv, consts):
+            return self.affine_post_body(
+                a1, a2, nc, inv, consts, unroll=self._kmode()
+            )
+
+        return jax.jit(affine_post_block)
+
+    def _lane_kernel(self, block, in_rows: tuple, out_rows: tuple):
+        """`block(*blocks, consts)` over lane tiles, as `_pallas_add` runs
+        `_add_block`: one grid step a tile, every argument and output a
+        (rows, tile) block of its own row count."""
+        pl, pltpu = _pl()
+        T, CROWS = self.tile, self.fconsts_np.shape[0]
+        n_in = len(in_rows)
+
+        def spec(rows):
+            return pl.BlockSpec((rows, T), lambda i: (0, i),
+                                memory_space=pltpu.VMEM)
+
+        def kern(*refs):
+            outs = block(*(r[:] for r in refs[: n_in + 1]))
+            if len(out_rows) == 1:
+                outs = (outs,)
+            for o_ref, o in zip(refs[n_in + 1 :], outs):
+                o_ref[:] = o
+
+        @jax.jit
+        def run(*args):
+            n = args[0].shape[1]
+            out = pl.pallas_call(
+                kern,
+                out_shape=[
+                    jax.ShapeDtypeStruct((r, n), jnp.uint32) for r in out_rows
+                ],
+                grid=(n // T,),
+                in_specs=[spec(r) for r in in_rows] + [
+                    pl.BlockSpec((CROWS, 1), lambda i: (0, 0),
+                                 memory_space=pltpu.VMEM)
+                ],
+                out_specs=[spec(r) for r in out_rows],
+            )(*args, self._fconsts())
+            return out[0] if len(out_rows) == 1 else tuple(out)
+
+        return run
+
+    @functools.cached_property
+    def _pallas_fmul(self):
+        CR = self.CR
+        return self._lane_kernel(self._fmul_block, (CR, CR), (CR,))
+
+    @functools.cached_property
+    def _pallas_affine_pre(self):
+        CR, AR = self.CR, self.AROWS
+        return self._lane_kernel(
+            self._affine_pre_block, (AR, AR), (CR + 1, CR)
+        )
+
+    @functools.cached_property
+    def _pallas_affine_post(self):
+        CR, AR = self.CR, self.AROWS
+        return self._lane_kernel(
+            self._affine_post_block, (AR, AR, CR + 1, CR), (AR,)
+        )
+
+    @functools.cached_property
+    def _pallas_root_inverse(self):
+        pl, pltpu = _pl()
+        bn = self.base_nl
+        nd = len(self._base_field.inv_digits)
+
+        def kern(x_ref, one_ref, c_ref, o_ref, tab_ref):
+            def table(factors):
+                for i, v in enumerate(factors):
+                    tab_ref[i] = v
+                return lambda i: tab_ref[i]
+
+            o_ref[:] = self.root_inverse_body(
+                x_ref[:], c_ref[:], one_ref[:], table, kernel=True
+            )
+
+        vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+
+        @jax.jit
+        def run(x):
+            one = jnp.broadcast_to(
+                jnp.asarray(self.one_col[:bn]), (bn, _INV_LANES)
+            )
+            return pl.pallas_call(
+                kern,
+                out_shape=jax.ShapeDtypeStruct(x.shape, jnp.uint32),
+                in_specs=[vmem, vmem, vmem],
+                out_specs=vmem,
+                scratch_shapes=[
+                    pltpu.VMEM((nd, bn, _INV_LANES), jnp.uint32)
+                ],
+            )(x, one, self._fconsts())
+
+        return run
+
+    @functools.cached_property
+    def _xla_fmul(self):
+        return jax.jit(
+            lambda a, b: self.fmul_body(a, b, self._fconsts(), unroll=False)
+        )
+
+    @functools.cached_property
+    def _xla_affine_pre(self):
+        return jax.jit(
+            lambda a1, a2: self.affine_pre_body(
+                a1, a2, self._fconsts(), unroll=False
+            )
+        )
+
+    @functools.cached_property
+    def _xla_affine_post(self):
+        return jax.jit(
+            lambda a1, a2, nc, inv: self.affine_post_body(
+                a1, a2, nc, inv, self._fconsts(), unroll=False
+            )
+        )
+
+    @functools.cached_property
+    def _xla_root_inverse(self):
+        bn = self.base_nl
+
+        def table(factors):
+            stack = jnp.stack(factors)
+            return lambda i: stack[i]
+
+        def run(x):
+            one = jnp.broadcast_to(
+                jnp.asarray(self.one_col[:bn]),
+                (bn, min(x.shape[1], _INV_LANES)),
+            )
+            return self.root_inverse_body(
+                x, self._fconsts(), one, table, kernel=False
+            )
+
+        return jax.jit(run)
+
+    def fmul(self, a, b):
+        """The field's product on (CR, ...) limb-major batches."""
+        return self._batched(self._pallas_fmul, self._xla_fmul, (a, b))
+
+    def batch_inverse(self, a):
+        """1 / a for every lane of a (CR, ...) batch of NON-ZERO field
+        elements (a caller's masked lane carries 1: one zero would zero
+        every inverse), for three products an element and one Fermat
+        inversion a call. A product tree up, halves against halves (lane
+        slices, where the up-sweep's neighbours would be a stride-2
+        shuffle; any pairing serves a product), each level one dense
+        product kernel; at one lane tile the root kernel takes over; and
+        the tree walked back down, inverse of a half = inverse of the
+        pair times the other half."""
+        shape = a.shape
+        a = a.reshape(self.CR, -1)
+        n = a.shape[1]
+        pallas = use_pallas()
+        width = max(_INV_LANES if pallas else 1,
+                    1 << max(n - 1, 0).bit_length())
+        if width != n:
+            a = jnp.concatenate(
+                [a, jnp.broadcast_to(jnp.asarray(self.one_col),
+                                     (self.CR, width - n))],
+                axis=1,
+            )
+        with jax.named_scope("msm.inverse"):
+            kept, x = [], a
+            while x.shape[1] > self.lane_pad(1):
+                h = x.shape[1] // 2
+                kept.append(x)
+                x = self.fmul(x[:, :h], x[:, h:])
+            inv = (
+                self._pallas_root_inverse if pallas
+                else self._xla_root_inverse
+            )(x)
+            for x in reversed(kept):
+                h = x.shape[1] // 2
+                inv = self.fmul(
+                    jnp.concatenate([inv, inv], axis=1),
+                    jnp.concatenate([x[:, h:], x[:, :h]], axis=1),
+                )
+        return inv[:, :n].reshape(shape)
+
+    def affine_add(self, a1, a2):
+        """P1 + P2 on (AROWS, ...) batches of affine points, exact for
+        every pair: two kernels round one batched inversion."""
+        nc, den = self._batched(
+            self._pallas_affine_pre, self._xla_affine_pre, (a1, a2)
+        )
+        inv = self.batch_inverse(den)
+        return self._batched(
+            self._pallas_affine_post, self._xla_affine_post,
+            (a1, a2, nc, inv),
+        )
+
+    def normalise(self, lm):
+        """(ROWS, n) projective, any Z -> (AROWS, n) affine: X / Z, Y / Z
+        and the flag Z == 0, with one batched inversion."""
+        CR = self.CR
+        inf = self.F.is_zero(lm[2 * CR :], jnp.asarray(self.F.p_col))
+        z = jnp.where(inf != 0, jnp.asarray(self.one_col), lm[2 * CR :])
+        zinv = self.batch_inverse(z)
+        return jnp.concatenate(
+            [self.fmul(lm[0:CR], zinv), self.fmul(lm[CR : 2 * CR], zinv),
+             inf],
+            axis=0,
+        )
+
+    def lift(self, a, axis: int = 0):
+        """Affine (x, y, inf) -> projective (x, y, 1), or (0, 1, 0) under
+        the flag; the rows lie along `axis`."""
+        CR = self.CR
+        axis %= a.ndim
+        rows = [1] * a.ndim
+        rows[axis] = -1
+        xy = jax.lax.slice_in_dim(a, 0, 2 * CR, axis=axis)
+        flag = jax.lax.slice_in_dim(a, 2 * CR, 2 * CR + 1, axis=axis)
+        one = jnp.asarray(self.one_col).reshape(rows)
+        one = jnp.broadcast_to(
+            one, xy.shape[:axis] + (CR,) + xy.shape[axis + 1 :]
+        )
+        return jnp.where(
+            flag != 0,
+            jnp.asarray(self.inf_col).reshape(rows),
+            jnp.concatenate([xy, one], axis=axis),
+        )
 
     # -- window combine (Horner over c-bit windows), one fused kernel -------
 
@@ -880,6 +1324,57 @@ def _tree_npad(n: int) -> int:
     return 1 << max(1, (n - 1).bit_length())
 
 
+# A level of the tree's up-sweep runs affine adds (`LimbGroup.affine_add`)
+# when it holds at least this many adds, and complete projective ones
+# under it: an affine add is half the field work, and a level of them pays
+# one Fermat inversion, serial on one block, whatever its width.
+_AFFINE_MIN_ADDS = 1 << 16
+# the lane width the one Fermat inversion of a batched inversion runs at
+_INV_LANES = 128
+
+
+def _tree_window_bits(n: int) -> int:
+    # the Fenwick/combine stages scale with B = 2^c per window: a small
+    # MSM with c=8 would spend everything on 255 empty buckets
+    return 8 if n >= 4096 else 4
+
+
+def _tree_window_group(g: "LimbGroup", npad: int, windows: int) -> int:
+    # bound live tree memory to ~8 * 48 * 2^20 * 4 * 2 ≈ 3.2 GB
+    # (half the window count for G2's double-width rows)
+    return windows if npad <= (1 << 17) else max(1, 8 * 48 // g.ROWS)
+
+
+def _affine_depth(windows: int, npad: int, min_adds: int) -> int:
+    """How many levels of a window group's up-sweep are affine: level d
+    holds `windows * npad >> (d + 1)` adds, the widest first."""
+    return sum(
+        (windows * npad) >> (d + 1) >= min_adds
+        for d in range(npad.bit_length() - 1)
+    )
+
+
+def _affine_depths(windows: int, group: int, npad: int,
+                   min_adds: int) -> list[int]:
+    """`_affine_depth` of each window group of a launch, in order."""
+    return [
+        _affine_depth(min(group, windows - w0), npad, min_adds)
+        for w0 in range(0, windows, group)
+    ]
+
+
+def tree_affine_levels(g: "LimbGroup", n: int, limbs: int) -> int:
+    """The affine levels a launch of `msm_tree` runs on n points with
+    scalars of `limbs` 16-bit limbs (1 for the limb-0 form), over all its
+    window groups: what `msm_affine_levels_total` is raised by."""
+    npad = _tree_npad(n)
+    windows = limbs * LIMB_BITS // _tree_window_bits(n)
+    return sum(_affine_depths(
+        windows, _tree_window_group(g, npad, windows), npad,
+        _AFFINE_MIN_ADDS,
+    ))
+
+
 def wide_capacity(g: "LimbGroup", n: int) -> int:
     """How many wide scalars the limb-0 tree carries beside n points: each
     takes 15 of the slots that padding n to its power of two leaves empty,
@@ -917,6 +1412,19 @@ def msm_tree(points_rm, scalars_std, c: int | None = None,
     The whole computation is one jitted program: per-dispatch host latency
     would otherwise dominate the ~30 narrow query/combine steps.
 
+    The price of an add follows the level's width. A level of the sum tree
+    that holds at least `_AFFINE_MIN_ADDS` (2^16) adds over its window
+    group runs them affine (`LimbGroup.affine_add`: 7 field products an
+    add, three of them its share of one inversion for the whole level,
+    against the complete projective add's 14 and twice the additions); a
+    narrower level runs the complete add, because the level's one Fermat
+    inversion is serial on one 128-lane block (1.2 ms on a v5e) whatever
+    the width, and a launch with an affine level first makes its points
+    affine (1.6 ms at 32,768). The widest levels come first, so the
+    affine ones are a prefix: 32 windows of 32,768 points take four (94 %
+    of the adds), the limb-0 form's 2 windows none. Both adds are exact
+    on every input, so the sum is the same group element either way.
+
     The window count follows the occupancy of the scalars, as arkworks'
     MSM drops zero scalars and takes unit ones in its first window. Without
     `wide` (device scalars nobody has seen) every window of the k limbs is
@@ -932,9 +1440,7 @@ def msm_tree(points_rm, scalars_std, c: int | None = None,
     """
     n = points_rm.shape[0]
     if c is None:
-        # the Fenwick/combine stages scale with B = 2^c per window: a small
-        # MSM with c=8 would spend everything on 255 empty buckets
-        c = 8 if n >= 4096 else 4
+        c = _tree_window_bits(n)
     g = group or (lg2() if points_rm.ndim == 4 else lg1())
     if not takes_limb0(g, n, wide):
         return _MSM_TREE_JITS[g.kind](
@@ -1006,11 +1512,15 @@ def _limb0_fill(g: LimbGroup, points_rm, scalars_std, idx, limbs, steps):
 
 
 def _msm_tree(g: LimbGroup, points_rm, scalars_std, c: int,
-              window_group: int | None):
-    """The tree MSM's body (see `msm_tree`). Its five stages sit in
+              window_group: int | None,
+              affine_min_adds: int = _AFFINE_MIN_ADDS):
+    """The tree MSM's body (see `msm_tree`). Its stages sit in
     `jax.named_scope`s (`msm.sort`, `msm.upsweep`, `msm.fenwick`,
-    `msm.combine`, `msm.horner`), so every device op of the program can be
-    put down to a stage in a profiler trace."""
+    `msm.combine`, `msm.horner`; where a level is affine also
+    `msm.normalise`, `msm.upsweep.affine` and, inside both, `msm.inverse`),
+    so every device op of the program can be put down to a stage in a
+    profiler trace. `affine_min_adds` is the rule's constant, an argument
+    so that a test can run affine levels at 16 points."""
     RR = g.ROWS
     n = points_rm.shape[0]
     W_all = scalars_std.shape[1] * LIMB_BITS // c
@@ -1026,14 +1536,23 @@ def _msm_tree(g: LimbGroup, points_rm, scalars_std, c: int,
     levels_n = npad.bit_length() - 1  # log2(npad)
 
     if window_group is None:
-        # bound live tree memory to ~8 * 48 * 2^20 * 4 * 2 ≈ 3.2 GB
-        # (half the window count for G2's double-width rows)
-        window_group = (
-            W_all if npad <= (1 << 17) else max(1, 8 * 48 // RR)
-        )
+        window_group = _tree_window_group(g, npad, W_all)
+    # A window group's widest levels are affine (`_affine_depth`); a launch
+    # that has one takes its points affine from the start, normalised once
+    # (they may come with any Z: the limb-0 fill's ladder points do), so
+    # that the sort gathers AROWS rows a point in place of ROWS. With no
+    # such level nothing below differs from the all-projective program.
+    depths = _affine_depths(W_all, window_group, npad, affine_min_adds)
+    affine = any(depths)
+    if affine:
+        with jax.named_scope("msm.normalise"):
+            lm = g.normalise(lm)
+
+    def rows_last(x):  # (rows, Wg, K) -> (Wg * K, rows)
+        return jnp.transpose(x, (1, 2, 0)).reshape(-1, x.shape[0])
 
     sums = []
-    for w0 in range(0, W_all, window_group):
+    for w0, depth in zip(range(0, W_all, window_group), depths):
         dg = digits[w0 : w0 + window_group]  # (Wg, npad)
         Wg = dg.shape[0]
         with jax.named_scope("msm.sort"):
@@ -1045,21 +1564,32 @@ def _msm_tree(g: LimbGroup, points_rm, scalars_std, c: int,
                 )
             )(sortd)  # (Wg, B-1)
             gathered = jnp.take(lm, order.reshape(-1), axis=1).reshape(
-                RR, Wg, npad
+                lm.shape[0], Wg, npad
             )
 
-        # Up-sweep; each level is also kept transposed to (Wg*K, ROWS) so
+        # Up-sweep; each level is also kept transposed to (Wg*K, rows) so
         # the Fenwick node lookups below are contiguous row gathers
-        # (embedding-style) instead of ROWS-way strided minor-axis gathers.
-        with jax.named_scope("msm.upsweep"):
-            lvls_t = []
+        # (embedding-style) instead of rows-way strided minor-axis gathers.
+        # Levels 0..depth of `lvls_t` hold affine points where the launch
+        # has an affine level (level 0, the gathered points, whatever this
+        # group's depth), the rest projective ones.
+        with jax.named_scope(
+            "msm.upsweep.affine" if depth else "msm.upsweep"
+        ):
+            lvls_t = [rows_last(gathered)]
             x = gathered
-            lvls_t.append(jnp.transpose(x, (1, 2, 0)).reshape(-1, RR))
-            for _ in range(levels_n):
+            for _ in range(depth):
+                pair = x.reshape(g.AROWS, Wg, x.shape[-1] // 2, 2)
+                x = g.affine_add(pair[..., 0], pair[..., 1])
+                lvls_t.append(rows_last(x))
+        with jax.named_scope("msm.upsweep"):
+            if affine:
+                x = g.lift(x)
+            for _ in range(levels_n - depth):
                 k = x.shape[-1]
                 pair = x.reshape(RR, Wg, k // 2, 2)
                 x = g.add(pair[..., 0], pair[..., 1])
-                lvls_t.append(jnp.transpose(x, (1, 2, 0)).reshape(-1, RR))
+                lvls_t.append(rows_last(x))
             total = x[..., 0:1]  # (RR, Wg, 1)
 
         # Fenwick prefix at the B-1 bucket boundaries: gather one node per
@@ -1074,8 +1604,10 @@ def _msm_tree(g: LimbGroup, points_rm, scalars_std, c: int,
                 k = npad >> d
                 flat = (jnp.arange(Wg)[:, None] * k + idx).reshape(-1)
                 node = jnp.take(lvls_t[d], flat, axis=0).reshape(
-                    Wg, B - 1, RR
+                    Wg, B - 1, -1
                 )
+                if affine and d <= depth:  # an affine level's nodes
+                    node = g.lift(node, axis=-1)
                 node = jnp.where(takebit[..., None], node, inf_row)
                 nodes.append(node)
             D = len(nodes)
@@ -1129,7 +1661,7 @@ def _msm_tree(g: LimbGroup, points_rm, scalars_std, c: int,
 # shows in the program's name, so a trace tells a G1 launch from a G2 one.
 _MSM_TREE_JITS = {
     kind: named_jit(
-        f"_msm_tree_jit_{kind}", _msm_tree, static_argnums=(0, 3, 4)
+        f"_msm_tree_jit_{kind}", _msm_tree, static_argnums=(0, 3, 4, 5)
     )
     for kind in ("g1", "g2")
 }
@@ -1139,7 +1671,7 @@ _MSM_TREE_JITS = {
 # `_msm_tree_jit_<kind>`, which is what the MSM's launches are counted by.
 _MSM_LIMB0_JITS = {
     kind: named_jit(
-        f"_msm_tree_jit_{kind}_limb0", _msm_tree, static_argnums=(0, 3, 4)
+        f"_msm_tree_jit_{kind}_limb0", _msm_tree, static_argnums=(0, 3, 4, 5)
     )
     for kind in ("g1", "g2")
 }

@@ -71,6 +71,11 @@ _WIDE_CARRIED = _tm.registry().counter(
     "Scalars wider than 16 bits that limb-0 tree MSMs carried beside "
     "their points (15 ladder points each), summed over launches",
 )
+_AFFINE_LEVELS = _tm.registry().counter(
+    "msm_affine_levels_total",
+    "Up-sweep levels that tree MSMs ran as batch-affine adds (the levels "
+    "of at least limb_kernels._AFFINE_MIN_ADDS adds), summed over launches",
+)
 
 
 def _digits_for_window(scalars, w, c: int):
@@ -209,13 +214,15 @@ def msm(curve: CurvePoints, points, scalars, window_bits: int | None = None,
         else None
     )
     if tree_g is not None:
-        from .limb_kernels import msm_tree, takes_limb0
+        from .limb_kernels import msm_tree, takes_limb0, tree_affine_levels
 
         if takes_limb0(tree_g, n, wide):
             _R_TREE_LIMB0.inc()
             _WIDE_CARRIED.inc(wide.count)
+            _AFFINE_LEVELS.inc(tree_affine_levels(tree_g, n, 1))
             return msm_tree(points, scalars, group=tree_g, wide=wide)
         _R_TREE.inc()
+        _AFFINE_LEVELS.inc(tree_affine_levels(tree_g, n, scalars.shape[-1]))
         return msm_tree(points, scalars, group=tree_g)
     if window_bits is None and chunk is None and n <= _LADDER_MSM_MAX_N:
         _R_LADDER.inc()
@@ -247,9 +254,12 @@ def msm_batched(curve: CurvePoints, bases, scalars_std):
     B, n = scalars_std.shape[0], scalars_std.shape[1]
     tree_g = _tree_group(curve, n)
     if tree_g is not None:
-        from .limb_kernels import msm_tree
+        from .limb_kernels import msm_tree, tree_affine_levels
 
         _RB_TREE.inc()
+        _AFFINE_LEVELS.inc(
+            B * tree_affine_levels(tree_g, n, scalars_std.shape[-1])
+        )
         return jnp.stack(
             [
                 msm_tree(bases[b], scalars_std[b], group=tree_g)

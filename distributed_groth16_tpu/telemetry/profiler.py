@@ -11,10 +11,14 @@ can trigger against a LIVE ApiServer mid-job —
                                           200 .tar.gz artifact when done
     dg16-cli profile capture --seconds 3 --out prof.tar.gz
 
-The capture wraps `jax.profiler.start_trace/stop_trace` writing under
-`DG16_PROF_DIR`; at stop the trace directory (xplane.pb + trace.json.gz)
-is tarred into one artifact, openable in TensorBoard's profile plugin or
-Perfetto. Captures run with the Python tracer off. While a capture is
+The capture wraps `jax.profiler.start_trace` writing under
+`DG16_PROF_DIR`; at stop the trace directory (`plugins/profile/<run>/
+<host>.xplane.pb`) is tarred into one artifact, openable in TensorBoard's
+profile plugin. The stop writes the xplane alone (`_stop_trace`): jax's own
+`stop_trace` also converts it to a `trace.json.gz` in which every device
+event repeats its whole HLO text, a Mosaic kernel's payload included, and
+that second file took most of a stop's minutes under load and was read by
+nothing. Captures run with the Python tracer off. While a capture is
 live, `tracing.set_annotator` bridges every `tracing.span` into a
 `jax.profiler.TraceAnnotation` of the same name (the `job` span's carries
 its job id), so job phases (load / witness / packing / MPC Proof / dmsm / dfft...) line
@@ -31,6 +35,7 @@ production replica for an hour.
 from __future__ import annotations
 
 import os
+import socket
 import tarfile
 import threading
 import time
@@ -99,6 +104,37 @@ def _annotation_factory(name: str, attrs: dict | None):
     if name == "job" and attrs and "job" in attrs:
         return jax.profiler.TraceAnnotation(name, job_id=attrs["job"])
     return jax.profiler.TraceAnnotation(name)
+
+
+def _stop_trace() -> None:
+    """`jax.profiler.stop_trace()` less its `trace.json.gz`: the session's
+    xplane written where jax would write it. jax keeps the session in a
+    private holder; where that is not as expected (another jax, a test's
+    stand-in for `start_trace`), jax's own stop runs."""
+    import jax
+
+    try:
+        from jax._src.profiler import _profile_state as state
+
+        lock, sess, log_dir = state.lock, state.profile_session, state.log_dir
+    except (ImportError, AttributeError):
+        sess = None
+    if sess is None:
+        jax.profiler.stop_trace()
+        return
+    with lock:
+        try:
+            xspace = sess.stop()
+        finally:
+            state.reset()  # a failed stop must not hold jax's one slot
+    run_dir = os.path.join(
+        log_dir, "plugins", "profile", time.strftime("%Y_%m_%d_%H_%M_%S")
+    )
+    os.makedirs(run_dir, exist_ok=True)
+    with open(
+        os.path.join(run_dir, socket.gethostname() + ".xplane.pb"), "wb"
+    ) as f:
+        f.write(xspace)
 
 
 class Profiler:
@@ -181,8 +217,6 @@ class Profiler:
         """End the current capture, tar its trace directory into the
         downloadable artifact, and return the record (None if no capture
         was running — a late timer racing an explicit stop is benign)."""
-        import jax
-
         with self._lock:
             cap = self._current
             self._current = None
@@ -194,7 +228,7 @@ class Profiler:
         _tracing.set_annotator(None)
         _ACTIVE.set(0)
         try:
-            jax.profiler.stop_trace()
+            _stop_trace()
         except Exception as e:  # noqa: BLE001 — never turn profiling into a fault
             cap.state = "error"
             cap.error = f"{type(e).__name__}: {e}"
@@ -217,7 +251,7 @@ class Profiler:
         return cap
 
     def _pack(self, cap: Capture) -> str:
-        """Tar the trace directory (xplane.pb, trace.json.gz, ...) into
+        """Tar the trace directory (the xplane.pb) into
         `<id>.tar.gz` next to it — one downloadable file per capture."""
         path = os.path.join(self.directory, f"{cap.id}.tar.gz")
         with tarfile.open(path, "w:gz") as tar:

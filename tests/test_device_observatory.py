@@ -49,8 +49,39 @@ def test_capture_produces_downloadable_artifact(tmp_path):
     assert cap.artifact and cap.artifact_bytes > 0
     with tarfile.open(cap.artifact, "r:gz") as tar:
         names = tar.getnames()
-    # the jax trace payload is inside (xplane.pb and/or trace.json.gz)
-    assert any("xplane" in n or "trace" in n for n in names)
+    # the jax trace payload is inside: the xplane, and nothing converted
+    # from it
+    files = [n for n in names if "." in n.rsplit("/", 1)[-1]]
+    assert len(files) == 1 and files[0].endswith(".xplane.pb"), names
+
+
+def test_stop_writes_the_xplane_where_jax_would_and_frees_its_slot(tmp_path):
+    """The stop keeps `plugins/profile/<run>/<host>.xplane.pb` (what
+    TensorBoard and `benchmark/trace_reduce.py` open), readable and with
+    the spans in it, and leaves jax's one profiler slot free."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+
+    p = profiler.Profiler(str(tmp_path))
+    cap = p.start(duration_s=0)
+    with tracing.span("job", job="j-7", attrs={"kind": "prove"}):
+        (jnp.arange(256.0) * 2).sum().block_until_ready()
+    assert p.stop().state == "done"
+    (found,) = glob.glob(f"{cap.directory}/plugins/profile/*/*")
+    assert found.endswith(".xplane.pb")
+    names = {
+        ev.name
+        for plane in jax.profiler.ProfileData.from_file(found).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for ev in line.events
+    }
+    assert "job" in names
+    # jax's own start works again at once: the session was let go
+    jax.profiler.start_trace(str(tmp_path / "again"))
+    jax.profiler.stop_trace()
 
 
 def _wait_done(p: profiler.Profiler, cap_id: str, timeout: float = 15.0):

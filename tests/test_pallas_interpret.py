@@ -85,6 +85,47 @@ def test_pallas_double_kernel_interpret(pallas_interpret):
     ).all()
 
 
+@pytest.mark.parametrize("kind", ["g1", "g2"])
+def test_affine_level_kernels_interpret(pallas_interpret, kind):
+    """The four kernels of a batch-affine level at one lane tile, against
+    the XLA bodies of the same formulas: the field product, the affine
+    add's two halves on lanes that hold every case (a generic pair, an
+    operand at infinity, a doubling, a point and its negative) and the
+    root of the batched inversion."""
+    from distributed_groth16_tpu.ops import refmath as rm
+    from distributed_groth16_tpu.ops.constants import G2_GENERATOR
+    from distributed_groth16_tpu.ops.curve import g2 as g2_rm
+
+    g, C, host, gen = {
+        "g1": (lk.lg1(), g1_rm(), rm.G1, G1_GENERATOR),
+        "g2": (lk.lg2(), g2_rm(), rm.G2, G2_GENERATOR),
+    }[kind]
+    T, CR = g.tile, g.CR
+    P, Q = host.scalar_mul(gen, 2), host.scalar_mul(gen, 9)
+    left = [P, None, P, P, Q, None]
+    right = [Q, Q, None, P, host.neg(Q), None]
+
+    def lanes(pts):
+        lm = g.from_rowmajor(C.encode(pts))
+        return jnp.tile(lm, (1, T // len(pts) + 1))[:, :T]
+
+    a1, a2 = g.normalise(lanes(left)), g.normalise(lanes(right))
+    nc, den = g._pallas_affine_pre(a1, a2)
+    nc_x, den_x = g._xla_affine_pre(a1, a2)
+    assert (np.asarray(nc) == np.asarray(nc_x)).all()
+    assert (np.asarray(den) == np.asarray(den_x)).all()
+    assert sorted(set(np.asarray(nc[CR]).tolist())) == [0, 1, 2, 3]
+    inv = g._pallas_root_inverse(den)
+    assert (np.asarray(inv) == np.asarray(g._xla_root_inverse(den))).all()
+    one = np.asarray(g._pallas_fmul(den, inv))
+    assert (np.asarray(g.F.canon_rows(one)) == g.one_col).all()
+    assert (one == np.asarray(g._xla_fmul(den, inv))).all()
+    out = np.asarray(g._pallas_affine_post(a1, a2, nc, inv))
+    assert (out == np.asarray(g._xla_affine_post(a1, a2, nc, inv))).all()
+    got = C.decode(g.to_rowmajor(g.lift(jnp.asarray(out[:, : len(left)]))))
+    assert got == [host.add(a, b) for a, b in zip(left, right)]
+
+
 def test_msm_tree_interpret_matches_host(pallas_interpret):
     from distributed_groth16_tpu.ops import refmath as rm
     from distributed_groth16_tpu.ops.limb_kernels import msm_tree

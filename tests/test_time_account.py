@@ -166,9 +166,12 @@ def test_capture_starts_with_python_tracer_off_and_job_id_annotated(
 
 MSM_SCOPES = ("msm.sort", "msm.upsweep", "msm.fenwick", "msm.combine",
               "msm.horner")
+# where a launch has a batch-affine level (ISSUE 29): its points normalised
+# once, the affine levels, and the batched inversion inside both
+MSM_AFFINE_SCOPES = ("msm.normalise", "msm.upsweep.affine", "msm.inverse")
 
 
-def _lowered_msm(kind: str) -> str:
+def _lowered_msm(kind: str, *affine_min_adds) -> str:
     g, pts = {
         "g1": (lk.lg1(), (16, 3, 16)),
         "g2": (lk.lg2(), (16, 3, 2, 16)),
@@ -177,6 +180,7 @@ def _lowered_msm(kind: str) -> str:
     return lk._MSM_TREE_JITS[kind].lower(
         g, jax.ShapeDtypeStruct(pts, jnp.uint32),
         jax.ShapeDtypeStruct((16, 16), jnp.uint32), 4, None,
+        *affine_min_adds,
     ).as_text(debug_info=True)
 
 
@@ -192,6 +196,23 @@ def test_tree_msm_program_is_named_by_group_and_scoped_by_stage(kind):
     assert _module_name(text) == f"jit__msm_tree_jit_{kind}"
     for scope in MSM_SCOPES:
         assert f"/{scope}/" in text, scope
+    # 16 points: no level reaches the rule, no affine stage is named
+    for scope in MSM_AFFINE_SCOPES:
+        assert f"/{scope}/" not in text, scope
+
+
+def test_tree_msm_with_affine_levels_names_their_stages_too():
+    """The rule's constant set to 256 adds: the two widest levels of 64
+    windows over 16 points (512 and 256 adds) are affine, the other two
+    projective, so the program holds the five stages and the three more."""
+    assert lk._affine_depth(64, 16, 256) == 2
+    text = _lowered_msm("g1", 256)
+    assert _module_name(text) == "jit__msm_tree_jit_g1"
+    for scope in MSM_SCOPES + MSM_AFFINE_SCOPES:
+        assert f"/{scope}/" in text, scope
+    # the inversion sits inside the stage that called it
+    assert "/msm.normalise/msm.inverse/" in text
+    assert "/msm.upsweep.affine/msm.inverse/" in text
 
 
 def test_tree_msm_programs_keep_their_names_and_count():

@@ -75,3 +75,30 @@ def test_group_law_kernels_compile_with_their_bodies_behind_a_jit(
         (g._horner(8, 2), (_shape(one_chip, (g.ROWS, 2)),)),
     ):
         assert "tpu_custom_call" in fn.lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("kind", ["g1", "g2"])
+def test_affine_level_kernels_compile_for_v5e_at_one_lane_tile(
+    one_chip, pallas, kind
+):
+    """The kernels of a batch-affine up-sweep level (ISSUE 29), each at one
+    lane tile: the field product, the affine add's two halves (the first
+    holds a `lax.cond` on a lane reduction: a block with no doubling skips
+    the tangent's product) and the root of the batched inversion (halves
+    folded to 128 lanes, the Fermat loop reading its factors from a VMEM
+    scratch by a dynamic leading index; Fq2 through the norm). The whole
+    32,768-point program is a hundred kernel instances and compiles in
+    some 25 s: by hand, before a chip run."""
+    g = pallas[kind]
+    CR, AR = g.CR, g.AROWS
+
+    def tile(rows):
+        return _shape(one_chip, (rows, g.tile))
+
+    for fn, args in (
+        (g._pallas_fmul, (tile(CR), tile(CR))),
+        (g._pallas_affine_pre, (tile(AR), tile(AR))),
+        (g._pallas_affine_post, (tile(AR), tile(AR), tile(CR + 1), tile(CR))),
+        (g._pallas_root_inverse, (tile(CR),)),
+    ):
+        assert "tpu_custom_call" in fn.lower(*args).compile().as_text()
