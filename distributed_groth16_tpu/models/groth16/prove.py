@@ -261,7 +261,10 @@ def prove_single(
     `wide` is the host's view of the witness that `encode_observed` made
     beside `z_mont` (`ops/msm.py`); the MSMs over z then run limb-0
     windows where its wide wires fit, and the proof is the same. The MSM
-    over h always runs all windows: its scalars fill the field.
+    over h always runs all windows: its scalars fill the field. Which of
+    the two an MSM over z took, its dispatch notes as the `route` of the
+    span it runs in (`prove.A/B/C`): a witness that fills the field reads
+    "tree" on all three.
     """
     from ...ops.msm import msm as _msm
     from ...ops.ntt import domain as _domain

@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from ..telemetry import metrics as _tm
+from ..telemetry import tracing as _tracing
 from .constants import LIMB_BITS, N_LIMBS
 from .curve import CurvePoints, g1, g2
 from .limb_kernels import WideScalars
@@ -76,6 +77,13 @@ _AFFINE_LEVELS = _tm.registry().counter(
     "Up-sweep levels that tree MSMs ran as batch-affine adds (the levels "
     "of at least limb_kernels._AFFINE_MIN_ADDS adds), summed over launches",
 )
+_LIMB0_DECLINED = _tm.registry().counter(
+    "msm_limb0_declined_total",
+    "Tree MSMs that were handed the host's view of their scalars and ran "
+    "all windows all the same, by the reason the limb-0 form was declined",
+    ("reason",),
+)
+_DECLINED_OVER_CAPACITY = _LIMB0_DECLINED.labels(reason="over_capacity")
 
 
 def _digits_for_window(scalars, w, c: int):
@@ -181,7 +189,6 @@ def _tree_group(curve: CurvePoints, n: int):
     return factory() if (use_pallas() and n >= 1024) else None
 
 
-
 def msm(curve: CurvePoints, points, scalars, window_bits: int | None = None,
         chunk: int | None = None, wide: WideScalars | None = None):
     """sum_i scalars[i] * points[i].
@@ -196,8 +203,10 @@ def msm(curve: CurvePoints, points, scalars, window_bits: int | None = None,
           it, and room for the wide scalars, the MSM runs the limb-0
           windows alone (route `msm/tree_limb0`); without it, as for
           every scalar array that lives on the device only (h, the MPC
-          round's shares), all windows (route `msm/tree`). The result is
-          the same point either way.
+          round's shares), all windows (route `msm/tree`). With it and no
+          room (a witness that fills the field), all windows too, and
+          `msm_limb0_declined_total` says so. The result is the same point
+          every way.
 
     Returns a single projective point (3,) + elem_shape.
     """
@@ -216,11 +225,22 @@ def msm(curve: CurvePoints, points, scalars, window_bits: int | None = None,
     if tree_g is not None:
         from .limb_kernels import msm_tree, takes_limb0, tree_affine_levels
 
-        if takes_limb0(tree_g, n, wide):
+        limb0 = takes_limb0(tree_g, n, wide)
+        if wide is not None:
+            # a view came: the open span (`prove.A/B/C`) says which side
+            # of the rule it put this launch on
+            span = _tracing.current()
+            if span is not None:
+                span.note(route="tree_limb0" if limb0 else "tree")
+        if limb0:
             _R_TREE_LIMB0.inc()
             _WIDE_CARRIED.inc(wide.count)
             _AFFINE_LEVELS.inc(tree_affine_levels(tree_g, n, 1))
             return msm_tree(points, scalars, group=tree_g, wide=wide)
+        if wide is not None:
+            # a view came and the rule said no: more wide scalars than the
+            # padding has slots for. A call with no view is not counted.
+            _DECLINED_OVER_CAPACITY.inc()
         _R_TREE.inc()
         _AFFINE_LEVELS.inc(tree_affine_levels(tree_g, n, scalars.shape[-1]))
         return msm_tree(points, scalars, group=tree_g)

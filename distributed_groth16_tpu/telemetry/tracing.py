@@ -227,6 +227,12 @@ class Span:
         self.t0 = 0.0
         self.annotation = annotation
 
+    def note(self, **attrs) -> None:
+        """Add attrs to the open span: a fact the work inside it learnt
+        (the route `ops/msm.py` dispatched). A new dict, since the one the
+        span was opened with may be shared (`DISPATCH`)."""
+        self.attrs = {**(self.attrs or {}), **attrs}
+
     def __enter__(self):
         parent = _CURRENT.get()
         if parent is not None:

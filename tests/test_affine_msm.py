@@ -252,6 +252,27 @@ def test_under_the_constant_the_program_is_the_all_projective_one():
     assert "msm.upsweep" in text
 
 
+def test_the_served_h_query_program_is_the_accepted_one_text_for_text():
+    """`sha256-bn254-single`'s one full-width launch: G1, 32,768 points, 32
+    windows of c = 8, four affine levels. Its lowered text (the plain-XLA
+    bodies, as every CPU test runs them) hashes to what it hashed to at PR
+    29's accepted commit: a PR that supports another deployment through
+    the same `_msm_tree` (PR 30: 65,536 points, five levels) leaves this
+    program alone, and one that means to change it says so here."""
+    import hashlib
+
+    g = lk.lg1()
+    lowered = lk._MSM_TREE_JITS["g1"].lower(
+        g, jax.ShapeDtypeStruct((32768, 3, 16), jnp.uint32),
+        jax.ShapeDtypeStruct((32768, 16), jnp.uint32), 8, None,
+    )
+    text = lowered.as_text()
+    assert lk.tree_affine_levels(g, 32768, 16) == 4
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "5c4bf12016a5e3f25a38a2f1d6fe6c06ffd5dcd69c8ba17bacb50c975fc13171"
+    )
+
+
 # -- the rule and its counter -------------------------------------------------
 
 
@@ -269,6 +290,13 @@ def test_under_the_constant_the_program_is_the_all_projective_one():
     ("g1", 1024, 16, 0),
     # 2^18 points run in window groups of 8: 4 groups of 5 levels
     ("g1", 1 << 18, 16, 20),
+    # the four MSMs of `million-chain-bn254-single` (a witness that fills
+    # the field: all 32 windows): h, A, L and B pad to 65,536, the levels
+    # of 2^20 ... 2^16 adds, in one window group
+    ("g1", 65536, 16, 5),
+    ("g1", 65002, 16, 5),
+    ("g1", 65000, 16, 5),
+    ("g2", 65002, 16, 5),
 ])
 def test_the_rule_counts_levels_of_at_least_two_to_the_sixteen_adds(
     name, n, limbs, want

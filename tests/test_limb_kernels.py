@@ -401,3 +401,95 @@ def test_msm_routes_by_the_hosts_view_and_counts_it(monkeypatch):
     c3 = _route_counts()
     assert c3["tree"] - c2["tree"] == 1
     assert c3["tree_limb0"] == c2["tree_limb0"] and c3["wide"] == c2["wide"]
+
+
+@pytest.mark.parametrize("kind", ["field"])
+def test_prove_single_takes_the_route_its_witness_asks_for(kind, monkeypatch):
+    """A whole proof on the tree path, G2 included, byte for byte the plain
+    reference prover's: a witness that fills the field is declined the
+    limb-0 form three times and runs four full-width trees (the cell
+    `million_chain_c1`). The `bits` case of the same test is in
+    tests/test_msm.py, beside the G2 limb-0 programs it needs; this one
+    takes the full-width G2 program `test_msm_tree_g2_matches_reference`
+    compiled (37 points). The body: tests/prove_routes.py."""
+    from prove_routes import check_prove_single_routes
+
+    check_prove_single_routes(kind, monkeypatch)
+
+
+def test_a_served_chain_proof_says_which_side_of_the_rule_it_stood_on(
+    monkeypatch, tmp_path
+):
+    """The same chain through `ApiServer` (POST /save_circuit, POST
+    /jobs/prove, GET /jobs/{id}/result): between two reads of /metrics one
+    proof moves `msm/tree` by 4, `msm/tree_limb0` by 0 and
+    `msm_limb0_declined_total{reason="over_capacity"}` by 3, the job's
+    trace names the route on `prove.A/B/C`, and the proof is the reference
+    prover's. The tree programs are the ones the case above compiled."""
+    import asyncio
+    import json
+    import re
+
+    from aiohttp.test_utils import TestClient, TestServer
+    from prove_routes import field_circuit
+
+    from distributed_groth16_tpu.api.server import ApiServer
+    from distributed_groth16_tpu.api.store import CircuitStore
+    from distributed_groth16_tpu.frontend.ark_serde import proof_to_bytes
+    from distributed_groth16_tpu.frontend.readers import write_r1cs, write_wtns
+    from distributed_groth16_tpu.models.groth16 import setup
+    from distributed_groth16_tpu.models.groth16.reference import prove_host
+
+    monkeypatch.setenv("DG16_FORCE_TREE_MSM", "1")
+    r1cs, z = field_circuit()
+
+    def series(text):
+        found = re.findall(
+            r'^(kernel_route_total\{kernel="msm",path="(?:tree|tree_limb0)"\}'
+            r'|msm_limb0_declined_total\{reason="over_capacity"\})\s+(\S+)$',
+            text, re.M,
+        )
+        return {name: float(v) for name, v in found}
+
+    async def run():
+        server = ApiServer(CircuitStore(str(tmp_path)))
+        client = TestClient(TestServer(server.app()))
+        await client.start_server()
+        try:
+            resp = await client.post("/save_circuit", data={
+                "circuit_name": "chain", "r1cs_file": write_r1cs(r1cs)})
+            assert resp.status == 200, await resp.text()
+            cid = (await resp.json())["circuitId"]
+            before = series(await (await client.get("/metrics")).text())
+            resp = await client.post("/jobs/prove", data={
+                "circuit_id": cid, "witness_file": write_wtns(z)})
+            assert resp.status == 202, await resp.text()
+            job = (await resp.json())["jobId"]
+            for _ in range(3000):
+                resp = await client.get(f"/jobs/{job}/result")
+                if resp.status != 409:
+                    break
+                await asyncio.sleep(0.1)
+            assert resp.status == 200, await resp.text()
+            proof = bytes((await resp.json())["proof"])
+            after = series(await (await client.get("/metrics")).text())
+            trace = await (await client.get(f"/jobs/{job}/trace")).text()
+            return proof, before, after, json.loads(trace)
+        finally:
+            await client.close()
+
+    proof, before, after, trace = asyncio.run(run())
+    assert proof == proof_to_bytes(prove_host(setup(r1cs), r1cs, z))
+    moved = {k: after[k] - before.get(k, 0.0) for k in after}
+    assert moved == {
+        'kernel_route_total{kernel="msm",path="tree"}': 4.0,
+        'kernel_route_total{kernel="msm",path="tree_limb0"}': 0.0,
+        'msm_limb0_declined_total{reason="over_capacity"}': 3.0,
+    }
+    events = trace["traceEvents"] if isinstance(trace, dict) else trace
+    told = {e["name"]: e.get("args", {}) for e in events
+            if e.get("name", "").startswith("prove.")}
+    assert [told[s]["route"] for s in ("prove.A", "prove.B", "prove.C")] \
+        == ["tree"] * 3
+    assert told["prove.A"]["wide_scalars"] == 35
+    assert "route" not in told["prove.h"]

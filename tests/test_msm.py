@@ -156,3 +156,17 @@ def test_msm_g2_limb0_windows_match_reference(monkeypatch):
     assert C.decode(out) == rm.G2.msm(pts, vals)
     assert limb0.value == before + 1
     assert lk._MSM_TREE_JITS["g2"]._cache_size() == full_programs
+
+
+@pytest.mark.parametrize("kind", ["bits"])
+def test_prove_single_takes_the_route_its_witness_asks_for(kind, monkeypatch):
+    """A whole proof on the tree path, G2 included, byte for byte the plain
+    reference prover's: a witness of bits and one wide public wire takes
+    the limb-0 form for A, B and L and the full width for h (the cells of
+    `sha256-bn254-single`). The `field` case of the same test is in
+    tests/test_limb_kernels.py, beside the full-width G2 program it needs;
+    this one takes the G2 limb-0 programs the test above compiled (37
+    points). The body: tests/prove_routes.py."""
+    from prove_routes import check_prove_single_routes
+
+    check_prove_single_routes(kind, monkeypatch)

@@ -234,6 +234,27 @@ def test_span_noop_when_idle_and_records_when_collecting():
     assert [n["name"] for n in tree[0]["children"]] == ["t.inner"]
 
 
+def test_span_note_adds_to_the_open_span_and_leaves_a_shared_dict_alone():
+    """What the work inside a span learnt (`ops/msm.py`: the route it
+    dispatched) lands on the innermost open span, opened with attrs or
+    without; the dict the span was opened with may be shared."""
+    buf = tracing.TraceBuffer()
+    with tracing.collect(buf):
+        with tracing.span("t.shared", attrs=tracing.DISPATCH):
+            tracing.current().note(route="tree")
+        with tracing.span("t.bare"):
+            tracing.current().note(route="tree_limb0")
+            with tracing.span("t.child"):
+                pass
+    told = {e["name"]: e["args"] for e in buf.events()}
+    assert told["t.shared"]["route"] == "tree"
+    assert told["t.shared"]["clock"] == "dispatch"
+    assert told["t.bare"]["route"] == "tree_limb0"
+    assert "route" not in told["t.child"]
+    assert tracing.DISPATCH == {"clock": "dispatch"}
+    assert tracing.current() is None
+
+
 def test_span_records_timings_without_buffer():
     t = timers.PhaseTimings()
     with timers.phase("t-phase", t):
