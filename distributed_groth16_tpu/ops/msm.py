@@ -310,7 +310,12 @@ def msm_g2(points, scalars, **kw):
 def encode_observed(F, values) -> tuple[jnp.ndarray, WideScalars]:
     """`F.encode(values)` and, from the same reduced integers, the view
     of them that `msm(..., wide=)` takes: the one place the two are made,
-    so that a view cannot disagree with the scalars it describes."""
+    so that a view cannot disagree with the scalars it describes.
+
+    The job's `encode` phase is this call. It costs one reduction pass,
+    `F.encode` (a `to_bytes` a value, one buffer) and the view (one pass
+    that finds the wide values, one buffer of those): all three follow n,
+    the view's buffer K, and none runs a Python loop per limb."""
     reduced = [int(v) % F.p for v in values]
     return F.encode(reduced), WideScalars.observe(reduced)
 

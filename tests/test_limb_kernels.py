@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from distributed_groth16_tpu.ops.constants import G1_GENERATOR, Q, R
+from distributed_groth16_tpu.ops.constants import G1_GENERATOR, Q, R, to_limbs
 from distributed_groth16_tpu.ops.curve import g1
 from distributed_groth16_tpu.ops.field import fq
 from distributed_groth16_tpu.ops.limb_kernels import lfq, lg1, msm_tree, _digits
@@ -342,6 +342,73 @@ def test_msm_tree_limb0_matches_full_width_and_reference(case):
     assert got == host.msm(pts, vals)
     if (name, n) in (("g1", 300), ("g2", 37)):
         assert got == C.decode(msm_tree(P, sc, group=g)[None])[0]
+
+
+def _field_filling(n):
+    rng = np.random.default_rng(31)
+    return [int.from_bytes(rng.bytes(32), "little") % R for _ in range(n)]
+
+
+def _bits_wide_at_both_ends(n):
+    vals = [int(b) for b in np.random.default_rng(32).integers(0, 2, size=n)]
+    vals[0], vals[-1] = R - 2, _WIDE_128
+    return vals
+
+
+# What `WideScalars.observe` sees, from nothing to the chain cell's witness.
+_VIEW_CASES = {
+    "empty": lambda: [],
+    "all-narrow": lambda: [0, 1, 65535, 2, 40000] * 7,
+    "value-0": lambda: [0],
+    "value-2^16-1": lambda: [(1 << 16) - 1],
+    "value-2^16": lambda: [1 << 16],
+    "value-r-1": lambda: [R - 1],
+    "value-2^256-1": lambda: [(1 << 256) - 1],
+    "bits-wide-first-and-last": lambda: _bits_wide_at_both_ends(27627),
+    "field-65002": lambda: _field_filling(65002),
+}
+
+
+def _observe_by_definition(values):
+    """The view as PR 24 defined it, a limb at a time: the reference."""
+    idx = [i for i, v in enumerate(values) if v >> 16]
+    limbs = [to_limbs(values[i] >> 16, 15) for i in idx]
+    return len(values), idx, np.asarray(limbs, np.int64).reshape(len(idx), 15)
+
+
+@pytest.mark.parametrize("case", sorted(_VIEW_CASES))
+def test_wide_scalars_view_is_the_definitions_element_for_element(case):
+    """Host only, no program compiled: the buffer-built view against the
+    limb-at-a-time definition, and `tail(k)` against the view of
+    `values[k:]`, in every field, dtype and shape."""
+    from distributed_groth16_tpu.ops import limb_kernels as lk
+
+    values = _VIEW_CASES[case]()
+    view = lk.WideScalars.observe(values)
+    for k in sorted({0, min(1, len(values)), len(values)}):
+        n, idx, limbs = _observe_by_definition(values[k:])
+        got = view.tail(k)
+        assert got.n == n and got.count == len(idx)
+        assert got.idx.dtype == np.int32 and got.idx.shape == (len(idx),)
+        assert got.idx.tolist() == idx
+        assert got.limbs.dtype == np.uint32 and got.limbs.shape == (len(idx), 15)
+        assert np.array_equal(got.limbs, limbs)
+    assert view.limbs.flags.c_contiguous
+
+
+def test_wide_scalars_view_is_built_without_a_python_loop_per_limb(monkeypatch):
+    """The mechanism: no `to_limbs` a wide value. A thousand wide values
+    are observed with the limb-at-a-time helper made to raise."""
+    from distributed_groth16_tpu.ops import limb_kernels as lk
+
+    def raises(*a, **kw):
+        raise AssertionError("observe split a value limb by limb")
+
+    monkeypatch.setattr(lk, "to_limbs", raises)
+    values = _field_filling(1000)
+    view = lk.WideScalars.observe(values)
+    assert view.count == sum(1 for v in values if v >> 16) >= 999
+    assert view.limbs[0].tolist() == to_limbs(values[view.idx[0]] >> 16, 15)
 
 
 def _route_counts():

@@ -128,6 +128,34 @@ print("BATCHED_OK")
     assert "BATCHED_OK" in r.stdout
 
 
+_ENCODE_OBSERVED_CASES = {
+    "bits": lambda rng: [rng.randrange(2) for _ in range(300)] + [R - 3],
+    "field-and-above-r": lambda rng: (
+        [rng.randrange(R) for _ in range(500)] + [R, R + (1 << 16), 2 * R - 1]),
+    "empty": lambda rng: [],
+}
+
+
+@pytest.mark.parametrize("case", sorted(_ENCODE_OBSERVED_CASES))
+def test_encode_observed_uploads_what_encode_does_and_views_the_same_integers(
+        case):
+    """The witness-upload boundary: `z_mont` is `F.encode` of the same
+    values limb for limb, and the view is of those values reduced."""
+    from distributed_groth16_tpu.ops.constants import from_limbs
+    from distributed_groth16_tpu.ops.field import fr
+    from distributed_groth16_tpu.ops.msm import encode_observed
+
+    vals = _ENCODE_OBSERVED_CASES[case](random.Random(31))
+    z_mont, view = encode_observed(fr(), vals)
+    assert z_mont.dtype == np.uint32 and z_mont.shape == (len(vals), 16)
+    assert np.array_equal(np.asarray(z_mont), fr().encode_np(vals))
+    reduced = [v % R for v in vals]
+    assert view.n == len(vals)
+    assert view.idx.tolist() == [i for i, v in enumerate(reduced) if v >> 16]
+    for i, row in zip(view.idx, view.limbs):
+        assert from_limbs(row) == reduced[i] >> 16
+
+
 def test_msm_g2_limb0_windows_match_reference(monkeypatch):
     """The G2 case of tests/test_limb_kernels.py's limb-0 cases (here so
     that its minutes of XLA:CPU compile run beside that file's, not after
