@@ -34,7 +34,7 @@ import jax.numpy as jnp
 from ..ops.field import fr
 from ..ops.ntt import bitrev_perm, domain
 from ..telemetry import tracing as _tracing
-from .net import Net
+from .net import Net, king_section
 from .pss import PackedSharingParams
 
 log = logging.getLogger(__name__)
@@ -198,14 +198,18 @@ async def _d_transform(
             # and scattering here would be immediately undone by a gather).
             if not net.is_king:
                 return None
-            return _king_clear_array(
-                jnp.stack(gathered, axis=0), pp, logm, degree2, inverse, wpows
-            )
+            with king_section("dfft"):
+                return _king_clear_array(
+                    jnp.stack(gathered, axis=0), pp, logm, degree2, inverse,
+                    wpows,
+                )
         out = None
         if net.is_king:
-            out = _king_tail(
-                gathered, pp, logm, rearrange, pad, degree2, inverse, wpows
-            )
+            with king_section("dfft"):
+                out = _king_tail(
+                    gathered, pp, logm, rearrange, pad, degree2, inverse,
+                    wpows,
+                )
         return await net.scatter_from_king(out, sid)
 
 

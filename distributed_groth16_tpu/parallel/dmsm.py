@@ -18,7 +18,7 @@ from ..ops.curve import CurvePoints
 from ..ops.field import fr
 from ..ops.msm import msm
 from ..telemetry import tracing as _tracing
-from .net import Net
+from .net import Net, king_section
 from .pss import PackedSharingParams
 
 log = logging.getLogger(__name__)
@@ -55,9 +55,10 @@ async def d_msm(
         def king(points):
             import jax.numpy as jnp
 
-            stacked = jnp.stack(points, axis=0)  # (n, 3) + elem
-            partials = pp.unpackexp(curve, stacked, degree2=True)  # (l, 3)+
-            total = curve.sum(partials, axis=0)
+            with king_section("dmsm"):
+                stacked = jnp.stack(points, axis=0)  # (n, 3) + elem
+                partials = pp.unpackexp(curve, stacked, degree2=True)  # (l, 3)+
+                total = curve.sum(partials, axis=0)
             return [total] * pp.n
 
         return await net.king_compute(local, king, sid)

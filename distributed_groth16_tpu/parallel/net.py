@@ -95,6 +95,35 @@ _ROUND_FAILURES = _REG.counter(
     "MPC rounds abandoned after exhausting retries",
 )
 
+_KING_SECONDS = _REG.counter(
+    "mpc_king_seconds_total",
+    "Wall seconds of the king's own function (between a gather and the "
+    "scatter that follows it; the device's back-pressure included), per "
+    "distributed kernel",
+    ("stage",),
+)
+_KING = {stage: _KING_SECONDS.labels(stage=stage) for stage in ("dmsm", "dfft")}
+
+
+@contextmanager
+def king_section(stage: str):
+    """The clock round the king's own function of one distributed kernel
+    (`stage`: "dmsm", "dfft"): what party 0 alone computes between a
+    gather and the scatter that follows. A span `<stage>.king` on party 0,
+    and the body's wall added to `mpc_king_seconds_total{stage=}` (bound
+    above, so both series print 0 unraised). Wall time, not `clock:
+    "dispatch"`: the body only enqueues device work, but on the chip its
+    eager ops return only as the device catches up, so the wall holds the
+    device's back-pressure as well as the king's Python (PERF.md, PR 32).
+    The other parties' `net.king_compute` waits for it."""
+    t0 = time.perf_counter()
+    try:
+        with _tracing.span(stage + ".king", party=0):
+            yield
+    finally:
+        _KING[stage].inc(time.perf_counter() - t0)
+
+
 # The job the current dynamic extent is proving for, threaded by the
 # service layer (service/worker.py) so a transport failure deep inside a
 # collective names the job that died. Contextvars flow into asyncio tasks

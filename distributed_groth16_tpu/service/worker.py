@@ -288,11 +288,16 @@ class ProofExecutor:
             pp = PackedSharingParams(job.l)
             job.note_phase("packing")
             with phase("packing", timings):
-                qap_shares = circ.comp.qap(z_mont).pss(pp)
-                crs_shares = self.packed_crs(job, pk, pp)
-                ni = r1cs.num_instance
-                a_sh = pack_from_witness(pp, z_mont[1:])
-                ax_sh = pack_from_witness(pp, z_mont[ni:])
+                with phase("packing.qap", timings):
+                    qap_shares = circ.comp.qap(z_mont).pss(pp)
+                with phase("packing.crs", timings):
+                    # a cache hit for a circuit proved before; the pack
+                    # itself on a miss
+                    crs_shares = self.packed_crs(job, pk, pp)
+                with phase("packing.witness", timings):
+                    ni = r1cs.num_instance
+                    a_sh = pack_from_witness(pp, z_mont[1:])
+                    ax_sh = pack_from_witness(pp, z_mont[ni:])
             job.check_cancel()
 
             async def party(net, d):
@@ -311,18 +316,25 @@ class ProofExecutor:
 
             job.note_phase("MPC Proof")
             with phase("MPC Proof", timings):
-                res = run_round_with_retries(
-                    pp.n,
-                    party,
-                    [
-                        (crs_shares[i], qap_shares[i], a_sh[i], ax_sh[i])
-                        for i in range(pp.n)
-                    ],
-                    retries=self.cfg.round_retries,
-                )
-                # the host's wait for the round's device work: decoding
-                # adds no span, so the critical-path window is unchanged
-                proof = reassemble_proof(res[0], pk)
+                # the host issuing the eight parties' work: nothing in
+                # the round reads a device value, yet on the chip its
+                # eager ops return only as the device catches up, so
+                # this wall follows the device's (PERF.md, PR 32)
+                with phase("MPC Proof.round", timings):
+                    res = run_round_with_retries(
+                        pp.n,
+                        party,
+                        [
+                            (crs_shares[i], qap_shares[i], a_sh[i], ax_sh[i])
+                            for i in range(pp.n)
+                        ],
+                        retries=self.cfg.round_retries,
+                    )
+                # the host's wait for the round's device work, then the
+                # decoding; it lies after the round's close, so the
+                # critical-path window is unchanged
+                with phase("MPC Proof.reassemble", timings):
+                    proof = reassemble_proof(res[0], pk)
         else:
             raise ValueError(f"unknown job kind {job.kind!r}")
         job.check_cancel()

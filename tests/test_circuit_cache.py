@@ -13,6 +13,8 @@ CPU, tiny circuits: these check behaviour, never a speed.
 
 import asyncio
 import os
+import subprocess
+import sys
 import threading
 import time
 
@@ -31,6 +33,7 @@ from distributed_groth16_tpu.models.groth16 import (
     pack_proving_key,
 )
 from distributed_groth16_tpu.models.groth16.prove import prove_single
+from distributed_groth16_tpu.models.groth16.reference import prove_host
 from distributed_groth16_tpu.models.groth16.setup import setup
 from distributed_groth16_tpu.ops.field import fr
 from distributed_groth16_tpu.parallel.pss import PackedSharingParams
@@ -271,22 +274,147 @@ def test_a_second_job_is_a_hit_and_its_proof_is_the_same_bytes(saved):
     assert bytes(first["proof"]) == proof_to_bytes(fresh)
 
 
-def test_two_mpc_proofs_on_one_entry_leave_it_as_it_was(saved):
-    """`packed_crs` packs with `strip=True`: that may clear a dealer's
-    scalars and nothing of a key read from disk."""
+MPC_TOP_LEVEL = ("load", "witness", "encode", "packing", "MPC Proof",
+                 "serialize")
+MPC_CHILDREN = {
+    "packing": ("packing.qap", "packing.crs", "packing.witness"),
+    "MPC Proof": ("MPC Proof.round", "MPC Proof.reassemble"),
+}
+
+
+def _king_seconds():
+    return {
+        k[0]: c.value
+        for k, c in tm.registry().family("mpc_king_seconds_total").items()
+    }
+
+
+@pytest.fixture(scope="module")
+def mpc_jobs(saved):
+    """Two served `mpc_prove` jobs on one resident entry, then the `prove`
+    job of the same witness: the one compiled round the cases below share.
+    Of the second MPC job (a packed-CRS hit, as every job of a benchmark
+    window is) it keeps the result, the wall round `ProofExecutor.run`,
+    what its trace buffer holds and how the king's counter moved."""
     root, circuits = saved
-    cid, _, z = circuits["nine"]
+    cid, r1cs, z = circuits["nine"]
     ex = _executor(root)
     entry = ex.circuit(cid)
     held = dict(vars(entry.pk))
+    first = ex.run(_job(cid, z, "mpc_prove"))
+    job = _job(cid, z, "mpc_prove")
+    before = _king_seconds()
+    t0 = time.perf_counter()
+    second = ex.run(job)
+    wall_ms = 1e3 * (time.perf_counter() - t0)
+    moved = {k: v - before[k] for k, v in _king_seconds().items()}
+    return {
+        "ex": ex, "cid": cid, "r1cs": r1cs, "z": z, "entry": entry,
+        "held": held, "results": (first, second), "wall_ms": wall_ms,
+        "events": job.trace.events(), "king_moved": moved,
+        "single": ex.run(_job(cid, z)),
+    }
+
+
+def test_two_mpc_proofs_on_one_entry_leave_it_as_it_was(mpc_jobs):
+    """`packed_crs` packs with `strip=True`: that may clear a dealer's
+    scalars and nothing of a key read from disk."""
+    ex, entry, held = mpc_jobs["ex"], mpc_jobs["entry"], mpc_jobs["held"]
     assert held["query_scalars"] is None
-    proofs = [ex.run(_job(cid, z, "mpc_prove"))["proof"] for _ in range(2)]
-    assert ex.circuit(cid) is entry
+    proofs = [r["proof"] for r in mpc_jobs["results"]]
+    assert ex.circuit(mpc_jobs["cid"]) is entry
     assert all(vars(entry.pk)[k] is v for k, v in held.items())
     assert proofs[0] == proofs[1]
     # r = s = 0: the single-node proof of the same witness, byte for byte
-    assert proofs[0] == ex.run(_job(cid, z))["proof"]
+    assert proofs[0] == mpc_jobs["single"]["proof"]
     assert ex.crs_cache.stats()["misses"] == 1
+
+
+def test_a_served_mpc_proof_is_the_host_provers_bytes(mpc_jobs):
+    """The served path (`ProofExecutor.run`, kind `mpc_prove`) against the
+    reference prover, as `test_groth16.py` holds the bare round to it."""
+    r1cs, z = mpc_jobs["r1cs"], mpc_jobs["z"]
+    want = proof_to_bytes(prove_host(setup(r1cs), r1cs, z))
+    assert bytes(mpc_jobs["results"][1]["proof"]) == want
+
+
+def test_an_mpc_jobs_phases_hold_the_rounds_account(mpc_jobs):
+    """ISSUE 32: `packing` and `MPC Proof` have children under dotted keys,
+    so the top-level keys still partition the job."""
+    phases = mpc_jobs["results"][1]["phases"]
+    dotted = {c for cs in MPC_CHILDREN.values() for c in cs}
+    assert set(MPC_TOP_LEVEL) | dotted <= set(phases)
+    assert {k for k in phases if "." not in k} == set(MPC_TOP_LEVEL)
+    # the phases lie inside `ProofExecutor.run`'s wall (as_millis rounds
+    # each); how much of it they name depends on the machine's load, so
+    # only a gross loss is held against
+    named = sum(phases[k] for k in MPC_TOP_LEVEL)
+    wall_ms = mpc_jobs["wall_ms"]
+    assert 0.75 * wall_ms - 50.0 <= named <= wall_ms + 1.0, (named, wall_ms)
+
+
+@pytest.mark.parametrize("parent", sorted(MPC_CHILDREN))
+def test_an_mpc_phase_is_its_children_and_little_else(mpc_jobs, parent):
+    phases = mpc_jobs["results"][1]["phases"]
+    children = sum(phases[c] for c in MPC_CHILDREN[parent])
+    # structural: the children lie inside their parent (as_millis rounds
+    # each phase to a microsecond). Between them lie an assignment or two,
+    # which a loaded machine can stretch: loose on that side
+    assert children <= phases[parent] + 0.01
+    assert children >= 0.75 * phases[parent] - 50.0
+
+
+@pytest.mark.parametrize("name,count", [("dmsm.king", 4), ("dfft.king", 6)])
+def test_the_kings_own_function_is_a_span_on_party_0_only(
+    mpc_jobs, name, count
+):
+    """With r = s = 0 a round runs four d_msms (A, B and C's two) and
+    `ext_wit.h` three d_iffts and three d_ffts: the king's part of each is
+    one span, on the wall clock (on the chip its eager ops wait for the
+    device: PERF.md, PR 32), inside the kernel's own span."""
+    events = mpc_jobs["events"]
+    found = [e for e in events if e["name"] == name]
+    assert len(found) == count
+    assert {e["pid"] for e in found} == {0}
+    assert all("clock" not in e["args"] for e in found)
+    kernel = name.split(".")[0]
+    outer = [
+        (e["ts"], e["ts"] + e["dur"]) for e in events
+        if e["pid"] == 0 and e["name"] in (kernel, f"{kernel}.fft",
+                                           f"{kernel}.ifft")
+    ]
+    assert len(outer) == count
+    for e in found:
+        assert any(lo <= e["ts"] and e["ts"] + e["dur"] <= hi
+                   for lo, hi in outer)
+
+
+def test_the_kings_counter_moves_by_the_spans_wall(mpc_jobs):
+    moved = mpc_jobs["king_moved"]
+    assert set(moved) == {"dmsm", "dfft"}
+    for stage, seconds in moved.items():
+        spans = sum(e["dur"] for e in mpc_jobs["events"]
+                    if e["name"] == f"{stage}.king") / 1e6
+        # structural: the counter's clock starts before the span's and
+        # ends after it. What lies between the two clocks is a span's
+        # opening and closing, ten times a round: loose on that side
+        assert spans <= seconds + 1e-4
+        assert seconds <= 1.25 * spans + 0.05
+
+
+def test_the_kings_counter_prints_zero_before_any_round():
+    """Both series are bound when `parallel/net.py` is imported, so the
+    benchmark's first /metrics text holds them unraised."""
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "from distributed_groth16_tpu.parallel import net\n"
+         "from distributed_groth16_tpu.telemetry import metrics\n"
+         "print(metrics.registry().render_prometheus())"],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
+        text=True, timeout=JOIN_S, check=True,
+    ).stdout.splitlines()
+    assert 'mpc_king_seconds_total{stage="dfft"} 0' in out
+    assert 'mpc_king_seconds_total{stage="dmsm"} 0' in out
 
 
 def test_strip_clears_the_dealers_scalars_and_nothing_else(saved):
