@@ -32,7 +32,7 @@ from ...telemetry import tracing as _tracing
 from .ext_wit import h as ext_wit_h
 from .keys import Proof, ProvingKey
 from .proving_key import PackedProvingKeyShare
-from .qap import PackedQAPShare
+from .qap import PackedQAPShare, require_satisfied
 
 
 def _maybe_mul(curve: CurvePoints, p, k: int):
@@ -265,6 +265,11 @@ def prove_single(
     the two an MSM over z took, its dispatch notes as the `route` of the
     span it runs in (`prove.A/B/C`): a witness that fills the field reads
     "tree" on all three.
+
+    A witness that does not satisfy the circuit makes no proof: the
+    device decides it beside the QAP (`compiled.satisfied`), and the
+    verdict is read once the witness map is queued behind it and before
+    any MSM is, in `prove.check`. ValueError, as the service reports it.
     """
     from ...ops.msm import msm as _msm
     from ...ops.ntt import domain as _domain
@@ -286,6 +291,7 @@ def prove_single(
 
     with _tracing.span("prove.qap", attrs=enqueue):
         qap = compiled.qap(z_mont)
+        ok = compiled.satisfied(z_mont, qap)
     with _tracing.span("prove.h", attrs=enqueue):
         m = pk.domain_size
         dom = _domain(m)
@@ -295,6 +301,10 @@ def prove_single(
         q_ev = dom_shift.fft(dom.ifft(qap.b))
         w_ev = dom_shift.fft(dom.ifft(qap.c))
         h_vec = F.sub(F.mul(p_ev, q_ev), w_ev)  # (m, 16) Montgomery
+    # the host waits for the upload and the QAP's products, with the NTTs
+    # queued behind them to keep the chip fed: wall time, like the decode
+    with _tracing.span("prove.check"):
+        require_satisfied(ok)
 
     z_std = F.from_mont(z_mont)
     ni = pk.num_instance

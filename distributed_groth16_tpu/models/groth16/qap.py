@@ -30,6 +30,20 @@ from ...ops.field import fr
 from ...ops.ntt import JaxDomain, domain
 from ...parallel.packing import pack_strided
 from ...parallel.pss import PackedSharingParams
+from ...telemetry import metrics as _tm
+
+_CHECKS = _tm.registry().counter(
+    "witness_device_checks_total",
+    "Witnesses whose satisfiability was decided on the device, from the "
+    "QAP evaluations their own proof uses, by the verdict that was read",
+    ("verdict",),
+)
+# bound at import, so that an unraised verdict still prints 0
+_CHECK_OK = _CHECKS.labels(verdict="ok")
+_CHECK_REJECTED = _CHECKS.labels(verdict="rejected")
+# what a refused witness fails with, whichever check refused it (the form's
+# on the host, `service/worker.py`, or the rows' here)
+UNSATISFIED = "witness does not satisfy the circuit"
 
 
 def _next_pow2(x: int) -> int:
@@ -93,6 +107,23 @@ def _matvec_jit(coeffs, cols, ends_idx, starts_idx, nonempty, at_origin, z):
     return jnp.where(nonempty[:, None], val, jnp.zeros_like(val))
 
 
+@jax.jit
+def _rows_equal(cz, c):
+    return jnp.all(cz == c[: cz.shape[0]])
+
+
+def require_satisfied(flag) -> None:
+    """Read `CompiledR1CS.satisfied`'s verdict from the device (the host
+    waits here for the QAP's products, and for nothing queued behind
+    them) and refuse a witness that failed it, before it can become a
+    proof that does not verify. The one place the verdict is read."""
+    if bool(flag):
+        _CHECK_OK.inc()
+        return
+    _CHECK_REJECTED.inc()
+    raise ValueError(UNSATISFIED)
+
+
 @dataclass
 class QAP:
     """Evaluated QAP vectors on device (groth16/src/qap.rs:17-29)."""
@@ -143,6 +174,7 @@ class CompiledR1CS:
         self.domain_size = _next_pow2(self.num_constraints + self.num_inputs)
         self.A = SparseMatrixDevice.build(r1cs.a)
         self.B = SparseMatrixDevice.build(r1cs.b)
+        self.C = SparseMatrixDevice.build(r1cs.c)
 
     @cached_property
     def dom(self) -> JaxDomain:
@@ -166,6 +198,21 @@ class CompiledR1CS:
             c=c,
             domain=self.dom,
         )
+
+    def satisfied(self, z_mont: jnp.ndarray, qap: QAP) -> jnp.ndarray:
+        """Whether <A_j, z> * <B_j, z> == <C_j, z> in every row j, as one
+        device boolean: `R1CS.is_satisfied`'s verdict on the values of
+        `z_mont`, from the products `qap = self.qap(z_mont)` already holds
+        (`qap.c[:nc]` is `a * b` row for row) and one more matrix-vector
+        product, `C z`. Nothing is read back here; `require_satisfied`
+        reads it.
+
+        The comparison is limb-wise equality of the Montgomery forms.
+        Every `PrimeField` op returns the canonical representative (< r)
+        of its class for canonical inputs, `F.encode` reduces mod r as
+        `eval_lc` does, and x -> x * 2^256 mod r is a bijection of
+        [0, r): equal limbs are equal field elements and no others are."""
+        return _rows_equal(self.C.matvec(z_mont), qap.c)
 
 
 def qap_from_r1cs(r1cs: R1CS, assignment: list[int]) -> QAP:

@@ -31,6 +31,7 @@ from ..models.groth16 import (
 )
 from ..models.groth16.mesh_prover import build_batch_mesh_prover
 from ..models.groth16.prove import PartyProofShare
+from ..models.groth16.qap import require_satisfied
 from ..ops.field import fr
 from ..service.jobs import JobCancelled
 from ..parallel.pss import PackedSharingParams
@@ -199,8 +200,14 @@ class BatchProver:
                     t_w = time.monotonic()
                     z = self.executor.resolve_witness(job, r1cs)
                     job.timings.record("witness", time.monotonic() - t_w)
+                    z_mont = F.encode(z)
+                    # the device's verdict, read job by job: a bad
+                    # witness drops out of the batch alone
+                    require_satisfied(
+                        comp.satisfied(z_mont, comp.qap(z_mont))
+                    )
                     good.append(job)
-                    z_monts.append(F.encode(z))
+                    z_monts.append(z_mont)
                 except BaseException as e:  # noqa: BLE001 — per-job outcome
                     outcomes.append((job, e))
                     _BATCH_JOBS.labels(

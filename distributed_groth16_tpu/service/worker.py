@@ -34,6 +34,7 @@ from ..models.groth16 import (
 )
 from ..models.groth16.keys import ProvingKey
 from ..models.groth16.prove import prove_single
+from ..models.groth16.qap import UNSATISFIED, require_satisfied
 from ..ops.field import fr
 from ..ops.msm import encode_observed
 from ..parallel.net import job_context, run_round_with_retries
@@ -62,14 +63,14 @@ class ResidentCircuit:
     a key; `pack_proving_key(strip=True)` clears only the dealer scalars,
     which a key read from disk does not carry)."""
 
-    r1cs: R1CS  # `witness.check` runs `is_satisfied` on it
-    comp: CompiledR1CS  # A and B on the device
+    r1cs: R1CS  # `witness.check` holds a witness to its wire count
+    comp: CompiledR1CS  # A, B and C on the device
     pk: ProvingKey  # its seven arrays on the device
 
     def device_bytes(self) -> int:
         held = [
             v
-            for obj in (self.pk, self.comp.A, self.comp.B)
+            for obj in (self.pk, self.comp.A, self.comp.B, self.comp.C)
             for v in vars(obj).values()
         ]
         return sum(v.nbytes for v in held if isinstance(v, jax.Array))
@@ -157,12 +158,17 @@ class ProofExecutor:
         """Resolve + validate a job's witness assignment. Public because
         the batching scheduler's BatchProver resolves each batched job's
         witness through the same path (scheduler/batch_prover.py). The
-        two halves are the phases `witness.parse` and `witness.check`."""
+        two halves are the phases `witness.parse` and `witness.check`.
+        The check here is of the witness's form, before anything is
+        uploaded: what `R1CS.is_satisfied` refuses ahead of its rows.
+        The rows are the device's, decided from the QAP evaluations of
+        the job's own proof (`CompiledR1CS.satisfied`) and read by
+        `require_satisfied`, with the same error."""
         with phase("witness.parse", job.timings):
             z = self._parse_witness(job)
         with phase("witness.check", job.timings):
-            if len(z) != r1cs.num_wires or not r1cs.is_satisfied(z):
-                raise ValueError("witness does not satisfy the circuit")
+            if len(z) != r1cs.num_wires or z[0] != 1:
+                raise ValueError(UNSATISFIED)
         return z
 
     def _parse_witness(self, job: ProofJob) -> list[int]:
@@ -289,7 +295,9 @@ class ProofExecutor:
             job.note_phase("packing")
             with phase("packing", timings):
                 with phase("packing.qap", timings):
-                    qap_shares = circ.comp.qap(z_mont).pss(pp)
+                    qap = circ.comp.qap(z_mont)
+                    ok = circ.comp.satisfied(z_mont, qap)
+                    qap_shares = qap.pss(pp)
                 with phase("packing.crs", timings):
                     # a cache hit for a circuit proved before; the pack
                     # itself on a miss
@@ -298,6 +306,10 @@ class ProofExecutor:
                     ni = r1cs.num_instance
                     a_sh = pack_from_witness(pp, z_mont[1:])
                     ax_sh = pack_from_witness(pp, z_mont[ni:])
+                # the verdict of `packing.qap`, read with the packing
+                # queued behind it: a bad witness never starts a round
+                with phase("packing.check", timings):
+                    require_satisfied(ok)
             job.check_cancel()
 
             async def party(net, d):

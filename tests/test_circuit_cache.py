@@ -26,7 +26,7 @@ import distributed_groth16_tpu
 from distributed_groth16_tpu.api.server import ApiServer
 from distributed_groth16_tpu.api.store import CircuitStore
 from distributed_groth16_tpu.frontend.ark_serde import proof_to_bytes
-from distributed_groth16_tpu.frontend.r1cs import mult_chain_circuit
+from distributed_groth16_tpu.frontend.r1cs import R1CS, mult_chain_circuit
 from distributed_groth16_tpu.frontend.readers import write_r1cs, write_wtns
 from distributed_groth16_tpu.models.groth16 import (
     CompiledR1CS,
@@ -250,7 +250,8 @@ def test_an_entry_weighs_its_keys_and_its_matrices_arrays(saved):
         circ.pk.b_g1_query, circ.pk.b_g2_query, circ.pk.h_query,
         circ.pk.l_query,
     ]
-    for m in (circ.comp.A, circ.comp.B):
+    # C too (ISSUE 33): the device's witness check reads it every job
+    for m in (circ.comp.A, circ.comp.B, circ.comp.C):
         arrays += [m.coeffs, m.cols, m.ends_idx, m.starts_idx, m.nonempty,
                    m.at_origin]
     assert all(isinstance(a, jax.Array) for a in arrays)
@@ -277,7 +278,8 @@ def test_a_second_job_is_a_hit_and_its_proof_is_the_same_bytes(saved):
 MPC_TOP_LEVEL = ("load", "witness", "encode", "packing", "MPC Proof",
                  "serialize")
 MPC_CHILDREN = {
-    "packing": ("packing.qap", "packing.crs", "packing.witness"),
+    "packing": ("packing.qap", "packing.crs", "packing.witness",
+                "packing.check"),
     "MPC Proof": ("MPC Proof.round", "MPC Proof.reassemble"),
 }
 
@@ -415,6 +417,129 @@ def test_the_kings_counter_prints_zero_before_any_round():
     ).stdout.splitlines()
     assert 'mpc_king_seconds_total{stage="dfft"} 0' in out
     assert 'mpc_king_seconds_total{stage="dmsm"} 0' in out
+
+
+# -- the device's verdict on a served witness (ISSUE 33) ----------------------
+# here, beside the compiled prover and round that `mpc_jobs` holds; the
+# verdict itself is held to `is_satisfied` in test_witness_device_check.py
+
+
+def _device_checks():
+    fam = tm.registry().family("witness_device_checks_total")
+    return {k[0]: c.value for k, c in fam.items()}
+
+
+def _off_the_served_path(monkeypatch):
+    def refuse(self, z):
+        raise AssertionError("R1CS.is_satisfied ran on the served path")
+
+    monkeypatch.setattr(R1CS, "is_satisfied", refuse)
+
+
+@pytest.mark.parametrize("kind,never_opened", [
+    ("prove", ("prove.A", "prove.B", "prove.C", "prove.decode")),
+    ("mpc_prove", ("prove.party", "MPC Proof", "MPC Proof.round")),
+])
+def test_a_bad_witness_is_refused_by_the_devices_verdict_alone(
+    mpc_jobs, monkeypatch, kind, never_opened
+):
+    """FAILED with the message it always had, before an MSM is enqueued
+    (`prove`) or a round is entered (`mpc_prove`); the executor and the
+    resident entry serve the next job."""
+    _off_the_served_path(monkeypatch)
+    ex, cid, z = mpc_jobs["ex"], mpc_jobs["cid"], mpc_jobs["z"]
+    bad = list(z)
+    bad[-1] = (bad[-1] + 1) % fr().p
+    job = _job(cid, bad, kind)
+    before = _device_checks()
+    with pytest.raises(
+        ValueError, match="^witness does not satisfy the circuit$"
+    ):
+        ex.run(job)
+    moved = {k: v - before[k] for k, v in _device_checks().items()}
+    assert moved == {"ok": 0, "rejected": 1}
+    names = {e["name"] for e in job.trace.events()}
+    assert not names & set(never_opened)
+    # where the verdict was read, after the form's own check passed
+    read_in = "prove.check" if kind == "prove" else "packing.check"
+    assert {"witness.check", read_in} <= names
+    assert ex.circuit(cid) is mpc_jobs["entry"]
+    good = ex.run(_job(cid, z, kind))
+    assert good["proof"] == mpc_jobs["single"]["proof"]
+    moved = {k: v - before[k] for k, v in _device_checks().items()}
+    assert moved == {"ok": 1, "rejected": 1}
+
+
+def test_a_witness_of_the_wrong_form_is_refused_before_the_upload(mpc_jobs):
+    ex, cid, z = mpc_jobs["ex"], mpc_jobs["cid"], mpc_jobs["z"]
+    before = _device_checks()
+    for bad in (z[:-1], z + [0], [2] + z[1:]):
+        job = _job(cid, bad)
+        with pytest.raises(ValueError, match="does not satisfy"):
+            ex.run(job)
+        names = {e["name"] for e in job.trace.events()}
+        assert "witness.check" in names and "encode" not in names
+    assert _device_checks() == before
+
+
+def test_a_bad_witness_fails_alone_in_a_batch(mpc_jobs, monkeypatch):
+    """`BatchProver.run_batch` reads each job's verdict before the job
+    joins the batch. The mesh program is stood in for by the sequential
+    prover (tests/test_scheduler.py holds the two to the same bytes)."""
+    from types import SimpleNamespace
+
+    import numpy as np
+
+    from distributed_groth16_tpu.scheduler import batch_prover as bp
+    from distributed_groth16_tpu.scheduler.bucketer import BucketKey
+
+    _off_the_served_path(monkeypatch)
+    ex, cid, z = mpc_jobs["ex"], mpc_jobs["cid"], mpc_jobs["z"]
+    proved = []
+
+    def sequential(pk, comp, pp, mesh, crs_shares, z_monts, prover=None):
+        proved.append(len(z_monts))
+        return [prove_single(pk, comp, zm) for zm in z_monts]
+
+    monkeypatch.setattr(bp, "build_batch_mesh_prover", lambda *a: None)
+    monkeypatch.setattr(bp, "prove_batch", sequential)
+    bad = list(z)
+    bad[3] = (bad[3] + 1) % fr().p
+    jobs = [_job(cid, w) for w in (z, bad, z)]
+    entry = mpc_jobs["entry"]
+    key = BucketKey("prove", cid, "bn254", entry.pk.domain_size,
+                    entry.r1cs.num_instance, 2)
+    before = _device_checks()
+    outcomes = dict(
+        (job.id, out) for job, out in bp.BatchProver(ex).run_batch(
+            jobs, key, SimpleNamespace(devices=np.array([object()]))
+        )
+    )
+    assert proved == [2]
+    failed = outcomes[jobs[1].id]
+    assert isinstance(failed, ValueError)
+    assert str(failed) == "witness does not satisfy the circuit"
+    for job in (jobs[0], jobs[2]):
+        assert outcomes[job.id]["proof"] == mpc_jobs["single"]["proof"]
+        assert outcomes[job.id]["batchSize"] == 2
+    moved = {k: v - before[k] for k, v in _device_checks().items()}
+    # the stand-in's two `prove_single` calls read a verdict each as well
+    assert moved == {"ok": 4, "rejected": 1}
+
+
+def test_the_verdicts_counter_prints_zero_before_any_job():
+    """Both series are bound when `models/groth16/qap.py` is imported, so
+    the benchmark's first /metrics text holds them unraised."""
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "from distributed_groth16_tpu.service import worker\n"
+         "from distributed_groth16_tpu.telemetry import metrics\n"
+         "print(metrics.registry().render_prometheus())"],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
+        text=True, timeout=JOIN_S, check=True,
+    ).stdout.splitlines()
+    assert 'witness_device_checks_total{verdict="ok"} 0' in out
+    assert 'witness_device_checks_total{verdict="rejected"} 0' in out
 
 
 def test_strip_clears_the_dealers_scalars_and_nothing_else(saved):

@@ -194,15 +194,24 @@ def test_strip_clears_trapdoor_scalars(world):
     assert pk.strip() is pk  # idempotent, chains
 
 
-def _bits_circuit(nbits: int = 87):
+def _bits_circuit(nbits: int = 87, filled: bool = False):
     """A circuit shaped like the served SHA-256 one: every witness wire a
     bit, and two public wires that pack the bits into wide values (the
     digest halves there). 3 + nbits wires; the A query's 90 and the L
-    query's 87 both pad to 128, with room for two wide scalars."""
+    query's 87 both pad to 128, with room for two wide scalars.
+
+    `filled`: the same shape, row for row, with every witness wire free
+    (w * 1 = w in place of w * w = w) and a value that fills the field."""
     from distributed_groth16_tpu.frontend.r1cs import ConstraintSystem
 
     rng = np.random.default_rng(24)
-    bits = [int(b) for b in rng.integers(0, 2, size=nbits)]
+    if filled:
+        bits = [
+            int.from_bytes(rng.bytes(40), "little") % fr().p
+            for _ in range(nbits)
+        ]
+    else:
+        bits = [int(b) for b in rng.integers(0, 2, size=nbits)]
     half = nbits // 2
     weigh = lambda part: sum(b << (90 + i) for i, b in enumerate(part))
     cs = ConstraintSystem()
@@ -210,7 +219,7 @@ def _bits_circuit(nbits: int = 87):
     hi = cs.new_instance(weigh(bits[half:]))
     wires = [cs.new_witness(b) for b in bits]
     for w in wires:
-        cs.enforce([(1, w)], [(1, w)], [(1, w)])
+        cs.enforce([(1, w)], [(1, cs.ONE if filled else w)], [(1, w)])
     for out, part in ((lo, wires[:half]), (hi, wires[half:])):
         cs.enforce(
             [(1 << (90 + i), w) for i, w in enumerate(part)],
@@ -273,13 +282,11 @@ def test_prove_single_follows_the_witness_occupancy(monkeypatch):
     assert proof == prove_host(pk, r1cs, z)
     assert took == {"tree": 3, "ladder": 1}
 
-    # field-filling values (they need not satisfy the circuit for the two
-    # provers to agree): every wire is wide, nothing fits, all windows run
-    rng = np.random.default_rng(25)
-    zf = [1] + [
-        int.from_bytes(rng.bytes(40), "little") % fr().p
-        for _ in range(len(z) - 1)
-    ]
+    # field-filling values on a circuit of the same shape that they
+    # satisfy (`prove_single` makes no proof of a witness that does not,
+    # ISSUE 33): every wire is wide, nothing fits, all windows run
+    r1cs, zf = _bits_circuit(filled=True)
+    pk, comp = setup(r1cs), CompiledR1CS(r1cs)
     zf_mont, viewf = msm_mod.encode_observed(fr(), zf)
     assert viewf.count == len(z) - 1
     proof, took = moved(lambda: prove_single(pk, comp, zf_mont, wide=viewf))

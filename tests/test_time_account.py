@@ -112,13 +112,17 @@ def test_job_span_tree_is_the_phase_partition(done_job):
 def test_prove_single_stages_and_their_clock(done_job):
     job = _find(done_job["metrics"]["spans"], "job")
     stages = _find(job["children"], "prove")["children"]
+    # `prove.check` (ISSUE 33): the device's verdict on the witness is
+    # read once the witness map is enqueued and before any MSM is
+    at = ENQUEUE_ONLY.index("prove.h") + 1
     assert [s["name"] for s in stages] == [
-        "prove.r1cs", *ENQUEUE_ONLY, "prove.decode",
+        "prove.r1cs", *ENQUEUE_ONLY[:at], "prove.check", *ENQUEUE_ONLY[at:],
+        "prove.decode",
     ]
     for name in ENQUEUE_ONLY:
         assert _find(stages, name)["attrs"]["clock"] == "dispatch"
     # host work, and the host's wait for the chip: wall time
-    for name in ("prove.r1cs", "prove.decode"):
+    for name in ("prove.r1cs", "prove.check", "prove.decode"):
         assert "clock" not in _find(stages, name).get("attrs", {})
 
 
