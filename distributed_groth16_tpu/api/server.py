@@ -92,9 +92,11 @@ from aiohttp import web
 from ..service.jobs import error_dto
 from ..telemetry import buildinfo as telemetry_buildinfo
 from ..telemetry import devmem as telemetry_devmem
+from ..telemetry import host as telemetry_host
 from ..telemetry import logbus as telemetry_logbus
 from ..telemetry import metrics as telemetry_metrics
 from ..telemetry import profiler as telemetry_profiler
+from ..telemetry import tracing as telemetry_tracing
 from ..telemetry.aggregate import now_ns as _trace_now_ns
 from ..service import (
     CrsCache,
@@ -130,6 +132,59 @@ _DRAINING = telemetry_metrics.registry().gauge(
 )
 
 
+# the front door's own clock (the router's `fleet_http_seconds` is the
+# pattern): every handler's wall and count by route template
+_HTTP_SECONDS = telemetry_metrics.registry().counter(
+    "http_server_seconds_total",
+    "Wall seconds inside this replica's HTTP handlers, per route template",
+    ("route",),
+)
+_HTTP_REQUESTS = telemetry_metrics.registry().counter(
+    "http_server_requests_total",
+    "HTTP requests this replica's handlers answered, per route template",
+    ("route",),
+)
+
+
+def _route(request) -> str:
+    """The matched route's template (`/jobs/{job_id}/result`), so the
+    label set stays bounded; `unmatched` for a path no route has."""
+    resource = request.match_info.route.resource
+    return resource.canonical if resource is not None else "unmatched"
+
+
+@web.middleware
+async def _http_span(request, handler):
+    """A span `http` round every handler (attrs `route`, `method`,
+    `status`, and `job`: the job the path names or the handler minted, the
+    id the job's own `job` span carries), its wall and count added to
+    `http_server_seconds_total{route}` / `http_server_requests_total`."""
+    route = _route(request)
+    attrs = {"route": route, "method": request.method}
+    job_id = request.match_info.get("job_id")
+    if job_id:
+        attrs["job"] = job_id
+    t0 = time.perf_counter()
+    status = 500
+    with telemetry_tracing.span("http", attrs=attrs) as span:
+        try:
+            response = await handler(request)
+            status = response.status
+            return response
+        except web.HTTPException as e:
+            status = e.status
+            raise
+        finally:
+            if span is not telemetry_tracing.NOOP:
+                minted = request.get("job")
+                if minted and not job_id:
+                    span.note(status=status, job=minted)
+                else:
+                    span.note(status=status)
+            _HTTP_SECONDS.labels(route=route).inc(time.perf_counter() - t0)
+            _HTTP_REQUESTS.labels(route=route).inc()
+
+
 class DrainingError(Exception):
     """Raised at admission once a drain began — mapped to HTTP 503 so a
     rolling-restart router retries the submission on a healthy replica."""
@@ -158,6 +213,12 @@ async def _read_multipart(request) -> dict[str, bytes]:
     async for part in reader:
         out[part.name] = await part.read(decode=False)
     return out
+
+
+def _devmem_tick() -> None:
+    """One tick of the device-memory sampler, on the thread that reads."""
+    with telemetry_host.background("devmem"):
+        telemetry_devmem.sample()
 
 
 def _millis(t0: float) -> int:
@@ -264,6 +325,8 @@ class ApiServer:
         if self.draining:
             raise DrainingError("service is draining; not accepting jobs")
         job_id = fields.get("job_id", b"").decode().strip()
+        if request is not None and job_id:
+            request["job"] = job_id  # the front door's `http` span names it
         if job_id:
             existing = self.queue.jobs.get(job_id)
             if existing is not None:
@@ -289,7 +352,10 @@ class ApiServer:
             trace_id=trace_id or uuid.uuid4().hex,
             **kwargs,
         )
-        return await self.queue.submit_async(job)
+        job = await self.queue.submit_async(job)
+        if request is not None:
+            request["job"] = job.id  # the front door's `http` span names it
+        return job
 
     # -- crash recovery + graceful drain -------------------------------------
 
@@ -865,7 +931,8 @@ class ApiServer:
         assert self.slo is not None
         while True:
             await asyncio.sleep(self.slo_cfg.sample_s)
-            self.slo.sample()
+            with telemetry_host.background("slo"):
+                self.slo.sample()
 
     async def _devmem_loop(self) -> None:
         """Background device-memory sampler: keeps the
@@ -874,7 +941,7 @@ class ApiServer:
         backend reports no stats)."""
         while True:
             await asyncio.sleep(self.devmem_sample_s)
-            await asyncio.to_thread(telemetry_devmem.sample)
+            await asyncio.to_thread(_devmem_tick)
 
     async def _on_cleanup(self, app):
         if self._slo_task is not None:
@@ -948,7 +1015,9 @@ class ApiServer:
         raise web.GracefulExit()
 
     def app(self) -> web.Application:
-        app = web.Application(client_max_size=MAX_BODY)
+        app = web.Application(
+            client_max_size=MAX_BODY, middlewares=[_http_span]
+        )
         app.on_startup.append(self._on_startup)
         app.on_cleanup.append(self._on_cleanup)
         app.router.add_post("/save_circuit", self.save_circuit)

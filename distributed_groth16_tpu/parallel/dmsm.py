@@ -55,10 +55,16 @@ async def d_msm(
         def king(points):
             import jax.numpy as jnp
 
+            # one child span a statement: an idle chip inside the king
+            # then names the statement the host sat in
             with king_section("dmsm"):
-                stacked = jnp.stack(points, axis=0)  # (n, 3) + elem
-                partials = pp.unpackexp(curve, stacked, degree2=True)  # (l, 3)+
-                total = curve.sum(partials, axis=0)
+                with _tracing.span("dmsm.king.stack", party=0):
+                    stacked = jnp.stack(points, axis=0)  # (n, 3) + elem
+                with _tracing.span("dmsm.king.unpack", party=0):
+                    partials = pp.unpackexp(  # (l, 3) + elem
+                        curve, stacked, degree2=True)
+                with _tracing.span("dmsm.king.sum", party=0):
+                    total = curve.sum(partials, axis=0)
             return [total] * pp.n
 
         return await net.king_compute(local, king, sid)

@@ -2,8 +2,11 @@
 tracing with Chrome trace-event export (tracing.py), the star-wide
 aggregation plane — clock alignment, cross-party trace merging, critical
 path (aggregate.py) — the fault flight recorder (flight.py), and jax's
-own compile and trace clocks as counters (compile.py: importing this
-package registers the program's one `jax.monitoring` listener). Every layer — transport,
+own compile and trace clocks as counters and spans (compile.py:
+importing this package registers the program's one `jax.monitoring`
+listener), and the host's work outside a job's phases, Python's collector
+and the server's background ticks (host.py: the one `gc.callbacks`
+hook). Every layer — transport,
 distributed kernels, prover, service, API — records through here;
 docs/OBSERVABILITY.md is the catalog and naming convention.
 
@@ -21,6 +24,7 @@ from . import (  # noqa: F401
     compile,
     devmem,
     flight,
+    host,
     metrics,
     tracing,
     transfer,

@@ -19,7 +19,15 @@ This module is the program's one listener for them. It feeds
     jax_compiles_total              backend compiles begun, all functions
 
 Sums, not merged wall time: a jitted function traced inside another
-reports both, so add series of one `fn`, not across them. After `/readyz`
+reports both, so add series of one `fn`, not across them.
+
+Each event also becomes a span, `jax.trace`, `jax.lower` or `jax.compile`
+with attribute `fn`: jax reports the start as a scalar (its value the
+start time) and the end as the time span, so the scalar listener opens
+the span and the time-span listener closes it, on a per-thread stack (a
+function traced inside another nests). The spans only annotate a live
+capture (`tracing.host_span`): a job's span tree does not hold them, and
+with no capture they are the no-op. After `/readyz`
 a rise of `jax_compiles_total` is the recompile alarm: a shape or a static
 argument is varying per job, and `jax_compile_seconds_total{fn}` says
 whose. The listener is registered when the telemetry package is imported
@@ -28,17 +36,25 @@ and costs nothing between compiles.
 
 from __future__ import annotations
 
+import threading
 import types
 
 import jax
 
 from . import metrics as _tm
+from . import tracing as _tracing
 
 _EV_COMPILE = "/jax/core/compile/backend_compile_duration"
 _EV_TRACE = (
     "/jax/core/compile/jaxpr_trace_duration",
     "/jax/core/compile/jaxpr_to_mlir_module_duration",
 )
+_SPAN = {
+    _EV_TRACE[0]: "jax.trace",
+    _EV_TRACE[1]: "jax.lower",
+    _EV_COMPILE: "jax.compile",
+}
+_open = threading.local()  # .spans: this thread's open spans, innermost last
 
 _REG = _tm.registry()
 _TRACE_SECONDS = _REG.counter(
@@ -69,12 +85,33 @@ def _fn(kw: dict) -> str:
     return name
 
 
+def _on_start(event: str, value: float, **kw) -> None:
+    name = _SPAN.get(event)
+    if name is None:
+        return
+    span = _tracing.host_span(name, {"fn": _fn(kw)}, record=False)
+    try:
+        span.__enter__()
+    except Exception:  # noqa: BLE001 — profiling must never fail a trace
+        span = _tracing.NOOP
+    stack = getattr(_open, "spans", None)
+    if stack is None:
+        stack = _open.spans = []
+    stack.append(span)
+
+
 def _on_time_span(event: str, start: float, end: float, **kw) -> None:
     if event == _EV_COMPILE:
         _COMPILE_SECONDS.labels(fn=_fn(kw)).inc(end - start)
         _COMPILES.inc()
     elif event in _EV_TRACE:
         _TRACE_SECONDS.labels(fn=_fn(kw)).inc(end - start)
+    stack = getattr(_open, "spans", None)
+    if event in _SPAN and stack:
+        try:
+            stack.pop().__exit__(None, None, None)
+        except Exception:  # noqa: BLE001 — profiling must never fail a trace
+            pass
 
 
 def named_jit(name: str, fn, **jit_kwargs):
@@ -93,4 +130,5 @@ def named_jit(name: str, fn, **jit_kwargs):
     return jax.jit(named, **jit_kwargs)
 
 
+jax.monitoring.register_scalar_listener(_on_start)
 jax.monitoring.register_event_time_span_listener(_on_time_span)

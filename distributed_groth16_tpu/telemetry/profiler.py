@@ -95,14 +95,23 @@ class Capture:
         }
 
 
+# spans of the host's work outside a job's phases (PR 34): their attrs
+# (`fn`, `generation`, `route`, `method`, `job`) become event stats
+_STAT_SPANS = ("http", "gc", "jax.")
+
+
 def _annotation_factory(name: str, attrs: dict | None):
     """The span-to-device-timeline bridge. The `job` annotation carries
     its job id as an event stat, so a trace's `job` events can be matched
-    to their status DTOs; every other span is a bare name."""
+    to their status DTOs; the host's spans outside the phases carry their
+    attrs; every other span is a bare name (what `Span.note` adds later
+    becomes stats of any)."""
     import jax
 
     if name == "job" and attrs and "job" in attrs:
         return jax.profiler.TraceAnnotation(name, job_id=attrs["job"])
+    if attrs and name.startswith(_STAT_SPANS):
+        return jax.profiler.TraceAnnotation(name, **attrs)
     return jax.profiler.TraceAnnotation(name)
 
 

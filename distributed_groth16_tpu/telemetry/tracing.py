@@ -102,7 +102,10 @@ class TraceBuffer:
 
     def __init__(self, max_events: int = 65536):
         self.max_events = max_events
-        self._lock = threading.Lock()
+        # reentrant: Python's collector runs between any two bytecodes,
+        # inside these methods too, and a generation-2 collection records
+        # its `gc` span here on the same thread (telemetry/host.py)
+        self._lock = threading.RLock()
         self._events: list[dict] = []
         self.dropped = 0
 
@@ -229,9 +232,15 @@ class Span:
 
     def note(self, **attrs) -> None:
         """Add attrs to the open span: a fact the work inside it learnt
-        (the route `ops/msm.py` dispatched). A new dict, since the one the
-        span was opened with may be shared (`DISPATCH`)."""
+        (the route `ops/msm.py` dispatched, an HTTP status). A new dict,
+        since the one the span was opened with may be shared (`DISPATCH`).
+        During a capture they also become stats of its annotation."""
         self.attrs = {**(self.attrs or {}), **attrs}
+        if self.annotation is not None:
+            try:
+                self.annotation.set_metadata(**attrs)
+            except Exception:  # noqa: BLE001 — profiling must never fail work
+                pass
 
     def __enter__(self):
         parent = _CURRENT.get()
@@ -329,6 +338,34 @@ def span(
         except Exception:  # noqa: BLE001 — a capture teardown race is benign
             annotation = None
     return Span(name, bufs, timings, party, a, annotation)
+
+
+def host_span(name: str, attrs: dict, *, record: bool):
+    """A span of the host's own machinery. With `record` it records only
+    into the job's and the global TraceBuffer, whose lock is reentrant,
+    never into the extra sinks; with nothing to record into it is the live
+    capture's bare annotation, which never becomes the current span, or
+    NOOP. Two callers: a `gc` callback, which runs between any two
+    bytecodes, so possibly while this thread holds a sink's lock; and
+    jax's own steps, which never record: a cold job traces hundreds of
+    programs, and their spans would crowd the round's own out of the job's
+    bounded tree."""
+    bufs = ()
+    if record:
+        b, g = _BUFFER.get(), _global_buffer
+        bufs = tuple(x for x in (b, g) if x is not None)
+        if len(bufs) == 2 and b is g:
+            bufs = (b,)
+    ann = _annotator
+    annotation = None
+    if ann is not None:
+        try:
+            annotation = ann(name, attrs)
+        except Exception:  # noqa: BLE001 — a capture teardown race is benign
+            annotation = None
+    if not bufs:
+        return NOOP if annotation is None else annotation
+    return Span(name, bufs, None, None, attrs, annotation)
 
 
 def current() -> "Span | None":

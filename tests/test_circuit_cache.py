@@ -391,6 +391,23 @@ def test_the_kings_own_function_is_a_span_on_party_0_only(
                    for lo, hi in outer)
 
 
+@pytest.mark.parametrize("statement", ["stack", "unpack", "sum"])
+def test_each_statement_of_the_dmsm_king_is_a_child_span(mpc_jobs, statement):
+    """ISSUE 34 (e): an idle chip inside the king's d_msm function names
+    the statement the host sat in. One child of each `dmsm.king`, in
+    order, on party 0, wall clock."""
+    events = mpc_jobs["events"]
+    kings = {e["args"]["id"]: e for e in events if e["name"] == "dmsm.king"}
+    found = [e for e in events if e["name"] == f"dmsm.king.{statement}"]
+    assert len(found) == len(kings) == 4
+    assert {e["args"]["parent"] for e in found} == set(kings)
+    assert {e["pid"] for e in found} == {0}
+    assert all("clock" not in e["args"] for e in found)
+    for e in found:
+        king = kings[e["args"]["parent"]]
+        assert king["ts"] <= e["ts"] and e["ts"] + e["dur"] <= king["ts"] + king["dur"]
+
+
 def test_the_kings_counter_moves_by_the_spans_wall(mpc_jobs):
     moved = mpc_jobs["king_moved"]
     assert set(moved) == {"dmsm", "dfft"}
