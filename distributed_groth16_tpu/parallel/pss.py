@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import refmath as rm
-from ..ops.constants import FR_GENERATOR, R
+from ..ops.constants import FR_GENERATOR, R, to_limbs
 from ..ops.curve import CurvePoints, fixed_scalar_ladder_tensors
 from ..ops.field import fr
 from ..ops.ntt import domain
@@ -165,7 +165,30 @@ class PackedSharingParams:
             cols.append(evals[: 2 * self.l : 2])
         return [[cols[j][i] for j in range(self.n)] for i in range(self.l)]
 
+    @functools.cached_property
+    def unpack2_weights(self) -> list[int]:
+        """(n,) ints: w_j = sum_o unpack2_matrix[o][j] mod modulus, so that
+        the SUM of the l secrets a degree-2(t+l) sharing packs is
+        sum_j w_j * share_j. d_msm only ever sums what it unpacks, so each
+        party weighs its own shares by w_j before its MSM and the king adds
+        the n points."""
+        mat = self.unpack2_matrix
+        return [sum(row[j] for row in mat) % self.modulus
+                for j in range(self.n)]
+
+    def unpack2_weight_limbs(self, F, party: int) -> np.ndarray:
+        """Party `party`'s w_j as STANDARD-form limbs of F, the scalar field
+        this PackedSharingParams is built over: F.mul(mont_shares, this)
+        is w_j * shares in standard form, one Montgomery product."""
+        assert F.p == self.modulus, "weights live in pp's own scalar field"
+        return np.array(to_limbs(self.unpack2_weights[party], F.nl),
+                        dtype=np.uint32)
+
     # -- group-element ("in the exponent") transforms -------------------------
+    #
+    # d_msm's king does not unpack in the exponent: it sums the points its
+    # parties weighed by `unpack2_weights` (parallel/dmsm.py). These maps
+    # serve the CRS packing, the mesh path and point-NTT tests.
     #
     # Two implementations of the same linear maps on curve points:
     #

@@ -291,6 +291,10 @@ def _king_seconds():
     }
 
 
+def _weighted_rounds():
+    return tm.registry().family("dmsm_weighted_rounds_total").value
+
+
 @pytest.fixture(scope="module")
 def mpc_jobs(saved):
     """Two served `mpc_prove` jobs on one resident entry, then the `prove`
@@ -306,14 +310,17 @@ def mpc_jobs(saved):
     first = ex.run(_job(cid, z, "mpc_prove"))
     job = _job(cid, z, "mpc_prove")
     before = _king_seconds()
+    weighted = _weighted_rounds()
     t0 = time.perf_counter()
     second = ex.run(job)
     wall_ms = 1e3 * (time.perf_counter() - t0)
     moved = {k: v - before[k] for k, v in _king_seconds().items()}
+    weighted = _weighted_rounds() - weighted
     return {
         "ex": ex, "cid": cid, "r1cs": r1cs, "z": z, "entry": entry,
         "held": held, "results": (first, second), "wall_ms": wall_ms,
         "events": job.trace.events(), "king_moved": moved,
+        "weighted_moved": weighted,
         "single": ex.run(_job(cid, z)),
     }
 
@@ -391,7 +398,7 @@ def test_the_kings_own_function_is_a_span_on_party_0_only(
                    for lo, hi in outer)
 
 
-@pytest.mark.parametrize("statement", ["stack", "unpack", "sum"])
+@pytest.mark.parametrize("statement", ["stack", "sum"])
 def test_each_statement_of_the_dmsm_king_is_a_child_span(mpc_jobs, statement):
     """ISSUE 34 (e): an idle chip inside the king's d_msm function names
     the statement the host sat in. One child of each `dmsm.king`, in
@@ -434,6 +441,28 @@ def test_the_kings_counter_prints_zero_before_any_round():
     ).stdout.splitlines()
     assert 'mpc_king_seconds_total{stage="dfft"} 0' in out
     assert 'mpc_king_seconds_total{stage="dmsm"} 0' in out
+
+
+def test_each_dmsm_king_sums_weighted_points(mpc_jobs):
+    """The king of each of a proof's four d_msms adds the parties'
+    weighted points: the counter moves by four, and no statement of the
+    king unpacks in the exponent."""
+    assert mpc_jobs["weighted_moved"] == 4
+    names = {e["name"] for e in mpc_jobs["events"]}
+    assert "dmsm.king.unpack" not in names
+
+
+def test_the_weighted_rounds_counter_prints_zero_before_any_round():
+    """Bound when `parallel/dmsm.py` is imported, as the server does."""
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "from distributed_groth16_tpu.parallel import dmsm\n"
+         "from distributed_groth16_tpu.telemetry import metrics\n"
+         "print(metrics.registry().render_prometheus())"],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
+        text=True, timeout=JOIN_S, check=True,
+    ).stdout.splitlines()
+    assert "dmsm_weighted_rounds_total 0" in out
 
 
 # -- the device's verdict on a served witness (ISSUE 33) ----------------------

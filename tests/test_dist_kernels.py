@@ -21,7 +21,7 @@ from distributed_groth16_tpu.parallel.packing import (
     pack_strided,
     unpack_shares,
 )
-from distributed_groth16_tpu.parallel.pss import PackedSharingParams
+from distributed_groth16_tpu.parallel.pss import PackedSharingParams, pack_host
 
 L = 2
 N = 4 * L
@@ -214,3 +214,63 @@ def test_d_pp_random():
     outs = simulate_network_round(N, party, [(sn[i], sd[i]) for i in range(N)])
     got = _ints(F.decode(unpack_shares(pp, jnp.stack(outs, 0))))
     assert got == expected
+
+
+def _g2_dmsm_round(seed):
+    """A d_msm round over G2 with bases packed in the exponent on the host
+    (share j of a chunk is pack_host(exponents)[j] * G2), against the host
+    MSM of the clear bases and scalars."""
+    from distributed_groth16_tpu.ops.constants import G2_GENERATOR
+    from distributed_groth16_tpu.ops.curve import g2
+
+    pp = PackedSharingParams(L)
+    F = fr()
+    C = g2()
+    rng = random.Random(seed)
+    m = 8
+    ks = [rng.randrange(1, R) for _ in range(m)]
+    scalars = [rng.randrange(R) for _ in range(m)]
+    expected = rm.G2.scalar_mul(
+        G2_GENERATOR, sum(k * s for k, s in zip(ks, scalars)) % R)
+    chunks = [pack_host(pp, ks[c:c + L]) for c in range(0, m, L)]
+    b_shares = [
+        C.encode([rm.G2.scalar_mul(G2_GENERATOR, ch[j]) for ch in chunks])
+        for j in range(N)
+    ]  # n x (m/l, 3, 2, 16)
+    s_shares = pack_consecutive(pp, F.encode(scalars))
+
+    async def party(net, data):
+        return await d_msm(C, data[0], data[1], pp, net)
+
+    outs = simulate_network_round(
+        N, party, [(b_shares[i], s_shares[i]) for i in range(N)]
+    )
+    return C, outs, expected
+
+
+def test_d_msm_g2_matches_host_msm():
+    C, outs, expected = _g2_dmsm_round(49)
+    for o in outs:
+        assert C.decode(o) == expected
+
+
+def test_d_msm_king_runs_no_unpack(monkeypatch):
+    """The king sums the parties' weighted points: nothing unpacks in the
+    exponent, and the result is still the clear MSM."""
+
+    def refuse(*args, **kwargs):
+        raise AssertionError("d_msm unpacked in the exponent")
+
+    monkeypatch.setattr(PackedSharingParams, "unpackexp", refuse)
+    C, outs, expected = _g2_dmsm_round(50)
+    for o in outs:
+        assert C.decode(o) == expected
+
+
+def test_d_msm_raises_the_weighted_rounds_counter_once():
+    from distributed_groth16_tpu.telemetry import metrics as tm
+
+    counter = tm.registry().family("dmsm_weighted_rounds_total")
+    before = counter.value
+    _g2_dmsm_round(51)
+    assert counter.value - before == 1

@@ -218,3 +218,48 @@ def test_packexp_limb_ladder_matches_rowmajor(monkeypatch):
     # and unpacking the fast-packed shares returns the originals
     back = pp.unpackexp(C, fast, method="dense")
     assert C.decode(back) == C.decode(pts1)
+
+
+@pytest.mark.parametrize("l", [1, 2, 4])
+def test_unpack2_weights_sum_the_secrets_over_fr(l):
+    """sum_j w_j * share_j is the sum of the l secrets of a degree-2(t+l)
+    sharing, on the host and through the device's unpack2; and the
+    weight's limbs take Montgomery shares to w_j * share_j in standard
+    form in one product (d_msm's party side)."""
+    pp = PackedSharingParams(l)
+    F = fr()
+    rng = random.Random(200 + l)
+    shares = [rng.randrange(R) for _ in range(pp.n)]
+    weighted = sum(w * s for w, s in zip(pp.unpack2_weights, shares)) % R
+    assert sum(unpack2_host(pp, shares)) % R == weighted
+    dev = F.decode(pp.unpack2(F.encode(shares)))
+    assert sum(int(v) for v in dev) % R == weighted
+    std = np.asarray(F.mul(
+        F.encode(shares),
+        np.stack([pp.unpack2_weight_limbs(F, j) for j in range(pp.n)]),
+    ))
+    assert [sum(int(x) << (16 * i) for i, x in enumerate(row))
+            for row in std] == [w * s % R for w, s in
+                                zip(pp.unpack2_weights, shares)]
+
+
+@pytest.mark.parametrize("curve", ["g1", "g2"])
+@pytest.mark.parametrize("l", [1, 2, 4])
+def test_unpack2_weights_sum_what_unpackexp_unpacks(l, curve):
+    """The king's weighted sum of n points is the sum of the l partials
+    that unpacking them in the exponent gives (parallel/dmsm.py)."""
+    from distributed_groth16_tpu.ops.constants import G2_GENERATOR
+    from distributed_groth16_tpu.ops.curve import g2
+
+    pp = PackedSharingParams(l)
+    C, host, gen = {
+        "g1": (g1(), rm.G1, G1_GENERATOR),
+        "g2": (g2(), rm.G2, G2_GENERATOR),
+    }[curve]
+    rng = random.Random(300 + l)
+    ks = [rng.randrange(1, R) for _ in range(pp.n)]
+    pts = C.encode([host.scalar_mul(gen, k) for k in ks])
+    want = host.scalar_mul(
+        gen, sum(w * k for w, k in zip(pp.unpack2_weights, ks)) % R)
+    partials = pp.unpackexp(C, pts, degree2=True)
+    assert C.decode(C.sum(partials, axis=0)) == want
