@@ -5,6 +5,10 @@ h-poly FFT pipelines + the A/B/C MSMs — jitted once over a "parties" mesh
 axis (parallel/mesh.py collectives): the in-slice TPU execution mode where
 the async star backend's network rounds become ICI collectives and XLA
 overlaps everything the reference runs on channels 0/1/2.
+
+Every party-stacked input is sharded along its leading n axis; a shard
+holds the (k, ...) block of its k = n/d parties (`parallel.mesh`), and
+the program is the same for every k.
 """
 
 from __future__ import annotations
@@ -24,6 +28,7 @@ from ...parallel.mesh import (
     _own_row,
     make_mesh,  # noqa: F401  (re-exported convenience)
     mesh_jit,
+    parties_per_shard,
     shard_map,
 )
 from ...parallel.pss import PackedSharingParams
@@ -46,6 +51,23 @@ class MeshProverInputs:
     h: jnp.ndarray | None = None  # (n, c_a, 3, 16) b_g1_query shares (zk)
 
 
+def _pad_scalars(x, cmax: int):
+    """(k, ..., c, 16) -> (k, ..., cmax, 16); a zero scalar is inert."""
+    widths = [(0, 0)] * (x.ndim - 2) + [(0, cmax - x.shape[-2]), (0, 0)]
+    return jnp.pad(x, widths)
+
+
+def _pad_points(x, cmax: int):
+    """(k, c, 3, 16) G1 points -> (k, cmax, 3, 16), padded with the
+    INFINITY encoding (0,1,0) — all-zero rows are absorbing (not identity)
+    under the RCB complete add, which would poison the Pallas tree-MSM
+    path's pairwise sum tree."""
+    extra = jnp.broadcast_to(
+        g1().infinity(), (x.shape[0], cmax - x.shape[1], 3) + g1().elem_shape
+    )
+    return jnp.concatenate([x, extra], axis=1)
+
+
 def build_mesh_prover(pp: PackedSharingParams, m: int, mesh: Mesh,
                       zk: bool = False):
     """Returns a jitted SPMD function computing the clear proof cores
@@ -64,12 +86,14 @@ def build_mesh_prover(pp: PackedSharingParams, m: int, mesh: Mesh,
     wpows_2m = dom2._live_wpows()
     size_inv_m = dom._size_inv
 
+    k = parties_per_shard(pp, mesh)
+
     def step(qa, qb, qc, a_sh, ax_sh, s_q, u_q, v_q, w_q, h_q=None):
         # --- ext_wit::h -------------------------------------------------
         # the a/b/c pipelines are shape-identical: run them as ONE batched
-        # transform (leading axis 3) — a third of the traced graph, and the
+        # transform (axis 1) — a third of the traced graph, and the
         # analog of the reference's three overlapped channels
-        stacked = jnp.stack([qa, qb, qc], axis=1)  # (1, 3, m/l, 16)
+        stacked = jnp.stack([qa, qb, qc], axis=1)  # (k, 3, m/l, 16)
         coeffs = _mesh_dfft(
             stacked, pp, logm, True, True, 2, False, False,
             wpows_m, size_inv_m,
@@ -79,7 +103,7 @@ def build_mesh_prover(pp: PackedSharingParams, m: int, mesh: Mesh,
             wpows_2m, None,
         )  # king_clear: (3, 2m, 16) clear, replicated
         p, q, w = evals[0], evals[1], evals[2]
-        h_share = _own_row(king_combine_h(p, q, w, pp))  # (1, m/l, 16)
+        h_share = _own_row(king_combine_h(p, q, w, pp), k)  # (k, m/l, 16)
 
         # --- A, B, C ----------------------------------------------------
         # the three G1 MSMs run as ONE batched d_msm (zero-padded to a
@@ -87,28 +111,17 @@ def build_mesh_prover(pp: PackedSharingParams, m: int, mesh: Mesh,
         # the main compile-time lever. Zero-scalar / zero-point padding
         # contributes the identity.
         cmax = max(s_q.shape[1], w_q.shape[1], u_q.shape[1])
-
-        def pads(x):  # scalars (c, 16) -> (cmax, 16); zero scalar is inert
-            return jnp.pad(x, [(0, cmax - x.shape[0]), (0, 0)])
-
-        def padp(x):  # points (c, 3, 16) -> (cmax, 3, 16); pad with the
-            # INFINITY encoding (0,1,0) — all-zero rows are absorbing (not
-            # identity) under the RCB complete add, which would poison the
-            # Pallas tree-MSM path's pairwise sum tree
-            extra = jnp.broadcast_to(
-                g1().infinity(), (cmax - x.shape[0], 3) + g1().elem_shape
-            )
-            return jnp.concatenate([x, extra], axis=0)
-
-        g1_bases = [padp(s_q[0]), padp(w_q[0]), padp(u_q[0])]
-        g1_scalars = [pads(a_sh[0]), pads(ax_sh[0]), pads(h_share[0])]
+        g1_bases = [_pad_points(s_q, cmax), _pad_points(w_q, cmax),
+                    _pad_points(u_q, cmax)]
+        g1_scalars = [_pad_scalars(a_sh, cmax), _pad_scalars(ax_sh, cmax),
+                      _pad_scalars(h_share, cmax)]
         if zk:
-            g1_bases.append(padp(h_q[0]))
-            g1_scalars.append(pads(a_sh[0]))
+            g1_bases.append(_pad_points(h_q, cmax))
+            g1_scalars.append(_pad_scalars(a_sh, cmax))
         out = _mesh_dmsm_batched(
             g1(),
-            jnp.stack(g1_bases, axis=0)[None],
-            jnp.stack(g1_scalars, axis=0)[None],
+            jnp.stack(g1_bases, axis=1),
+            jnp.stack(g1_scalars, axis=1),
             pp,
         )
         pi_a, c_w, c_u = out[0], out[1], out[2]
@@ -150,7 +163,8 @@ def build_batch_mesh_prover(pp: PackedSharingParams, m: int, mesh: Mesh,
         a_share  (n, B, c_a, 16)      per-job packed witness shares
         ax_share (n, B, c_w, 16)
         s/u/v/w  as in MeshProverInputs (shared CRS, no batch axis)
-    producing (n, B, ...) replicated clear cores (pi_a, pi_b, pi_c)."""
+    producing (d, B, ...) clear cores (pi_a, pi_b, pi_c), one copy a
+    device of the d the mesh spans."""
     logm = m.bit_length() - 1
     dom = domain(m)
     dom2 = domain(2 * m)
@@ -158,46 +172,40 @@ def build_batch_mesh_prover(pp: PackedSharingParams, m: int, mesh: Mesh,
     wpows_2m = dom2._live_wpows()
     size_inv_m = dom._size_inv
 
+    k = parties_per_shard(pp, mesh)
+
     def step(qabc, a_sh, ax_sh, s_q, u_q, v_q, w_q):
         # --- ext_wit::h, batched over (B, 3) ----------------------------
         coeffs = _mesh_dfft(
             qabc, pp, logm, True, True, 2, False, False,
             wpows_m, size_inv_m,
-        )  # (1, B, 3, 2m/l, 16)
+        )  # (k, B, 3, 2m/l, 16)
         evals = _mesh_dfft(
             coeffs, pp, logm + 1, False, False, 1, False, True,
             wpows_2m, None,
         )  # king_clear: (B, 3, 2m, 16) clear, replicated
         p, q, w = evals[:, 0], evals[:, 1], evals[:, 2]
-        h_share = _own_row(king_combine_h(p, q, w, pp))  # (1, B, m/l, 16)
+        h_share = _own_row(king_combine_h(p, q, w, pp), k)  # (k, B, m/l, 16)
 
         # --- A, B, C: 3B G1 MSM rows over B copies of the shared bases --
         cmax = max(s_q.shape[1], w_q.shape[1], u_q.shape[1])
-
-        def pads(x):  # scalars (B, c, 16) -> (B, cmax, 16); zero is inert
-            return jnp.pad(x, [(0, 0), (0, cmax - x.shape[1]), (0, 0)])
-
-        def padp(x):  # points (c, 3, 16) -> (cmax, 3, 16); INFINITY pad
-            extra = jnp.broadcast_to(
-                g1().infinity(), (cmax - x.shape[0], 3) + g1().elem_shape
-            )
-            return jnp.concatenate([x, extra], axis=0)
-
         bases3 = jnp.stack(
-            [padp(s_q[0]), padp(w_q[0]), padp(u_q[0])], axis=0
-        )  # (3, cmax, 3)+elem
+            [_pad_points(s_q, cmax), _pad_points(w_q, cmax),
+             _pad_points(u_q, cmax)], axis=1,
+        )  # (k, 3, cmax, 3)+elem
         g1_bases = jnp.broadcast_to(
-            bases3[None], (batch,) + bases3.shape
-        ).reshape((3 * batch,) + bases3.shape[1:])
+            bases3[:, None], (k, batch) + bases3.shape[1:]
+        ).reshape((k, 3 * batch) + bases3.shape[2:])
         g1_scalars = jnp.stack(
-            [pads(a_sh[0]), pads(ax_sh[0]), pads(h_share[0])], axis=1
-        ).reshape(3 * batch, cmax, 16)
+            [_pad_scalars(a_sh, cmax), _pad_scalars(ax_sh, cmax),
+             _pad_scalars(h_share, cmax)], axis=2,
+        ).reshape(k, 3 * batch, cmax, 16)
         out = _mesh_dmsm_batched(
-            g1(), g1_bases[None], g1_scalars[None], pp
+            g1(), g1_bases, g1_scalars, pp
         ).reshape((batch, 3) + g1().infinity().shape)
         pi_a, c_w, c_u = out[:, 0], out[:, 1], out[:, 2]
-        vb = jnp.broadcast_to(v_q[0][None], (batch,) + v_q[0].shape)
-        pi_b = _mesh_dmsm_batched(g2(), vb[None], a_sh, pp)  # (B, 3, 2, 16)
+        vb = jnp.broadcast_to(v_q[:, None], (k, batch) + v_q.shape[1:])
+        pi_b = _mesh_dmsm_batched(g2(), vb, a_sh, pp)  # (B, 3, 2, 16)
         pi_c = g1().add(c_w, c_u)
         return pi_a[None], pi_b[None], pi_c[None]
 

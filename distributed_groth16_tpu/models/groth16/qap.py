@@ -135,12 +135,16 @@ class QAP:
     c: jnp.ndarray  # (m, 16)
     domain: JaxDomain
 
+    def pss_stacked(self, pp: PackedSharingParams):
+        """The a, b and c shares of every party stacked on a leading n axis,
+        (n, m/l, 16) each: the layout the mesh prover shards."""
+        return (pack_strided(pp, self.a), pack_strided(pp, self.b),
+                pack_strided(pp, self.c))
+
     def pss(self, pp: PackedSharingParams) -> list["PackedQAPShare"]:
         """Per-party packed shares in the bitrev+strided d_fft layout
         (qap.rs:143-187)."""
-        sa = pack_strided(pp, self.a)
-        sb = pack_strided(pp, self.b)
-        sc = pack_strided(pp, self.c)
+        sa, sb, sc = self.pss_stacked(pp)
         return [
             PackedQAPShare(
                 num_inputs=self.num_inputs,

@@ -187,8 +187,9 @@ class PackedSharingParams:
     # -- group-element ("in the exponent") transforms -------------------------
     #
     # d_msm's king does not unpack in the exponent: it sums the points its
-    # parties weighed by `unpack2_weights` (parallel/dmsm.py). These maps
-    # serve the CRS packing, the mesh path and point-NTT tests.
+    # parties weighed by `unpack2_weights` (parallel/dmsm.py, and the mesh
+    # path's `_mesh_dmsm_batched`). These maps serve the CRS packing; the
+    # unpacking ones, the point-NTT tests.
     #
     # Two implementations of the same linear maps on curve points:
     #

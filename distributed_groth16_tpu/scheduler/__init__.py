@@ -218,7 +218,8 @@ class BatchScheduler:
 
     def eligible(self, job) -> bool:
         """Can this job ride the batched path? Prove kinds need a
-        batchable kind and an inventory slice of 4l devices; verify jobs
+        batchable kind and an inventory slice for its 4l parties (as many
+        devices as divide them evenly, `DevicePool.width`); verify jobs
         batch whenever their own knob allows (they lease no mesh).
         Anything else falls back to the per-job executor funnel."""
         if self.cfg.batch_max <= 1:

@@ -1,8 +1,10 @@
 """Device inventory + mesh slicing — the placement half of the scheduler.
 
 The inventory (default: `jax.devices()`) is partitioned into disjoint
-contiguous slices of `n_parties` devices, each backing one independent
-prover mesh. A batch holds a `MeshLease` on one slice for its whole
+contiguous slices, each backing one independent prover mesh. A slice of
+n parties is `mesh_width(n, inventory)` devices wide: one party a device
+where the inventory allows, else n/width parties a device, as
+`parallel.mesh.make_mesh` lays them out. A batch holds a `MeshLease` on one slice for its whole
 proving round, so two batches of a 4-party circuit prove CONCURRENTLY on
 an 8-device host instead of serializing through `jax.devices()[:n]` —
 multi-mesh placement is the throughput lever the single `ProofExecutor`
@@ -14,7 +16,7 @@ slice is busy, and `release()` wakes exactly them. The Mesh object is
 built lazily per lease slice and memoized, so lease accounting is testable
 with fake device objects and repeated leases don't rebuild meshes.
 
-Circuit breakers (docs/ROBUSTNESS.md): each (slot, n_parties) slice
+Circuit breakers (docs/ROBUSTNESS.md): each (slot, width) slice
 carries a consecutive-failure counter fed by the scheduler's per-batch
 outcome reports. `threshold` consecutive failures TRIP the slice — it
 enters an OPEN cooldown and `_free_slot` routes new batches around it;
@@ -31,6 +33,7 @@ from __future__ import annotations
 import asyncio
 import time
 
+from ..parallel.mesh import mesh_width
 from ..telemetry import flight as _flight
 from ..telemetry import metrics as _tm
 
@@ -67,8 +70,8 @@ _CLOSED, _HALF_OPEN, _OPEN = 0, 1, 2
 
 
 class _Breaker:
-    """Consecutive-failure circuit breaker for one (slot, n_parties)
-    device slice. Pure state machine — the pool drives it under its own
+    """Consecutive-failure circuit breaker for one (slot, width) device
+    slice. Pure state machine — the pool drives it under its own
     event-loop-side accounting, so no lock is needed."""
 
     def __init__(self, label: str):
@@ -176,22 +179,29 @@ class DevicePool:
         self._cond = asyncio.Condition()
         self._meshes: dict[tuple, object] = {}  # (slot, n) -> Mesh
 
+    def width(self, n_parties: int) -> int:
+        """Devices in one slice of an n_parties mesh (0: none fits)."""
+        if n_parties <= 0 or not self.devices:
+            return 0
+        return mesh_width(n_parties, len(self.devices))
+
     def capacity(self, n_parties: int) -> int:
         """How many disjoint n_parties-meshes the inventory supports."""
-        if n_parties <= 0:
+        width = self.width(n_parties)
+        if width == 0:
             return 0
-        cap = len(self.devices) // n_parties
+        cap = len(self.devices) // width
         if self.max_meshes > 0:
             cap = min(cap, self.max_meshes)
         return cap
 
     # -- circuit breakers ----------------------------------------------------
 
-    def _breaker(self, slot: int, n_parties: int) -> _Breaker:
-        key = (slot, n_parties)
+    def _breaker(self, slot: int, width: int) -> _Breaker:
+        key = (slot, width)
         br = self._breakers.get(key)
         if br is None:
-            br = self._breakers[key] = _Breaker(f"{n_parties}p{slot}")
+            br = self._breakers[key] = _Breaker(f"{width}p{slot}")
         return br
 
     def report(self, lease: "MeshLease", ok: bool) -> None:
@@ -208,58 +218,59 @@ class DevicePool:
             _BREAKER_TRIPS.labels(slice=br.label).inc()
             _flight.note("breaker_trip", slice=br.label)
 
-    def _allows(self, slot: int, n_parties: int) -> bool:
+    def _allows(self, slot: int, width: int) -> bool:
         if self.breaker_threshold <= 0:
             return True
-        br = self._breakers.get((slot, n_parties))
+        br = self._breakers.get((slot, width))
         return br is None or br.allows(self._clock(), self.breaker_cooldown_s)
 
-    def _next_breaker_expiry(self, n_parties: int) -> float | None:
-        """Seconds until the earliest OPEN breaker of this party count
+    def _next_breaker_expiry(self, width: int) -> float | None:
+        """Seconds until the earliest OPEN breaker of this slice width
         could go half-open — the bounded wait an acquire() uses when
         every otherwise-free slice is tripped (nothing will notify the
         condition when a cooldown lapses)."""
         now = self._clock()
         remains = [
             r
-            for (slot, n), br in self._breakers.items()
-            if n == n_parties
+            for (slot, w), br in self._breakers.items()
+            if w == width
             and (r := br.cooldown_remaining(now, self.breaker_cooldown_s))
             is not None
         ]
         return min(remains) + 0.001 if remains else None
 
-    def _free_slot(self, n_parties: int) -> int | None:
+    def _free_slot(self, width: int) -> int | None:
         if self.max_meshes > 0 and self._leases >= self.max_meshes:
             return None
-        for slot in range(len(self.devices) // n_parties):
-            lo, hi = slot * n_parties, (slot + 1) * n_parties
+        for slot in range(len(self.devices) // width):
+            lo, hi = slot * width, (slot + 1) * width
             if all(i not in self._busy for i in range(lo, hi)) and (
-                self._allows(slot, n_parties)
+                self._allows(slot, width)
             ):
                 return slot
         return None
 
     async def acquire(self, n_parties: int) -> MeshLease:
-        """Lease a free slice of n_parties devices, waiting if every slice
+        """Lease a free slice for n_parties, waiting if every slice
         is busy or breaker-tripped. Raises RuntimeError when the inventory
         can NEVER satisfy the request (callers gate on capacity() at
         admission)."""
         if self.capacity(n_parties) < 1:
             raise RuntimeError(
-                f"no mesh slice of {n_parties} devices available "
+                f"no mesh slice for {n_parties} parties available "
                 f"(inventory: {len(self.devices)})"
             )
+        width = self.width(n_parties)
         loop = asyncio.get_running_loop()
         t0 = loop.time()
         async with self._cond:
             while True:
-                slot = self._free_slot(n_parties)
+                slot = self._free_slot(width)
                 if slot is not None:
-                    lo, hi = slot * n_parties, (slot + 1) * n_parties
+                    lo, hi = slot * width, (slot + 1) * width
                     self._busy.update(range(lo, hi))
                     self._leases += 1
-                    br = self._breakers.get((slot, n_parties))
+                    br = self._breakers.get((slot, width))
                     if br is not None:
                         br.on_lease()  # a half-open slice admits one probe
                     self._update_gauges(n_parties)
@@ -267,7 +278,7 @@ class DevicePool:
                     return MeshLease(self, slot, self.devices[lo:hi])
                 # bounded wait: a release() notifies, and an OPEN breaker
                 # lapsing into half-open must wake us even if nobody does
-                timeout = self._next_breaker_expiry(n_parties)
+                timeout = self._next_breaker_expiry(width)
                 try:
                     await asyncio.wait_for(self._cond.wait(), timeout)
                 except asyncio.TimeoutError:

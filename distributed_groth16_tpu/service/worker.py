@@ -8,9 +8,13 @@ proofs execute concurrently — the admission bound on the queue plus this
 pool is the whole backpressure story.
 
 `ProofExecutor` is the single proving path of the service: witness
-generation, CRS packing (through the packed-CRS cache), and the MPC round
-via PR 1's `run_round_with_retries` so a transient transport fault costs
-one round, not the job. Cooperative cancellation points sit between
+generation, CRS packing (through the packed-CRS cache), and the MPC round.
+The round takes one of two shapes, by the devices the executor holds: on
+one device the asyncio star round via `run_round_with_retries`, so
+a transient transport fault costs one round, not the job; on several, one
+SPMD mesh program (`models/groth16/mesh_prover.py`) with the parties
+spread over the devices, its gather, king step and scatter collectives
+over the chips' interconnect. Cooperative cancellation points sit between
 phases (`job.check_cancel()`).
 """
 
@@ -18,9 +22,12 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import threading
 from dataclasses import dataclass
 
 import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from ..frontend.ark_serde import proof_to_bytes
 from ..frontend.r1cs import R1CS
@@ -33,10 +40,12 @@ from ..models.groth16 import (
     reassemble_proof,
 )
 from ..models.groth16.keys import ProvingKey
-from ..models.groth16.prove import prove_single
+from ..models.groth16.mesh_prover import build_mesh_prover
+from ..models.groth16.prove import PartyProofShare, prove_single
 from ..models.groth16.qap import UNSATISFIED, require_satisfied
 from ..ops.field import fr
 from ..ops.msm import encode_observed
+from ..parallel.mesh import AXIS, make_mesh
 from ..parallel.net import job_context, run_round_with_retries
 from ..parallel.pss import PackedSharingParams
 from ..telemetry import aggregate, devmem, logbus, tracing, transfer
@@ -96,6 +105,38 @@ _CIRCUIT_COUNTERS = (
 )
 
 
+# which shape an `mpc_prove` round took, one a round, counted on the host
+# at its launch (the family ops/msm.py and ops/ntt.py register)
+_ROUTE = _REG.counter(
+    "kernel_route_total",
+    "Kernel-path routing decisions at dispatch/trace time, per kernel "
+    "and chosen implementation path",
+    ("kernel", "path"),
+)
+_ROUND_MESH = _ROUTE.labels(kernel="mpc_round", path="mesh")
+_ROUND_STAR = _ROUTE.labels(kernel="mpc_round", path="star")
+_GATHER_BYTES = _REG.counter(
+    "mesh_gather_bytes_total",
+    "Bytes the mesh round's all_gathers brought each device, summed over "
+    "launches (counted from the program's shapes when it was traced)",
+)
+
+_CRS_FIELDS = ("s", "u", "v", "w")  # what a deterministic round reads
+
+
+def chip_inventory() -> list:
+    """The devices an executor proves on: the backend's, where they are
+    chips; one device on XLA:CPU, whose virtual devices are a test
+    fixture and not chips."""
+    devices = jax.devices()
+    return devices[:1] if devices[0].platform == "cpu" else list(devices)
+
+
+def _shard_on(x, device):
+    """The block of a mesh program's output that `device` holds."""
+    return next(s.data for s in x.addressable_shards if s.device == device)
+
+
 def _resident_budget() -> int | None:
     limit = devmem.limit_bytes()
     return None if limit is None else int(RESIDENT_SHARE * limit)
@@ -109,9 +150,16 @@ class ProofExecutor:
         store,
         crs_cache: CrsCache | None = None,
         cfg: ServiceConfig | None = None,
+        devices=None,
     ):
         self.store = store
         self.cfg = cfg or ServiceConfig()
+        # more than one device: an `mpc_prove` round is one mesh program
+        # over them; one device: the asyncio star round
+        self.devices = chip_inventory() if devices is None else list(devices)
+        self._mesh_lock = threading.Lock()
+        self._meshes: dict = {}  # n -> Mesh
+        self._mesh_provers: dict = {}  # (l, m) -> MeshProgram
         # explicit None check: an EMPTY CrsCache is falsy (it has __len__),
         # so `crs_cache or ...` would silently split the server's cache
         # from the executor's
@@ -213,6 +261,78 @@ class ProofExecutor:
         key = (job.circuit_id, pp.l)
         return self.crs_cache.get_or_pack(key, _pack)
 
+    def mesh_crs(self, job: ProofJob, pk, pp: PackedSharingParams, mesh):
+        """The CRS shares a mesh round reads, party-stacked and sharded
+        over the mesh, each party's rows resident on its own device,
+        through the same cache: a hit moves nothing. Keyed besides by the
+        mesh's devices. The factory packs on the default device and
+        places; it accounts what it placed."""
+
+        def _place():
+            sharding = NamedSharding(mesh, PartitionSpec(AXIS))
+            with transfer.account("h2d") as t:
+                shares = pack_proving_key(pk, pp, strip=True)
+                placed = tuple(
+                    jax.device_put(
+                        jnp.stack([getattr(sh, f) for sh in shares]),
+                        sharding,
+                    )
+                    for f in _CRS_FIELDS
+                )
+                t.add_tree(placed)
+            return placed
+
+        key = (job.circuit_id, pp.l,
+               tuple(d.id for d in mesh.devices.flat))
+        return self.crs_cache.get_or_pack(key, _place)
+
+    # -- the mesh ------------------------------------------------------------
+
+    def mesh(self, pp: PackedSharingParams):
+        """The parties mesh over this executor's devices, or None where it
+        holds one device and the round is the star's."""
+        if len(self.devices) < 2:
+            return None
+        with self._mesh_lock:
+            mesh = self._meshes.get(pp.n)
+            if mesh is None:
+                mesh = self._meshes[pp.n] = make_mesh(pp.n, self.devices)
+            return mesh
+
+    def _mesh_prover(self, pp: PackedSharingParams, m: int, mesh):
+        key = (pp.l, m)
+        with self._mesh_lock:
+            prover = self._mesh_provers.get(key)
+            if prover is None:
+                prover = self._mesh_provers[key] = build_mesh_prover(
+                    pp, m, mesh
+                )
+            return prover
+
+    @staticmethod
+    def _place(pp: PackedSharingParams, mesh, tensors) -> list:
+        """The job's party-stacked (n, ...) tensors sharded over the mesh,
+        party by party: each party's rows go to its device inside a
+        `prove.party` span of their own."""
+        devices = list(mesh.devices.flat)
+        k = pp.n // len(devices)
+        rows = [[[] for _ in devices] for _ in tensors]
+        for j in range(pp.n):
+            dev = devices[j // k]
+            with tracing.span(
+                "prove.party", party=j, attrs={"party": j, "device": dev.id}
+            ):
+                for out, x in zip(rows, tensors):
+                    out[j // k].append(jax.device_put(x[j:j + 1], dev))
+        sharding = NamedSharding(mesh, PartitionSpec(AXIS))
+        return [
+            jax.make_array_from_single_device_arrays(
+                x.shape, sharding,
+                [b[0] if k == 1 else jnp.concatenate(b) for b in blocks],
+            )
+            for x, blocks in zip(tensors, rows)
+        ]
+
     # -- the proving path ----------------------------------------------------
 
     def run(self, job: ProofJob) -> dict:
@@ -292,16 +412,22 @@ class ProofExecutor:
                 proof = prove_single(pk, comp, z_mont, wide=z_wide)
         elif job.kind == "mpc_prove":
             pp = PackedSharingParams(job.l)
+            mesh = self.mesh(pp)
             job.note_phase("packing")
             with phase("packing", timings):
                 with phase("packing.qap", timings):
                     qap = circ.comp.qap(z_mont)
                     ok = circ.comp.satisfied(z_mont, qap)
-                    qap_shares = qap.pss(pp)
+                    qap_shares = (
+                        qap.pss(pp) if mesh is None else qap.pss_stacked(pp)
+                    )
                 with phase("packing.crs", timings):
                     # a cache hit for a circuit proved before; the pack
                     # itself on a miss
-                    crs_shares = self.packed_crs(job, pk, pp)
+                    crs_shares = (
+                        self.packed_crs(job, pk, pp) if mesh is None
+                        else self.mesh_crs(job, pk, pp, mesh)
+                    )
                 with phase("packing.witness", timings):
                     ni = r1cs.num_instance
                     a_sh = pack_from_witness(pp, z_mont[1:])
@@ -310,43 +436,18 @@ class ProofExecutor:
                 # queued behind it: a bad witness never starts a round
                 with phase("packing.check", timings):
                     require_satisfied(ok)
+                if mesh is not None:
+                    with phase("packing.place", timings):
+                        placed = self._place(
+                            pp, mesh, (*qap_shares, a_sh, ax_sh)
+                        )
             job.check_cancel()
-
-            async def party(net, d):
-                return await distributed_prove_party(
-                    pp, d[0], d[1], d[2], d[3], net
+            if mesh is None:
+                proof = self._star_round(
+                    job, pp, pk, crs_shares, qap_shares, a_sh, ax_sh
                 )
-
-            # round boundary for the aggregation plane: the load/witness/
-            # packing spans above are harness (pid 0) work — drop them so
-            # the round close at simulate_network_round's end decomposes
-            # only the MPC round (million.py does the same; concurrent
-            # jobs on one process buffer still interleave — the per-job
-            # windowed decomposition in jobs.py is the exact one)
-            if aggregate.enabled():
-                aggregate.drain()
-
-            job.note_phase("MPC Proof")
-            with phase("MPC Proof", timings):
-                # the host issuing the eight parties' work: nothing in
-                # the round reads a device value, yet on the chip its
-                # eager ops return only as the device catches up, so
-                # this wall follows the device's (PERF.md, PR 32)
-                with phase("MPC Proof.round", timings):
-                    res = run_round_with_retries(
-                        pp.n,
-                        party,
-                        [
-                            (crs_shares[i], qap_shares[i], a_sh[i], ax_sh[i])
-                            for i in range(pp.n)
-                        ],
-                        retries=self.cfg.round_retries,
-                    )
-                # the host's wait for the round's device work, then the
-                # decoding; it lies after the round's close, so the
-                # critical-path window is unchanged
-                with phase("MPC Proof.reassemble", timings):
-                    proof = reassemble_proof(res[0], pk)
+            else:
+                proof = self._mesh_round(job, pp, pk, mesh, placed, crs_shares)
         else:
             raise ValueError(f"unknown job kind {job.kind!r}")
         job.check_cancel()
@@ -363,6 +464,64 @@ class ProofExecutor:
             "proof": list(proof_bytes),
             "phases": timings.as_millis(),
         }
+
+    def _star_round(self, job, pp, pk, crs_shares, qap_shares, a_sh, ax_sh):
+        timings = job.timings
+
+        async def party(net, d):
+            return await distributed_prove_party(
+                pp, d[0], d[1], d[2], d[3], net
+            )
+
+        # round boundary for the aggregation plane: the load/witness/
+        # packing spans above are harness (pid 0) work — drop them so
+        # the round close at simulate_network_round's end decomposes
+        # only the MPC round (million.py does the same; concurrent
+        # jobs on one process buffer still interleave — the per-job
+        # windowed decomposition in jobs.py is the exact one)
+        if aggregate.enabled():
+            aggregate.drain()
+
+        job.note_phase("MPC Proof")
+        with phase("MPC Proof", timings):
+            # the host issuing the eight parties' work: nothing in
+            # the round reads a device value, yet on the chip its
+            # eager ops return only as the device catches up, so
+            # this wall follows the device's (PERF.md)
+            with phase("MPC Proof.round", timings):
+                _ROUND_STAR.inc()
+                res = run_round_with_retries(
+                    pp.n,
+                    party,
+                    [
+                        (crs_shares[i], qap_shares[i], a_sh[i], ax_sh[i])
+                        for i in range(pp.n)
+                    ],
+                    retries=self.cfg.round_retries,
+                )
+            # the host's wait for the round's device work, then the
+            # decoding; it lies after the round's close, so the
+            # critical-path window is unchanged
+            with phase("MPC Proof.reassemble", timings):
+                return reassemble_proof(res[0], pk)
+
+    def _mesh_round(self, job, pp, pk, mesh, placed, crs_shares):
+        timings = job.timings
+        prover = self._mesh_prover(pp, pk.domain_size, mesh)
+        job.note_phase("MPC Proof")
+        with phase("MPC Proof", timings):
+            # the launch of the one mesh program, which returns once it is
+            # enqueued (the first launch traces and compiles it)
+            with phase("MPC Proof.round", timings):
+                cores = prover(*placed, *crs_shares)
+                _ROUND_MESH.inc()
+                _GATHER_BYTES.inc(prover.gather_bytes)
+            # the host's wait for the program, then the decoding of the
+            # cores that the first device holds (every device holds them)
+            with phase("MPC Proof.reassemble", timings):
+                dev = mesh.devices.flat[0]
+                a, b, c = (_shard_on(x, dev)[0] for x in cores)
+                return reassemble_proof(PartyProofShare(a=a, b=b, c=c), pk)
 
 
 class WorkerPool:

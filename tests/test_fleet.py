@@ -43,6 +43,7 @@ from distributed_groth16_tpu.frontend.r1cs import mult_chain_circuit
 from distributed_groth16_tpu.frontend.readers import write_r1cs, write_wtns
 from distributed_groth16_tpu.models.groth16 import verify
 from distributed_groth16_tpu.service import ProofJob
+from distributed_groth16_tpu.telemetry import logbus
 from distributed_groth16_tpu.utils.config import (
     FleetConfig,
     ServiceConfig,
@@ -831,6 +832,23 @@ def test_trace_id_survives_journal_backed_handoff(circuit, tmp_path):
     asyncio.run(run())
 
 
+async def _job_logs_with_replica_error(client, jid, deadline_s=30.0):
+    """GET /fleet/jobs/{id}/logs until it holds the replica's ERROR record
+    of the job, or the deadline passes (then the asserts below say what
+    is missing)."""
+    deadline = time.monotonic() + deadline_s
+    while True:
+        resp = await client.get(f"/fleet/jobs/{jid}/logs")
+        doc = await resp.json()
+        assert resp.status == 200, doc
+        if time.monotonic() > deadline or any(
+            r["level"] == "ERROR" and r["source"].startswith("replica")
+            for r in doc["records"]
+        ):
+            return doc
+        await asyncio.sleep(0.2)
+
+
 def test_fleet_job_logs_federates_router_and_replica_records(
     circuit, tmp_path
 ):
@@ -838,7 +856,13 @@ def test_fleet_job_logs_federates_router_and_replica_records(
     spine"): a routed MPC job dies on the replica, and ONE query —
     `GET /fleet/jobs/{id}/logs` — returns the whole story under the
     router-minted trace id: the router's dispatch breadcrumb AND the
-    replica's ERROR, every record rebased onto the router clock."""
+    replica's ERROR, every record rebased onto the router clock.
+
+    The spine is the process's: a fresh one here, so that the storm
+    suppressor's buckets do not carry the failures of the tests that ran
+    before in this process (a template that logged ten jobs' failures
+    within seconds drops the next one's ERROR)."""
+    logbus.reset_for_tests()
     root, cid, _, _ = circuit
     cs = mult_chain_circuit(9, 7)
     r1cs, z = cs.finish()
@@ -871,9 +895,7 @@ def test_fleet_job_logs_federates_router_and_replica_records(
             status = await _poll_terminal(client, jid)
             assert status["state"] == "FAILED", status
 
-            resp = await client.get(f"/fleet/jobs/{jid}/logs")
-            doc = await resp.json()
-            assert resp.status == 200, doc
+            doc = await _job_logs_with_replica_error(client, jid)
             assert doc["jobId"] == jid and doc["traceId"] == trace
             assert "warning" not in doc, doc
             recs = doc["records"]

@@ -231,6 +231,43 @@ def test_device_pool_max_meshes_caps_concurrency():
     asyncio.run(run())
 
 
+def test_device_pool_slices_hold_several_parties_a_device():
+    """Four devices, eight parties: one slice of all four, two parties a
+    device. A slice is as wide as the largest divisor of n the inventory
+    holds, so four parties still take one device each."""
+    async def run():
+        pool = DevicePool(devices=[object() for _ in range(4)])
+        assert [pool.width(n) for n in (2, 4, 6, 8, 16)] == [2, 4, 3, 4, 4]
+        assert pool.capacity(8) == 1 and pool.capacity(4) == 1
+        assert pool.capacity(2) == 2 and pool.capacity(16) == 1
+        big = await pool.acquire(8)
+        assert big.devices == pool.devices
+        waiter = asyncio.ensure_future(pool.acquire(4))
+        await asyncio.sleep(0.02)
+        assert not waiter.done()  # the eight parties hold every device
+        big.release()
+        lease = await asyncio.wait_for(waiter, 5)
+        assert len(lease.devices) == 4
+        lease.release()
+        assert DevicePool(devices=[]).capacity(8) == 0
+
+    asyncio.run(run())
+
+
+def test_scheduler_admits_eight_parties_on_four_devices():
+    q = JobQueue(bound=8, workers=1)
+    cfg = SchedulerConfig(batch_max=4, batch_linger_ms=60000.0)
+    sched = BatchScheduler(
+        _StubExecutor(), q, cfg, devices=[object() for _ in range(4)]
+    )
+    assert sched.eligible(_job(l=2)) and sched.eligible(_job(l=1))
+    off = BatchScheduler(
+        _StubExecutor(), q, SchedulerConfig(batch_max=1),
+        devices=[object() for _ in range(4)],
+    )
+    assert not off.eligible(_job(l=2))
+
+
 def test_prover_cache_lru_and_next_pow2():
     cache = ProverCache(capacity=2)
     built = []
@@ -394,6 +431,30 @@ def test_prove_batch_of_8_byte_matches_sequential_path():
         assert proof.c == oracle.c
     # distinct witnesses produce distinct proofs — no demux mix-up
     assert len({(p.a, p.b) for p in proofs}) == 8
+
+
+@pytest.mark.skipif(len(jax.devices()) < 4, reason="needs 4 devices")
+def test_prove_batch_on_four_devices_two_parties_a_device():
+    """The batched program on a mesh of 4 devices for 8 parties: each
+    shard's two parties ride its batch axes, and each proof still
+    byte-matches the sequential path."""
+    cs = mult_chain_circuit(3, CHAIN_LEN)
+    r1cs, _ = cs.finish()
+    pp = PackedSharingParams(2)
+    pk = setup(r1cs, seed=5)
+    comp = CompiledR1CS(r1cs)
+    crs = pack_proving_key(pk, pp)
+    F = fr()
+    witnesses = [chain_witness(x0) for x0 in (3, 4)]
+    mesh = make_mesh(pp.n, jax.devices()[:4])
+    assert mesh.devices.size == 4
+    proofs = prove_batch(
+        pk, comp, pp, mesh, crs, [F.encode(z) for z in witnesses]
+    )
+    for z, proof in zip(witnesses, proofs):
+        oracle = prove_single(pk, comp, F.encode(z))
+        assert (proof.a, proof.b, proof.c) == (oracle.a, oracle.b, oracle.c)
+    assert proofs[0].a != proofs[1].a
 
 
 # -- full stack: the acceptance criterion ------------------------------------

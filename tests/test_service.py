@@ -18,6 +18,7 @@ import time
 
 import pytest
 from aiohttp.test_utils import TestClient, TestServer
+from test_telemetry import parse_prometheus
 
 from distributed_groth16_tpu.api.server import ApiServer
 from distributed_groth16_tpu.api.store import CircuitStore
@@ -617,3 +618,122 @@ def test_slo_disabled_by_default(tmp_path):
             await client.close()
 
     asyncio.run(run())
+
+
+# -- the round's two shapes: one mesh program over several devices, the
+#    star round on one ------------------------------------------------------
+
+
+def _series(text: str, family: str, **labels) -> float:
+    _, samples = parse_prometheus(text)
+    return samples.get((family, tuple(labels.items())), 0.0)
+
+
+def _party_spans(spans: list) -> list:
+    out, stack = [], list(spans)
+    while stack:
+        node = stack.pop()
+        if node["name"] == "prove.party":
+            out.append(node)
+        stack.extend(node.get("children", ()))
+    return out
+
+
+def _mpc_jobs_through_server(circuit, devices, jobs=2):
+    """`jobs` l = 2 MPC jobs and one single-node job on one circuit,
+    through the jobs API, with the executor holding `devices` (None: the
+    executor's own inventory). Returns the MPC jobs' status DTOs and
+    results, the single-node proof, and /metrics + /stats before/after."""
+    root, cid, wtns, _ = circuit
+    out = {}
+
+    async def run():
+        server = _server(root, workers=1)
+        if devices is not None:
+            server.executor.devices = list(devices)
+        client = TestClient(TestServer(server.app()))
+        await client.start_server()
+        try:
+            async def one(**extra):
+                resp = await client.post(
+                    "/jobs/prove",
+                    data={"circuit_id": cid, "witness_file": wtns, **extra},
+                )
+                jid = (await resp.json())["jobId"]
+                status = await _poll_terminal(client, jid)
+                assert status["state"] == "DONE", status
+                result = await (await client.get(f"/jobs/{jid}/result")).json()
+                return status, bytes(result["proof"])
+
+            async def snapshot():
+                metrics = await (await client.get("/metrics")).text()
+                stats = await (await client.get("/stats")).json()
+                return metrics, stats["crsCache"]
+
+            out["single"] = (await one())[1]
+            out["before"] = await snapshot()
+            out["mpc"] = []
+            for _ in range(jobs):
+                out["mpc"].append(await one(mpc="1", l="2"))
+                out.setdefault("after_first", await snapshot())
+            out["after"] = await snapshot()
+        finally:
+            await client.close()
+
+    _run(run())
+    return out
+
+
+def test_mpc_job_runs_one_mesh_program_over_four_devices(circuit):
+    """Four devices in the executor's inventory, eight parties: two a
+    device. The proof is the single-node proof, byte for byte; the job's
+    phases hold the placement, its spans one `prove.party` a party on the
+    device that holds it, and the mesh route moves once a job. The
+    second job finds the CRS shares placed: no miss, nothing packed."""
+    import jax
+
+    devices = jax.devices()[:4]
+    out = _mpc_jobs_through_server(circuit, devices)
+    before, first, after = (out[k] for k in ("before", "after_first", "after"))
+    for status, proof in out["mpc"]:
+        assert proof == out["single"]
+        assert {"packing.qap", "packing.crs", "packing.witness",
+                "packing.check", "packing.place", "MPC Proof.round",
+                "MPC Proof.reassemble"} <= set(status["phases"])
+        parties = [p["attrs"] for p in _party_spans(status["metrics"]["spans"])]
+        assert sorted(p["party"] for p in parties) == list(range(8))
+        assert {p["party"]: p["device"] for p in parties} == {
+            j: devices[j // 2].id for j in range(8)
+        }
+
+    def moved(a, b, **labels):
+        return (_series(b[0], "kernel_route_total", **labels)
+                - _series(a[0], "kernel_route_total", **labels))
+
+    assert moved(before, after, kernel="mpc_round", path="mesh") == 2
+    assert moved(before, after, kernel="mpc_round", path="star") == 0
+    gathered = (_series(after[0], "mesh_gather_bytes_total")
+                - _series(before[0], "mesh_gather_bytes_total"))
+    assert gathered > 0 and gathered == 2 * (
+        _series(first[0], "mesh_gather_bytes_total")
+        - _series(before[0], "mesh_gather_bytes_total"))
+    assert first[1]["misses"] == before[1]["misses"] + 1
+    assert after[1]["misses"] == first[1]["misses"]
+    assert after[1]["hits"] == first[1]["hits"] + 1
+
+
+def test_mpc_job_on_one_device_takes_the_star_round(circuit):
+    """On XLA:CPU the executor holds one device of the eight virtual ones:
+    the same job runs the asyncio star round, with the same proof."""
+    out = _mpc_jobs_through_server(circuit, None, jobs=1)
+    (status, proof), = out["mpc"]
+    assert proof == out["single"]
+    assert "packing.place" not in status["phases"]
+    assert len(_party_spans(status["metrics"]["spans"])) == 8
+    a, b = out["before"][0], out["after"][0]
+    assert (_series(b, "kernel_route_total", kernel="mpc_round", path="star")
+            - _series(a, "kernel_route_total", kernel="mpc_round",
+                      path="star")) == 1
+    assert _series(b, "kernel_route_total", kernel="mpc_round",
+                   path="mesh") == _series(
+        a, "kernel_route_total", kernel="mpc_round", path="mesh")

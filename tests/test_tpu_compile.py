@@ -13,16 +13,21 @@ from distributed_groth16_tpu.ops import limb_kernels as lk
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(
+        return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2"
         )
     except Exception as e:  # noqa: BLE001 — any failure to describe is a skip
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -102,3 +107,45 @@ def test_affine_level_kernels_compile_for_v5e_at_one_lane_tile(
         (g._pallas_root_inverse, (tile(CR),)),
     ):
         assert "tpu_custom_call" in fn.lower(*args).compile().as_text()
+
+
+def test_mesh_prover_compiles_for_a_2x2_host_with_two_parties_a_chip(
+    topo, pallas, monkeypatch
+):
+    """The served MPC round's one program, eight parties over the four
+    chips of a v5e:2x2 (two a chip), at m = 64 with the tree MSMs forced
+    (the served size takes them by itself): the compiler puts the
+    parties' gathers in as `all-gather`s and keeps the Pallas kernels.
+    The groups are the fixture's fresh ones, so no cached group keeps a
+    Pallas program for the CPU tests that follow."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distributed_groth16_tpu.models.groth16.mesh_prover import (
+        build_mesh_prover,
+    )
+    from distributed_groth16_tpu.parallel.mesh import AXIS, make_mesh
+    from distributed_groth16_tpu.parallel.pss import PackedSharingParams
+
+    monkeypatch.setenv("DG16_FORCE_TREE_MSM", "1")
+    monkeypatch.setattr(lk, "lg1", lambda: pallas["g1"])
+    monkeypatch.setattr(lk, "lg2", lambda: pallas["g2"])
+    pp = PackedSharingParams(2)
+    mesh = make_mesh(pp.n, topo.devices)
+    assert mesh.devices.size == 4
+    sh = NamedSharding(mesh, P(AXIS))
+    n, m, c = pp.n, 64, 30  # c: the packed witness, under m/l = 32
+
+    def arg(*shape):
+        return _shape(sh, (n,) + shape)
+
+    prog = build_mesh_prover(pp, m, mesh)
+    text = prog.lower(
+        arg(32, 16), arg(32, 16), arg(32, 16), arg(c, 16), arg(c, 16),
+        arg(c, 3, 16), arg(32, 3, 16), arg(c, 3, 2, 16), arg(c, 3, 16),
+    ).compile().as_text()
+    assert "all-gather" in text and "tpu_custom_call" in text
+    # the two d_fft gathers (3 vectors, 32 then 64 rows) and the two
+    # d_msms' points (3 G1 and 1 G2 a party), 6 of 8 parties from afar
+    fft = 3 * (32 + 64) * 16 * 4
+    points = 3 * 3 * 16 * 4 + 3 * 2 * 16 * 4
+    assert prog.gather_bytes == 6 * (fft + points)
