@@ -1138,9 +1138,19 @@ class LimbGroup:
         return jax.lax.fori_loop(0, W - 1, step, acc0)
 
     @functools.cache
-    def _horner(self, c: int, W: int):
+    def _horner(self, c: int, W: int, lanes: bool = False):
+        """The Horner program: on (ROWS, W) window sums of one MSM, or,
+        with `lanes`, on (W, ROWS, 128) columns whose lanes are 128 MSMs'
+        window sums (`horner_lanes`)."""
         RR = self.ROWS
         if not use_pallas():
+            if lanes:
+                return jax.jit(
+                    lambda cols: self.horner_body(
+                        lambda w: cols[w], self._consts(), c, W,
+                        kernel=False,
+                    )
+                )
             return jax.jit(
                 lambda s: self.horner_body(
                     lambda w: jax.lax.dynamic_slice(s, (0, w), (RR, 1)),
@@ -1161,7 +1171,7 @@ class LimbGroup:
 
         @jax.jit
         def run(s):
-            cols = jnp.broadcast_to(
+            cols = s if lanes else jnp.broadcast_to(
                 jnp.transpose(s)[:, :, None], (W, RR, 128)
             )
             out = pl.pallas_call(
@@ -1173,7 +1183,7 @@ class LimbGroup:
                 ],
                 out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
             )(cols, self._consts())
-            return out[:, :1]
+            return out if lanes else out[:, :1]
 
         return run
 
@@ -1183,6 +1193,28 @@ class LimbGroup:
         if W == 1:
             return s
         return self._horner(c, W)(s)
+
+    def horner_lanes(self, s, c: int):
+        """Window sums of B MSMs, s (ROWS, B, W), LSB window first -> B
+        point columns (ROWS, B): the same kernel, one MSM a lane where
+        `horner` broadcasts one MSM over all 128."""
+        RR, B, W = s.shape
+        if W == 1:
+            return s[..., 0]
+        cols = jnp.transpose(s, (2, 0, 1))  # (W, RR, B)
+        outs = []
+        for b0 in range(0, B, 128):
+            blk = cols[..., b0 : b0 + 128]
+            k = blk.shape[-1]
+            if k != 128:
+                blk = jnp.concatenate(
+                    [blk, jnp.broadcast_to(
+                        jnp.asarray(self.inf_col)[None], (W, RR, 128 - k)
+                    )],
+                    axis=-1,
+                )
+            outs.append(self._horner(c, W, lanes=True)(blk)[:, :k])
+        return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
 
     # -- layout conversion ---------------------------------------------------
 
@@ -1345,10 +1377,15 @@ def _tree_window_bits(n: int) -> int:
     return 8 if n >= 4096 else 4
 
 
-def _tree_window_group(g: "LimbGroup", npad: int, windows: int) -> int:
+def _tree_window_group(g: "LimbGroup", npad: int, windows: int,
+                       rows: int = 1) -> int:
     # bound live tree memory to ~8 * 48 * 2^20 * 4 * 2 ≈ 3.2 GB
-    # (half the window count for G2's double-width rows)
-    return windows if npad <= (1 << 17) else max(1, 8 * 48 // g.ROWS)
+    # (half the window count for G2's double-width rows); a launch of
+    # `rows` MSMs holds rows * npad lanes a window, so it counts as one
+    # MSM of that many points
+    return (
+        windows if rows * npad <= (1 << 17) else max(1, 8 * 48 // g.ROWS)
+    )
 
 
 def _affine_depth(windows: int, npad: int, min_adds: int) -> int:
@@ -1369,14 +1406,16 @@ def _affine_depths(windows: int, group: int, npad: int,
     ]
 
 
-def tree_affine_levels(g: "LimbGroup", n: int, limbs: int) -> int:
+def tree_affine_levels(g: "LimbGroup", n: int, limbs: int,
+                       rows: int = 1) -> int:
     """The affine levels a launch of `msm_tree` runs on n points with
-    scalars of `limbs` 16-bit limbs (1 for the limb-0 form), over all its
-    window groups: what `msm_affine_levels_total` is raised by."""
+    scalars of `limbs` 16-bit limbs (1 for the limb-0 form), or of
+    `msm_tree_batched` on `rows` such MSMs, over all its window groups:
+    what `msm_affine_levels_total` is raised by."""
     npad = _tree_npad(n)
-    windows = limbs * LIMB_BITS // _tree_window_bits(n)
+    windows = rows * limbs * LIMB_BITS // _tree_window_bits(n)
     return sum(_affine_depths(
-        windows, _tree_window_group(g, npad, windows), npad,
+        windows, _tree_window_group(g, npad, windows, rows), npad,
         _AFFINE_MIN_ADDS,
     ))
 
@@ -1469,6 +1508,45 @@ def msm_tree(points_rm, scalars_std, c: int | None = None,
     return _MSM_LIMB0_JITS[g.kind](g, points_pad, limb0_pad, c, window_group)
 
 
+def msm_tree_batched(points_rm, scalars_std, group: "LimbGroup",
+                     c: int | None = None, window_group: int | None = None):
+    """B same-length MSMs as ONE launch of the tree program:
+    points_rm (B, n) + rm_shape, scalars_std (B, n, k) standard form ->
+    (B,) + rm_shape, row b the sum `msm_tree` gives for row b, all
+    windows (no limb-0 form). The B * W windows run as one MSM's W do:
+    what a launch pays whatever its width (the narrow levels' tiles, the
+    affine levels' serial inversions, Horner) is paid once, while the
+    Fenwick and combine stages grow with the windows as the up-sweep
+    does; and the launch's B * W * npad lanes count toward the affine rule
+    and the window groups as one MSM's W * npad do
+    (`tree_affine_levels(..., rows=B)`). Rows of any length run at the
+    power of two (`_batched_pad`), so one program serves every length."""
+    n = points_rm.shape[1]
+    if c is None:
+        c = _tree_window_bits(n)
+    if n != _tree_npad(n):
+        points_rm, scalars_std = _MSM_TREE_BATCHED_PAD_JITS[group.kind](
+            group, points_rm, scalars_std
+        )
+    return _MSM_TREE_BATCHED_JITS[group.kind](
+        group, points_rm, scalars_std, c, window_group
+    )
+
+
+def _batched_pad(g: LimbGroup, points_rm, scalars_std):
+    """The rows of `msm_tree_batched` padded to the power of two ahead of
+    its jit, infinity with scalar 0, so that batches of different lengths
+    share one tree program (as `_limb0_fill` does for the limb-0 form)."""
+    rows, n = points_rm.shape[:2]
+    extra = _tree_npad(n) - n
+    inf_rm = jnp.asarray(g.inf_col)[:, 0].reshape(g.rm_shape)
+    points = jnp.concatenate(
+        [points_rm, jnp.broadcast_to(inf_rm, (rows, extra) + g.rm_shape)],
+        axis=1,
+    )
+    return points, jnp.pad(scalars_std, ((0, 0), (0, extra), (0, 0)))
+
+
 def _limb0_fill(g: LimbGroup, points_rm, scalars_std, idx, limbs, steps):
     """The limb-0 tree's inputs, padded to the power of two ahead of its
     jit (so that MSMs of different lengths share one tree program): the n
@@ -1527,10 +1605,7 @@ def _msm_tree(g: LimbGroup, points_rm, scalars_std, c: int,
     so every device op of the program can be put down to a stage in a
     profiler trace. `affine_min_adds` is the rule's constant, an argument
     so that a test can run affine levels at 16 points."""
-    RR = g.ROWS
     n = points_rm.shape[0]
-    W_all = scalars_std.shape[1] * LIMB_BITS // c
-    B = 1 << c
     npad = _tree_npad(n)
     with jax.named_scope("msm.sort"):  # its inputs: layout, padding, digits
         lm = g.from_rowmajor(points_rm)
@@ -1539,10 +1614,56 @@ def _msm_tree(g: LimbGroup, points_rm, scalars_std, c: int,
         digits = _digits(scalars_std, c)  # (W, n)
         if npad != n:
             digits = jnp.pad(digits, ((0, 0), (0, npad - n)))
+    s_all = _tree_window_sums(
+        g, lm, digits, c, npad, 1, window_group, affine_min_adds
+    )
+    with jax.named_scope("msm.horner"):
+        out = g.horner(s_all, c)  # (RR, 1)
+        return g.to_rowmajor(out)[0]
+
+
+def _msm_tree_batched(g: LimbGroup, points_rm, scalars_std, c: int,
+                      window_group: int | None,
+                      affine_min_adds: int = _AFFINE_MIN_ADDS):
+    """B tree MSMs of one length as one program (see `msm_tree_batched`):
+    points (B, n) + rm_shape, scalars (B, n, k) -> (B,) + rm_shape, n a
+    power of two. The B * W windows are what `_msm_tree` runs as its W:
+    row b's windows are b * W ... b * W + W - 1, each sorts over row b's
+    points, and Horner combines each row's W sums in a lane of its own."""
+    RR = g.ROWS
+    rows, n = points_rm.shape[:2]
+    assert n == _tree_npad(n), "rows come padded (`msm_tree_batched`)"
+    with jax.named_scope("msm.sort"):
+        lm = g.from_rowmajor(points_rm.reshape((rows * n,) + g.rm_shape))
+        digits = _digits(scalars_std.reshape(rows * n, -1), c)  # (W, B*n)
+        W = digits.shape[0]
+        digits = jnp.transpose(
+            digits.reshape(W, rows, n), (1, 0, 2)
+        ).reshape(rows * W, n)
+    s_all = _tree_window_sums(
+        g, lm, digits, c, n, W, window_group, affine_min_adds
+    )
+    with jax.named_scope("msm.horner"):
+        out = g.horner_lanes(s_all.reshape(RR, rows, W), c)  # (RR, B)
+        return g.to_rowmajor(out)
+
+
+def _tree_window_sums(g: LimbGroup, lm, digits, c: int, npad: int,
+                      row_windows: int, window_group: int | None,
+                      affine_min_adds: int):
+    """Every window's sum sum_b b * S_b, (ROWS, windows): the body of
+    `_msm_tree` and `_msm_tree_batched` between their inputs and Horner.
+    lm: (ROWS, rows * npad) limb-major points, row r's at r * npad;
+    digits: (windows, npad), window w over row w // row_windows's points
+    (`row_windows` 1 for one MSM: every window over the one row)."""
+    RR = g.ROWS
+    W_all = digits.shape[0]
+    rows = lm.shape[1] // npad
+    B = 1 << c
     levels_n = npad.bit_length() - 1  # log2(npad)
 
     if window_group is None:
-        window_group = _tree_window_group(g, npad, W_all)
+        window_group = _tree_window_group(g, npad, W_all, rows)
     # A window group's widest levels are affine (`_affine_depth`); a launch
     # that has one takes its points affine from the start, normalised once
     # (they may come with any Z: the limb-0 fill's ladder points do), so
@@ -1569,6 +1690,9 @@ def _msm_tree(g: LimbGroup, points_rm, scalars_std, c: int,
                     row, jnp.arange(B - 1), side="right"
                 )
             )(sortd)  # (Wg, B-1)
+            if rows > 1:  # each window gathers from its own row's points
+                first = np.arange(w0, w0 + Wg) // row_windows * npad
+                order = order + jnp.asarray(first, jnp.int32)[:, None]
             gathered = jnp.take(lm, order.reshape(-1), axis=1).reshape(
                 lm.shape[0], Wg, npad
             )
@@ -1656,11 +1780,7 @@ def _msm_tree(g: LimbGroup, points_rm, scalars_std, c: int,
                 terms = g.add(pair[..., 0], pair[..., 1])
                 k //= 2
             sums.append(terms[..., 0])  # (RR, Wg)
-
-    with jax.named_scope("msm.horner"):
-        s_all = jnp.concatenate(sums, axis=1)  # (RR, W_all)
-        out = g.horner(s_all, c)  # (RR, 1)
-        return g.to_rowmajor(out)[0]
+    return jnp.concatenate(sums, axis=1)  # (RR, W_all)
 
 
 # One body, one program per (static) group as before; the group now also
@@ -1668,6 +1788,23 @@ def _msm_tree(g: LimbGroup, points_rm, scalars_std, c: int,
 _MSM_TREE_JITS = {
     kind: named_jit(
         f"_msm_tree_jit_{kind}", _msm_tree, static_argnums=(0, 3, 4, 5)
+    )
+    for kind in ("g1", "g2")
+}
+# B MSMs of one length as one launch: `_msm_tree`'s window sums over a
+# batch axis folded into the windows, and the program that pads its rows.
+# Both names keep `_msm_tree_jit_<kind>`.
+_MSM_TREE_BATCHED_JITS = {
+    kind: named_jit(
+        f"_msm_tree_jit_{kind}_batched", _msm_tree_batched,
+        static_argnums=(0, 3, 4, 5),
+    )
+    for kind in ("g1", "g2")
+}
+_MSM_TREE_BATCHED_PAD_JITS = {
+    kind: named_jit(
+        f"_msm_tree_jit_{kind}_batched_pad", _batched_pad,
+        static_argnums=(0,),
     )
     for kind in ("g1", "g2")
 }

@@ -150,6 +150,16 @@ def _msm_ladder_jit(curve: CurvePoints, points, scalars):
     return curve.sum_sequential(acc, axis=0)
 
 
+@functools.partial(jax.jit, static_argnums=(0,))
+def _msm_batched_ladder_jit(curve: CurvePoints, bases, scalars):
+    """`_msm_ladder_jit` over a batch axis: B small MSMs as one program
+    (an eager `fori_loop` would be traced and compiled anew each call)."""
+    from .curve import scalar_bits
+
+    acc = curve.scalar_mul_bits(bases, scalar_bits(scalars))
+    return curve.sum_sequential(acc, axis=1)
+
+
 def _limb_group_for(curve: CurvePoints):
     """The LimbGroup factory matching this curve's base field + extension
     degree, or None for unsupported configurations. BN254 and
@@ -267,31 +277,29 @@ def msm(curve: CurvePoints, points, scalars, window_bits: int | None = None,
 def msm_batched(curve: CurvePoints, bases, scalars_std):
     """B same-length MSMs: (B, n, 3)+elem x (B, n, 16) std-form scalars ->
     (B, 3)+elem. Single routing point shared with msm() (incl. the
-    DG16_FORCE_TREE_MSM override): Pallas tree kernels per MSM on TPU G1,
-    one batched ladder at small n, ONE vmapped Pippenger otherwise (a
-    Python loop of Pippengers put B bodies in the traced graph and the
-    m=4096 mesh-prover compile took 13+ minutes)."""
+    DG16_FORCE_TREE_MSM override): on the tree route ONE launch of the
+    batched tree program (`limb_kernels.msm_tree_batched`: the B MSMs'
+    windows folded into one, row b the sum msm() gives for row b), one
+    batched ladder at small n, ONE vmapped Pippenger otherwise (a Python
+    loop of Pippengers put B bodies in the traced graph and the m=4096
+    mesh-prover compile took 13+ minutes).
+
+    Each of the B MSMs counts as `msm/tree`, as it would alone; the launch
+    counts once as `msm_batched/tree`."""
     B, n = scalars_std.shape[0], scalars_std.shape[1]
     tree_g = _tree_group(curve, n)
     if tree_g is not None:
-        from .limb_kernels import msm_tree, tree_affine_levels
+        from .limb_kernels import msm_tree_batched, tree_affine_levels
 
         _RB_TREE.inc()
+        _R_TREE.inc(B)
         _AFFINE_LEVELS.inc(
-            B * tree_affine_levels(tree_g, n, scalars_std.shape[-1])
+            tree_affine_levels(tree_g, n, scalars_std.shape[-1], B)
         )
-        return jnp.stack(
-            [
-                msm_tree(bases[b], scalars_std[b], group=tree_g)
-                for b in range(B)
-            ]
-        )
+        return msm_tree_batched(bases, scalars_std, tree_g)
     if n <= _LADDER_MSM_MAX_N:
-        from .curve import scalar_bits
-
         _RB_LADDER.inc()
-        acc = curve.scalar_mul_bits(bases, scalar_bits(scalars_std))
-        return curve.sum_sequential(acc, axis=1)
+        return _msm_batched_ladder_jit(curve, bases, scalars_std)
     _RB_VMAP.inc()
     wbits = 16 if n >= (1 << 14) else 8 if n >= 64 else 4
     return jax.vmap(lambda bs, sc: _msm_jit(curve, bs, sc, wbits))(

@@ -18,6 +18,13 @@ king adds the n points. The group element is the same, and the king learns
 w_j * P_j for a public w_j, no more than P_j.
 
 Communication: O(1) group elements per party — d_msm is compute-bound.
+
+Where the parties share a process and a chip (`LocalSimNet`, whose nets
+offer a `Rendezvous`), the n local MSMs of one d_msm are one launch of the
+batched tree program (`ops/msm.py:msm_batched`): each party hands in its
+(bases, w_j s_j) and gets its own row back. Row j is the MSM party j would
+have launched alone, so the king still receives n points. A net without a
+rendezvous (`ProdNet`, a party per process) runs its MSM alone.
 """
 
 from __future__ import annotations
@@ -30,7 +37,7 @@ import jax.numpy as jnp
 
 from ..ops.curve import CurvePoints
 from ..ops.field import fr
-from ..ops.msm import msm
+from ..ops.msm import msm, msm_batched
 from ..telemetry import metrics as _tm
 from ..telemetry import tracing as _tracing
 from .net import Net, king_section
@@ -44,6 +51,37 @@ _WEIGHTED_ROUNDS = _tm.registry().counter(
     "d_msm rounds whose king summed the parties' weighted points (no "
     "unpack in the exponent)",
 )
+
+_LOCAL_MSM = _tm.registry().counter(
+    "dmsm_local_msm_total",
+    "Parties' local d_msm MSMs, by whether the round's rendezvous ran them "
+    "as rows of one batched launch or the party ran its own",
+    ("path",),
+)
+_LOCAL_BATCHED = _LOCAL_MSM.labels(path="batched")
+_LOCAL_ALONE = _LOCAL_MSM.labels(path="alone")
+
+
+@jax.jit
+def _stack_rows(rows):
+    """The parties' (bases, scalars) as the batch's two stacked arrays."""
+    return (jnp.stack([b for b, _ in rows]), jnp.stack([s for _, s in rows]))
+
+
+@jax.jit
+def _unstack_rows(points):
+    """(n, 3)+elem -> the n rows, one launch for all."""
+    return tuple(points[j] for j in range(points.shape[0]))
+
+
+def _local_msms(curve: CurvePoints):
+    """The rendezvous's work for one d_msm: the n parties' MSMs as one
+    `msm_batched` launch, row j back to party j."""
+
+    def run(rows):
+        return list(_unstack_rows(msm_batched(curve, *_stack_rows(rows))))
+
+    return run
 
 
 @functools.partial(jax.jit, static_argnums=0)
@@ -82,7 +120,12 @@ async def d_msm(
         # ops/msm.py's digit decomposition is width-aware as of r5
         weight = pp.unpack2_weight_limbs(F, net.party_id)
         std = F.mul(scalar_shares, weight)
-        local = msm(curve, bases, std)
+        if getattr(net, "rendezvous", None) is None:
+            _LOCAL_ALONE.inc()
+            local = msm(curve, bases, std)
+        else:
+            _LOCAL_BATCHED.inc()
+            local = await net.batch_local((bases, std), _local_msms(curve), sid)
 
         def king(points):
             # one child span a statement: an idle chip inside the king

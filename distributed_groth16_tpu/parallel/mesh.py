@@ -185,7 +185,9 @@ def _mesh_dmsm_batched(curve, bases_block, scalar_block, pp: PackedSharingParams
     replicated clear (B, 3)+elem. Batching is the compile-time lever: each
     distinct curve-op instantiation costs seconds of XLA:CPU compile,
     so the prover's three same-length G1 MSMs share
-    one ladder instead of instantiating three.
+    one ladder instead of instantiating three. On the tree route the
+    shard's k * B local MSMs are one body of the batched tree program
+    (`msm_batched`), not k * B bodies.
 
     As in the star round (parallel/dmsm.py), each party weighs its shares
     by its public unpack weight w_j in the one Montgomery product that

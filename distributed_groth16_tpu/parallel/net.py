@@ -79,13 +79,16 @@ _COLL = {
     op: _COLLECTIVE_SECONDS.labels(op=op)
     for op in (
         "send_to", "recv_from", "gather_to_king", "scatter_from_king",
-        "king_compute",
+        "king_compute", "batch_local",
     )
 }
 _TIMEOUTS = _REG.counter(
     "net_timeouts_total", "Collective deadline expiries, per op", ("op",)
 )
-_TO = {op: _TIMEOUTS.labels(op=op) for op in ("send_to", "recv_from")}
+_TO = {
+    op: _TIMEOUTS.labels(op=op)
+    for op in ("send_to", "recv_from", "batch_local")
+}
 _ROUND_RETRIES = _REG.counter(
     "net_round_retries_total",
     "MPC rounds re-run after a transient transport fault",
@@ -404,18 +407,86 @@ class BaseNet:
         return None
 
 
+class Rendezvous:
+    """Where the parties of one in-process fabric meet to have one piece of
+    local work done for all of them at once: each hands in its value, the
+    last to arrive runs `f` once on the n values (in party order), and each
+    party gets its own of the n results back. Nothing crosses a party
+    boundary that the party would not compute alone: `f` maps row j to
+    result j. The parties meet per `sid` and, on one sid, in the order of
+    their calls, so the k-th call of every party on a sid is one meeting.
+
+    It exists only where the parties share a process and a device: the
+    eight MSMs of a `d_msm` are then one launch in place of eight
+    (`parallel/dmsm.py`). A party that never arrives leaves the others
+    waiting until the net's deadline, then MpcTimeoutError: a transport
+    fault, so `run_round_with_retries` reruns the round."""
+
+    def __init__(self, n_parties: int):
+        self.n_parties = n_parties
+        self._calls: dict[tuple[int, int], int] = {}
+        self._meetings: dict[tuple[int, int], tuple[dict, asyncio.Future]] = {}
+
+    async def meet(self, party: int, value: Any, f, sid: int,
+                   timeout: float | None):
+        k = self._calls.get((party, sid), 0)
+        self._calls[(party, sid)] = k + 1
+        meeting = self._meetings.get((sid, k))
+        if meeting is None:
+            meeting = ({}, asyncio.get_running_loop().create_future())
+            self._meetings[(sid, k)] = meeting
+        values, done = meeting
+        values[party] = value
+        if len(values) == self.n_parties:
+            del self._meetings[(sid, k)]
+            try:
+                done.set_result(f([values[j] for j in range(self.n_parties)]))
+            except Exception as e:  # every party of the meeting raises it
+                done.set_exception(e)
+        # shielded: one party's deadline or cancellation is not the others'
+        out = await asyncio.wait_for(asyncio.shield(done), timeout)
+        return out[party]
+
+
 class LocalSimNet(BaseNet):
     """In-process n-party network: one shared mailbox fabric, one instance
-    per party. The LocalTestNet role (multi.rs:227-316) without sockets."""
+    per party. The LocalTestNet role (multi.rs:227-316) without sockets.
+    Nets made together (`make_local_nets`) also share a `Rendezvous`, and
+    `batch_local` offers it to the kernels; a net made without one has
+    `rendezvous` None, as every net across processes does."""
 
     def __init__(
         self, party_id: int, n_parties: int, fabric,
         net_cfg: NetConfig | None = None,
+        rendezvous: Rendezvous | None = None,
     ):
         self.party_id = party_id
         self.n_parties = n_parties
         self._fabric = fabric
         self.net_cfg = net_cfg
+        self.rendezvous = rendezvous
+
+    async def batch_local(
+        self, value: Any, f: Callable[[list], list], sid: int = 0,
+        timeout: float | None = None,
+    ):
+        """This party's result of `f` run once on every party's `value`
+        (`Rendezvous`), under the net's per-op deadline."""
+        t = self._resolve_timeout(timeout)
+        t0 = time.perf_counter()
+        with _tracing.span("net.batch_local", party=self.party_id, sid=sid):
+            try:
+                return await self.rendezvous.meet(
+                    self.party_id, value, f, sid, t
+                )
+            except (asyncio.TimeoutError, TimeoutError):
+                _TO["batch_local"].inc()
+                raise MpcTimeoutError(
+                    f"rendezvous deadline ({t}s) exceeded",
+                    party=self.party_id, sid=sid, op="batch_local",
+                ) from None
+            finally:
+                _COLL["batch_local"].observe(time.perf_counter() - t0)
 
     async def _send_impl(self, to: int, value: Any, sid: int) -> None:
         if not (0 <= to < self.n_parties) or to == self.party_id:
@@ -433,7 +504,8 @@ class LocalSimNet(BaseNet):
 def make_local_nets(
     n_parties: int, net_cfg: NetConfig | None = None
 ) -> list[LocalSimNet]:
-    """One LocalSimNet per party over a fresh shared fabric."""
+    """One LocalSimNet per party over a fresh shared fabric and
+    rendezvous."""
     fabric = {
         (s, d, c): asyncio.Queue()
         for s in range(n_parties)
@@ -441,8 +513,10 @@ def make_local_nets(
         for c in range(CHANNELS)
         if s != d
     }
+    rendezvous = Rendezvous(n_parties)
     return [
-        LocalSimNet(i, n_parties, fabric, net_cfg) for i in range(n_parties)
+        LocalSimNet(i, n_parties, fabric, net_cfg, rendezvous)
+        for i in range(n_parties)
     ]
 
 

@@ -326,3 +326,67 @@ def test_msm_raises_the_affine_levels_counter_at_dispatch(monkeypatch):
     view = lk.WideScalars.observe([1] * 32767)
     assert msm_mod.msm(g1(), pts[:32767], sc[:32767], wide=view) == "launched"
     assert total() - before == 4
+
+
+@pytest.mark.parametrize("name,n,rows,want", [
+    # a d_msm of the MPC round as one launch: eight parties' 16,384 points,
+    # 256 windows: the levels of 2^21 ... 2^16 adds, in one window group
+    ("g1", 16384, 8, 6),
+    ("g2", 16384, 8, 6),
+    # B's shares, 13,813 points, pad to the same 16,384
+    ("g2", 13813, 8, 6),
+    # one row is the unbatched launch
+    ("g1", 16384, 1, 3),
+    # 16 rows of 2^14 lanes pass 2^17 a window: the 512 windows go in
+    # groups of 8 (G1), 2^17 lanes a group, one level of 2^16 adds each
+    ("g1", 16384, 16, 64),
+])
+def test_a_batched_launch_counts_the_lanes_of_all_its_rows(
+    name, n, rows, want
+):
+    g = _group(name)[1]
+    windows = rows * 16 * LIMB_BITS // lk._tree_window_bits(n)
+    group = lk._tree_window_group(g, lk._tree_npad(n), windows, rows)
+    assert group == (windows if rows * lk._tree_npad(n) <= 1 << 17 else 8)
+    assert lk.tree_affine_levels(g, n, 16, rows) == want
+
+
+def test_msm_batched_raises_its_counters_once_a_launch(monkeypatch):
+    """One launch for B rows: `msm/tree` by B (each row is a tree MSM),
+    `msm_batched/tree` by one, the affine counter by the launch's levels."""
+    from distributed_groth16_tpu.ops import msm as msm_mod
+    from distributed_groth16_tpu.ops.curve import g2
+    from distributed_groth16_tpu.telemetry import metrics
+
+    monkeypatch.setenv("DG16_FORCE_TREE_MSM", "1")
+    monkeypatch.setattr(lk, "msm_tree_batched", lambda *a, **kw: "launched")
+    routes = metrics.registry().family("kernel_route_total")
+    levels = metrics.registry().family("msm_affine_levels_total")
+
+    def read():
+        got = {k: c.value for k, c in routes.items()}
+        return (got.get(("msm", "tree"), 0), got.get(("msm_batched", "tree"), 0),
+                sum(c.value for _, c in levels.items()))
+
+    before = read()
+    sc = np.zeros((8, 13813, 16), np.uint32)
+    assert msm_mod.msm_batched(g2(), None, sc) == "launched"
+    assert [a - b for a, b in zip(read(), before)] == [8, 1, 6]
+
+
+@pytest.mark.parametrize("name", ["g1", "g2"])
+def test_a_batch_of_rows_pads_with_infinity_and_zero_scalars(name):
+    """`msm_tree_batched` runs rows of any length at the power of two: the
+    padding adds points at infinity with scalar 0 and keeps the rows."""
+    from distributed_groth16_tpu.ops.constants import G1_GENERATOR, G2_GENERATOR
+    from distributed_groth16_tpu.ops.curve import g1, g2
+
+    C, gen = (g1(), G1_GENERATOR) if name == "g1" else (g2(), G2_GENERATOR)
+    pts = C.encode([gen] * 6)
+    pts = pts.reshape((2, 3) + pts.shape[1:])
+    sc = jnp.ones((2, 3, 16), jnp.uint32)
+    padded, scp = lk._MSM_TREE_BATCHED_PAD_JITS[name](_group(name)[1], pts, sc)
+    assert padded.shape[:2] == scp.shape[:2] == (2, 4)
+    assert bool(jnp.all(padded[:, :3] == pts))
+    assert bool(jnp.all(scp[:, :3] == 1)) and bool(jnp.all(scp[:, 3] == 0))
+    assert [C.decode(padded[b, 3]) for b in range(2)] == [None, None]

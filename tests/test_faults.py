@@ -722,3 +722,34 @@ def test_kill_worker_mid_batch_jobs_survive(tmp_path):
         assert JobJournal(jdir, fsync=False).pending() == []
 
     _bounded(scenario())
+
+
+def test_a_party_that_never_reaches_the_rendezvous_times_the_round_out():
+    """Party 2 dies before the in-process rendezvous: the others wait
+    out the net's deadline, no longer, and the round ends in
+    MpcTimeoutError naming the op, which `run_round_with_retries` reruns
+    on a fresh fabric and rendezvous."""
+    state = {"round": 0, "died": None, "ended": None}
+
+    async def party(net, _):
+        if net.party_id == 0:
+            state["round"] += 1
+        if net.party_id == 2 and state["round"] == 1:
+            state["died"] = time.monotonic()
+            await asyncio.sleep(SUITE_BOUND_S)  # never arrives
+        return await net.batch_local(
+            net.party_id, lambda ids: [sum(ids)] * len(ids)
+        )
+
+    def on_retry(attempt, e):
+        state["ended"] = time.monotonic()
+        assert isinstance(e, MpcTimeoutError) and e.op == "batch_local"
+
+    before = _counter("net_timeouts_total", op="batch_local")
+    out = run_round_with_retries(
+        3, party, retries=1, net_cfg=FAST, on_retry=on_retry
+    )
+    assert out == [3] * 3 and state["round"] == 2
+    waited = state["ended"] - state["died"]
+    assert FAST.op_timeout_s <= waited + 0.05 < FAST.op_timeout_s + 1.0
+    assert _counter("net_timeouts_total", op="batch_local") >= before + 1

@@ -75,9 +75,29 @@ def test_msm_chunked_matches_unchunked():
     assert a == b == rm.G1.msm(pts, scalars)
 
 
-def test_msm_batched_matches_per_call():
-    """msm_batched must agree with per-call msm() on every routing path:
-    ladder, vmapped Pippenger, and (via the force override) the tree path.
+# msm_batched's cases over G1, each in a subprocess of its own: (B, n, the
+# tree route forced, `affine_min_adds` where the case forces affine levels).
+# "routes" is every route at B = 3: the ladder (16), the vmapped
+# Pippenger (192) and the tree (64), each row against a per-call msm(); the
+# others are the batched tree program alone, each row against the host's
+# MSM. Every row's scalars hold 0, 1, r - 1, bits and values about 2^16.
+# No G2 case: a G2 tree program costs 130-210 s of XLA:CPU compile
+# whatever its size, and the batching is the same code for both groups;
+# `tests/test_dmsm_rendezvous.py` proves a round through the G2 batched
+# program (B = 4) against the single-node proof. n = 12 pads to 16.
+_BATCHED_CASES = {
+    "routes": (3, None, None, None),
+    "tree_g1_b1": (1, 16, True, None),
+    "tree_g1_b8": (8, 12, True, None),
+    "tree_g1_b3_affine": (3, 16, True, 16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BATCHED_CASES))
+def test_msm_batched_matches_per_call(case):
+    """msm_batched must agree with the MSM of each row on every routing
+    path: ladder, vmapped Pippenger, and (via the force override) the tree
+    path, which is one launch of the batched tree program for all B rows.
     Runs in a FRESH subprocess: this jax's XLA:CPU compiler segfaults
     compiling the vmapped Pippenger once enough executables are live in a
     long-lived process (the same state-dependent crash documented in
@@ -91,30 +111,60 @@ import sys
 sys.path.insert(0, "@@ROOT@@")
 import numpy as np
 import jax.numpy as jnp
+from distributed_groth16_tpu.ops import limb_kernels as lk
 from distributed_groth16_tpu.ops import refmath as rm
 from distributed_groth16_tpu.ops.constants import G1_GENERATOR, R
 from distributed_groth16_tpu.ops.curve import g1
 from distributed_groth16_tpu.ops.msm import encode_scalars_std, msm, msm_batched
 import os
+B, n, force_tree, affine_min_adds = @@CASE@@
 C = g1()
 rng = np.random.default_rng(7)
-for n, force_tree in ((16, False), (192, False), (64, True)):
-    os.environ.pop("DG16_FORCE_TREE_MSM", None)
-    if force_tree:
-        os.environ["DG16_FORCE_TREE_MSM"] = "1"
-    B = 3
-    scal = [[int.from_bytes(rng.bytes(40), "little") % R for _ in range(n)]
-            for _ in range(B)]
-    base_pts = [rm.G1.scalar_mul(G1_GENERATOR, 1 + int(rng.integers(1, 1 << 30)))
-                for _ in range(B * n)]
-    bases = C.encode(base_pts).reshape(B, n, 3, 16)
+EDGE = [0, 1, R - 1, 0, 1, (1 << 16) - 1, 1 << 16]
+
+
+def rows(n):
+    scal = [[EDGE[(i + b) % len(EDGE)] if i % 2 == 0
+             else int.from_bytes(rng.bytes(40), "little") % R
+             for i in range(n)] for b in range(B)]
+    pts = [rm.G1.scalar_mul(G1_GENERATOR, 1 + int(rng.integers(1, 1 << 30)))
+           for _ in range(B * n)]
+    bases = C.encode(pts).reshape((B, n) + C.infinity().shape)
     std = jnp.stack([encode_scalars_std(s) for s in scal])
-    out = msm_batched(C, bases, std)
+    return scal, pts, bases, std
+
+
+if n is None:  # every route against a per-call msm()
+    for n, force in ((16, False), (192, False), (64, True)):
+        os.environ.pop("DG16_FORCE_TREE_MSM", None)
+        if force:
+            os.environ["DG16_FORCE_TREE_MSM"] = "1"
+        scal, pts, bases, std = rows(n)
+        out = msm_batched(C, bases, std)
+        for b in range(B):
+            exp = msm(C, bases[b], std[b])
+            assert bool(jnp.all(C.eq(out[b], exp))), (n, b, force)
+else:  # the batched tree program against the host's MSM of each row
+    os.environ["DG16_FORCE_TREE_MSM"] = "1"
+    scal, pts, bases, std = rows(n)
+    if affine_min_adds is None:
+        out = msm_batched(C, bases, std)
+    else:
+        c = lk._tree_window_bits(n)
+        windows = B * 16 * 16 // c
+        assert all(lk._affine_depths(
+            windows, windows, lk._tree_npad(n), affine_min_adds
+        ))
+        out = lk._MSM_TREE_BATCHED_JITS["g1"](
+            lk.lg1(), bases, std, c, None, affine_min_adds
+        )
+    assert out.shape == (B,) + C.infinity().shape
     for b in range(B):
-        exp = msm(C, bases[b], std[b])
-        assert bool(jnp.all(C.eq(out[b], exp))), (n, b, force_tree)
+        assert C.decode(out[b]) == rm.G1.msm(pts[b * n:(b + 1) * n], scal[b]), b
 print("BATCHED_OK")
-""".replace("@@ROOT@@", os.path.join(os.path.dirname(__file__), ".."))
+""".replace("@@ROOT@@", os.path.join(os.path.dirname(__file__), "..")).replace(
+        "@@CASE@@", repr(_BATCHED_CASES[case])
+    )
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(

@@ -87,3 +87,51 @@ def test_fabric_shape():
     assert [n.party_id for n in nets] == [0, 1, 2]
     assert nets[0].is_king and not nets[1].is_king
     assert len(nets[0]._fabric) == 3 * 2 * CHANNELS
+
+
+def test_rendezvous_runs_the_work_once_and_hands_each_party_its_row():
+    """The in-process star's rendezvous: one call of `f` for the n
+    parties' values in party order, party j gets row j; on one sid the
+    k-th call of every party is one meeting, and two sids meet apart."""
+    calls = []
+
+    def f(vals):
+        calls.append(list(vals))
+        return [v * 10 for v in vals]
+
+    async def party(net, _):
+        assert net.rendezvous is not None
+        a, b = await asyncio.gather(
+            net.batch_local(net.party_id, f, sid=0),
+            net.batch_local(100 + net.party_id, f, sid=1),
+        )
+        c = await net.batch_local(200 + net.party_id, f, sid=0)
+        return a, b, c
+
+    out = simulate_network_round(4, party)
+    assert out == [(10 * j, 10 * (100 + j), 10 * (200 + j)) for j in range(4)]
+    assert sorted(calls) == [
+        [0, 1, 2, 3], [100, 101, 102, 103], [200, 201, 202, 203]
+    ]
+
+
+def test_rendezvous_failure_reaches_every_party():
+    """An exception of the work is every party's: none waits on."""
+
+    def f(vals):
+        raise ValueError("the batched work failed")
+
+    async def party(net, _):
+        with pytest.raises(ValueError):
+            await net.batch_local(net.party_id, f)
+        return "raised"
+
+    assert simulate_network_round(3, party) == ["raised"] * 3
+
+
+def test_a_net_made_alone_offers_no_rendezvous():
+    from distributed_groth16_tpu.parallel.net import LocalSimNet
+
+    nets = make_local_nets(2)
+    assert nets[0].rendezvous is nets[1].rendezvous
+    assert LocalSimNet(0, 2, nets[0]._fabric).rendezvous is None
